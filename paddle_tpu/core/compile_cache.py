@@ -20,16 +20,25 @@ This module eliminates them across processes:
   Pass.apply version bump, a jaxlib upgrade, an AMP toggle) is a
   clean miss, never a stale executable.
 * Values are serialized AOT executables via
-  ``jax.experimental.serialize_executable`` (API feature-detected the
-  way native/hlo_exec.py detects the StableHLO bridges), plus the aux
-  metadata (state_in/const_in/state_out names, feed/fetch lists,
-  write-only carry specs) needed to rehydrate a compiled step with
-  ZERO tracing. When executable serialization is unavailable the
-  entry persists lowered StableHLO instead — tracing is still
-  skipped; only the backend compile is redone at load.
+  ``jax.experimental.serialize_executable``, plus the aux metadata
+  (state_in/const_in/state_out names, feed/fetch lists, write-only
+  carry specs, the ids of the devices it ran on) needed to rehydrate
+  a compiled step with ZERO tracing on those same devices. When an
+  executable refuses to serialize the entry persists lowered
+  StableHLO instead — tracing is still skipped; only the backend
+  compile is redone at load.
 * Corrupt or stale entries are discarded with a named reason
   (``CompileCache.discards``) and the caller recompiles — a broken
   cache can slow a process down, never break it.
+
+Placement. JAX's own persistent compilation cache and this cache
+share one rule (``cache_root``): under ``$JAX_COMPILATION_CACHE_DIR``
+when it is set (JAX reads the variable itself, so nothing here sets a
+directory in code), else ``<checkout>/.jax_cache`` derived from this
+file's path -- never the current directory, a temp dir, a pid or the
+clock, because the path is part of JAX's cache key and a directory
+that moves never hits. ``enable_persistent_cache()`` is the one place
+entry scripts (chip_smoke.py, bench.py) turn JAX's cache on.
 
 Gated by ``FLAGS_compile_cache={off,ro,rw}`` +
 ``FLAGS_compile_cache_dir``; wired through every Executor compile
@@ -55,11 +64,42 @@ from typing import Any, Dict, Optional, Tuple
 import numpy as np
 
 __all__ = ["CompileCache", "active_cache", "canonical_digest",
-           "version_token"]
+           "version_token", "cache_root", "exe_cache_root",
+           "enable_persistent_cache"]
 
 # bump when the entry layout changes: old-format entries become clean
 # named-reason discards instead of unpickling hazards
-_MAGIC = "ptp-exe-cache-v1"
+_MAGIC = "ptp-exe-cache-v2"
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def cache_root() -> str:
+    """Directory both compile caches live under (module docstring)."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+        or os.path.join(_CHECKOUT, ".jax_cache")
+
+
+def exe_cache_root() -> str:
+    """Root of this module's executable cache:
+    ``FLAGS_compile_cache_dir`` resolved against ``cache_root()``
+    (an absolute flag value is a deployment setting and stands)."""
+    from ..flags import FLAGS
+
+    return os.path.join(cache_root(), FLAGS.compile_cache_dir)
+
+
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache at ``cache_root()``
+    and return that directory. With ``JAX_COMPILATION_CACHE_DIR`` set
+    JAX has already read it and no directory is set here."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        import jax
+
+        jax.config.update("jax_compilation_cache_dir", cache_root())
+    return cache_root()
+
 
 # tests force the StableHLO persistence path without uninstalling the
 # serialize_executable API
@@ -109,22 +149,17 @@ def _source_token() -> str:
     h = hashlib.sha256()
     pkg_root = os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))
-    try:
-        paths = []
-        for dirpath, dirnames, files in os.walk(pkg_root):
-            dirnames[:] = [d for d in dirnames
-                           if d != "__pycache__"]
-            paths.extend(os.path.join(dirpath, f) for f in files
-                         if f.endswith(".py"))
-        for p in sorted(paths):
-            h.update(p[len(pkg_root):].encode())
-            with open(p, "rb") as f:
-                h.update(f.read())
-        token = h.hexdigest()
-    except Exception:
-        token = "unhashable-source"
-    _SOURCE_TOKEN.append(token)
-    return token
+    paths = []
+    for dirpath, dirnames, files in os.walk(pkg_root):
+        dirnames[:] = [d for d in dirnames if d != "__pycache__"]
+        paths.extend(os.path.join(dirpath, f) for f in files
+                     if f.endswith(".py"))
+    for p in sorted(paths):
+        h.update(p[len(pkg_root):].encode())
+        with open(p, "rb") as f:
+            h.update(f.read())
+    _SOURCE_TOKEN.append(h.hexdigest())
+    return _SOURCE_TOKEN[0]
 
 
 def version_token() -> Dict[str, str]:
@@ -136,30 +171,24 @@ def version_token() -> Dict[str, str]:
     of either must be a clean miss (tests spoof this to prove
     invalidation)."""
     import jax
+    import jaxlib
 
-    try:
-        import jaxlib
-
-        jl = getattr(getattr(jaxlib, "version", None), "__version__",
-                     None) or getattr(jaxlib, "__version__", "unknown")
-    except Exception:
-        jl = "unknown"
-    return {"jax": jax.__version__, "jaxlib": str(jl),
+    return {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
             "paddle_tpu_src": _source_token()}
 
 
-def _serialize_fns():
-    """Feature-detect the executable (de)serialization API — jaxlib
-    renames bite (CLAUDE.md r6: three spellings drifted in this
-    container alone), so never assume, always probe."""
-    if _FORCE_STABLEHLO[0]:
-        return None, None
-    try:
-        from jax.experimental import serialize_executable as se
-    except Exception:
-        return None, None
-    return (getattr(se, "serialize", None),
-            getattr(se, "deserialize_and_load", None))
+def _execution_device_ids(compiled) -> list:
+    """Ids of the devices a jax.stages.Compiled runs on, in assignment
+    order. Stored with the entry: deserialize_and_load defaults to
+    EVERY visible device and then wants one shard per device, so a
+    single-device executable would not load on a multi-device host."""
+    import jax
+
+    sh = jax.tree.leaves((compiled.input_shardings,
+                          compiled.output_shardings))[0]
+    mesh = getattr(sh, "mesh", None)
+    devs = mesh.devices.flat if mesh is not None else sh.device_set
+    return [int(d.id) for d in devs]
 
 
 class _StableHLOCallable:
@@ -190,17 +219,11 @@ class _StableHLOCallable:
         return jax.tree.unflatten(self._out_tree, list(outs))
 
 
-def _compile_stablehlo(text: str):
-    """backend.compile with the hlo_exec.py API feature detection."""
-    import jax
+def _compile_stablehlo(text: str, devices):
     from jax._src.lib import xla_client
 
-    backend = jax.devices()[0].client
-    opts = xla_client.CompileOptions()
-    if hasattr(backend, "compile_and_load"):
-        return backend.compile_and_load(text, backend.devices()[:1],
-                                        opts)
-    return backend.compile(text, opts)
+    return devices[0].client.compile_and_load(
+        text, devices[:1], xla_client.CompileOptions())
 
 
 class CompileCache:
@@ -284,40 +307,35 @@ class CompileCache:
             self._discard(digest, "entry format mismatch (truncated "
                           "or written by an incompatible version)")
             return None
-        mesh = (entry.get("meta") or {}).get("mesh")
-        if mesh:
-            # a sharded executable embeds its device assignment:
-            # validate BEFORE deserializing so a process without the
-            # mesh (fewer virtual devices, missing device ids) gets a
-            # NAMED discard instead of a deserialization crash deep
-            # inside jaxlib
-            import jax
+        # an executable embeds its device assignment: validate BEFORE
+        # deserializing so a process without those devices (fewer
+        # virtual devices, another mesh) gets a NAMED discard instead
+        # of a deserialization crash deep inside jaxlib
+        import jax
 
-            have = {int(d.id) for d in jax.devices()}
-            want = [int(i) for i in mesh.get("device_ids", [])]
-            missing = [i for i in want if i not in have]
-            if int(mesh.get("ndev", 0)) > len(have) or missing:
-                self._discard(
-                    digest,
-                    f"mesh mismatch: entry compiled for a "
-                    f"{mesh.get('ndev')}-device mesh "
-                    f"(axes {mesh.get('axes')}, device ids {want}); "
-                    f"this process has {len(have)} device(s) "
-                    f"{sorted(have)[:8]} — recompiling for the local "
-                    f"mesh")
-                return None
+        by_id = {int(d.id): d for d in jax.devices()}
+        want = [int(i) for i in entry.get("device_ids", [])]
+        if not want or any(i not in by_id for i in want):
+            mesh = (entry.get("meta") or {}).get("mesh") or {}
+            self._discard(
+                digest,
+                f"mesh mismatch: entry compiled for device ids {want}"
+                f" (mesh axes {mesh.get('axes')}); this process has "
+                f"{len(by_id)} device(s) {sorted(by_id)[:8]} — "
+                f"recompiling for the local devices")
+            return None
+        devices = [by_id[i] for i in want]
         try:
             fmt = entry["format"]
             if fmt == "aot":
-                _, deserialize = _serialize_fns()
-                if deserialize is None:
-                    raise RuntimeError(
-                        "serialize_executable API unavailable in this "
-                        "jax")
-                fn = deserialize(entry["payload"], entry["in_tree"],
-                                 entry["out_tree"])
+                from jax.experimental.serialize_executable import \
+                    deserialize_and_load
+
+                fn = deserialize_and_load(
+                    entry["payload"], entry["in_tree"],
+                    entry["out_tree"], execution_devices=devices)
             elif fmt == "stablehlo":
-                loaded = _compile_stablehlo(entry["payload"])
+                loaded = _compile_stablehlo(entry["payload"], devices)
                 fn = _StableHLOCallable(loaded, entry["in_tree"],
                                         entry["out_tree"],
                                         entry["in_dtypes"])
@@ -358,13 +376,14 @@ class CompileCache:
             return False
         import jax
 
+        from jax.experimental.serialize_executable import serialize
+
         entry = {"magic": _MAGIC, "meta": meta,
-                 "versions": version_token()}
-        serialize, _ = _serialize_fns()
+                 "versions": version_token(),
+                 "device_ids": _execution_device_ids(compiled)}
         try:
-            if serialize is None:
-                raise RuntimeError(
-                    "serialize_executable API unavailable")
+            if _FORCE_STABLEHLO[0]:
+                raise RuntimeError("StableHLO persistence forced")
             payload, in_tree, out_tree = serialize(compiled)
             entry.update(format="aot", payload=payload,
                          in_tree=in_tree, out_tree=out_tree)
@@ -514,7 +533,7 @@ def active_cache() -> Optional[CompileCache]:
     mode = FLAGS.compile_cache
     if mode == "off":
         return None
-    root = os.path.abspath(FLAGS.compile_cache_dir)
+    root = exe_cache_root()
     key = (root, mode)
     cache = _CACHES.get(key)
     if cache is None:

@@ -290,7 +290,8 @@ class CompiledProgram:
         mutated, const, state_out = _analyze_block(block, feed_names,
                                                    fetch_names)
         step = _build_step_fn(block, feed_names, mutated, const,
-                              state_out, fetch_names)
+                              state_out, fetch_names,
+                              on_mesh=mesh.devices.size > 1)
         repl = NamedSharding(mesh, P())
         batched = NamedSharding(mesh, P("dp"))
         rules = self._param_rules()

@@ -111,7 +111,10 @@ _DEFS = {
     # zero in-process compiles. off = current behavior, ro = load
     # existing entries but never write, rw = load + populate.
     "compile_cache": (_as_cache_mode, "off", True),
-    "compile_cache_dir": (str, ".paddle_tpu_cache", True),
+    # resolved against compile_cache.cache_root() ($JAX_COMPILATION_
+    # CACHE_DIR, else <checkout>/.jax_cache), never the current
+    # directory; an absolute path stands as given
+    "compile_cache_dir": (str, "paddle_tpu_exe", True),
     # disk compile-cache GC (multi-model churn grows the cache dir
     # unboundedly otherwise): prune LRU-by-mtime on write down to
     # these bounds. <= 0 = unbounded. Loads touch mtime so entries

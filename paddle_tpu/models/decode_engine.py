@@ -1991,13 +1991,13 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
     def _ngram_admit(sv, src, A, oh, keep_f):
         """Model-free draft admission: scatter the admission prompts
         into the admitted lanes' ``prompt_toks`` copies — the text
-        the suffix matcher scans at every spec tick. Token ids <
-        vocab << 2^24, so the float32 one-hot matmul scatter is exact
-        (the radix hist_toks idiom)."""
-        ohT = layers.transpose(oh, perm=[1, 0])            # [rows, A]
-        scat = layers.cast(
-            layers.matmul(ohT, layers.cast(src, "float32")),
-            "int64")                                       # [R,S]
+        the suffix matcher scans at every spec tick. The one-hot
+        scatter is an INTEGER matmul (the radix hist_toks idiom): a
+        float32 one at the TPU's default precision rounds its
+        operands to bf16 and corrupts every token id above 256."""
+        ohT = layers.cast(layers.transpose(oh, perm=[1, 0]),
+                          "int64")                         # [rows, A]
+        scat = layers.matmul(ohT, src)                     # [R,S]
         keep_i = layers.cast(keep_f, "int64")
         keep_col = layers.reshape(keep_i, [rows, 1])
         var = sv[f"{state_prefix}prompt_toks"]
@@ -2148,14 +2148,14 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
         _reset_lane_state(sv, any_i, keep_i, oh=oh, seeds=seeds,
                           tier="radix")
         # overwrite the shared reset's cold-start row/counters with
-        # the session history. Token ids < vocab << 2^24, so the
-        # float32 one-hot matmul scatter is exact; the counters use
-        # the pure-int scatter idiom (they share the seed path's
-        # magnitude concern)
-        ohT = layers.transpose(oh, perm=[1, 0])            # [rows, A]
-        hist_scat = layers.cast(
-            layers.matmul(ohT, layers.cast(hist, "float32")),
-            "int64")                                       # [R,maxT]
+        # the session history, in INTEGER arithmetic throughout: a
+        # float32 one-hot matmul is exact on the CPU backend only --
+        # at the TPU's default matmul precision its operands round to
+        # bf16, and the first chip run (PR 21) replayed token 6532 as
+        # 6528 on every radix admission
+        ohT = layers.cast(layers.transpose(oh, perm=[1, 0]),
+                          "int64")                         # [rows, A]
+        hist_scat = layers.matmul(ohT, hist)               # [R,maxT]
         any_col = layers.reshape(any_i, [rows, 1])
         keep_col = layers.reshape(keep_i, [rows, 1])
         tok_buf = sv[f"{state_prefix}tok_buf"]

@@ -9,8 +9,10 @@ block dataflow analysis (donation/GC planning), the RecordIO data format,
 and LoD utilities. Bindings are plain ctypes (pybind11 unavailable).
 
 The shared object is compiled on demand with g++ and cached next to the
-sources; if compilation fails (no toolchain), every entry point degrades
-to the pure-Python fallbacks used by the callers.
+sources, stamped with a content hash of src/*.cc,*.h (mtimes mean
+nothing in a copied or checked-out tree). If compilation fails (no
+toolchain), every entry point degrades to the pure-Python fallbacks
+used by the callers and `build_error()` says why.
 """
 from __future__ import annotations
 
@@ -35,13 +37,18 @@ def _sources():
         os.path.join(_SRC, f) for f in os.listdir(_SRC) if f.endswith(".cc"))
 
 
+def _lib_src_hash() -> str:
+    return _src_hash(_sources() + sorted(
+        os.path.join(_SRC, f) for f in os.listdir(_SRC)
+        if f.endswith(".h")))
+
+
 def _needs_build():
-    if not os.path.exists(_LIB_PATH):
+    stamp = _LIB_PATH + ".srchash"
+    if not (os.path.exists(_LIB_PATH) and os.path.exists(stamp)):
         return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    src_files = _sources() + [
-        os.path.join(_SRC, f) for f in os.listdir(_SRC) if f.endswith(".h")]
-    return any(os.path.getmtime(s) > lib_mtime for s in src_files)
+    with open(stamp) as f:
+        return f.read().strip() != _lib_src_hash()
 
 
 def _build():
@@ -50,6 +57,8 @@ def _build():
     proc = subprocess.run(cmd, capture_output=True, text=True)
     if proc.returncode != 0:
         raise RuntimeError(f"native build failed:\n{proc.stderr}")
+    with open(_LIB_PATH + ".srchash", "w") as f:
+        f.write(_lib_src_hash())
 
 
 def _declare(lib):
@@ -534,7 +543,7 @@ _XLA_TRAIN_BIN = os.path.join(_DIR, "_xla_train")
 _xla_train_lock = threading.Lock()
 # (source-hash, tf-root) -> error message: a failure is retried when
 # either the sources change or a different toolchain appears, instead
-# of latching the first error for the process lifetime (ADVICE r4)
+# of latching the first error for the process lifetime
 _xla_train_error: dict = {}
 
 
@@ -549,7 +558,7 @@ def _xla_train_deps():
 def _src_hash(paths) -> str:
     """Content hash of the native sources. Freshness must NOT use
     mtimes: git checkouts do not preserve them, so a stale (or
-    foreign) binary could shadow newer sources (ADVICE r4)."""
+    foreign) binary could shadow newer sources."""
     import hashlib
 
     h = hashlib.sha256()

@@ -58,25 +58,11 @@ class NativeBuiltStep:
                 self._manifest = json.load(f)
         from jax._src.lib import xla_client
 
-        mlir = xla_client._xla.mlir
-        if hasattr(mlir, "hlo_to_stablehlo"):
-            stablehlo = mlir.hlo_to_stablehlo(hlo)
-        else:
-            # newer jaxlibs dropped hlo_to_stablehlo; round-trip the
-            # HLO proto through an XlaComputation instead (same
-            # StableHLO module, different door)
-            comp = xla_client.XlaComputation(hlo)
-            stablehlo = mlir.xla_computation_to_mlir_module(comp)
+        stablehlo = xla_client._xla.mlir.hlo_to_stablehlo(hlo)
         backend = jax.devices()[0].client
-        if hasattr(backend, "compile_and_load"):
-            self._loaded = backend.compile_and_load(
-                stablehlo, backend.devices()[:1],
-                xla_client.CompileOptions())
-        else:
-            # older client API: compile() loads onto the backend's
-            # devices directly
-            self._loaded = backend.compile(
-                stablehlo, xla_client.CompileOptions())
+        self._loaded = backend.compile_and_load(
+            stablehlo, backend.devices()[:1],
+            xla_client.CompileOptions())
         self.state_out_names = [
             s["name"] for s in self._manifest["outputs"]
             if s["kind"] == "state"]

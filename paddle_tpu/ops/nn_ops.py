@@ -425,14 +425,15 @@ def layer_norm(ctx):
     x2f = x2.astype(jnp.float32)
     mean = jnp.mean(x2f, axis=1, keepdims=True)
     var = jnp.var(x2f, axis=1, keepdims=True)
-    from .pallas import layer_norm as pallas_ln
+    from .pallas import layer_norm as pallas_ln, note_route
 
     if scale is not None and bias is not None:
         s1, b1 = scale.reshape(-1), bias.reshape(-1)
         # pallas kernel when usable, else its oracle (_ln_ref) -- ONE
         # fp32 recipe shared with the kernel's custom_vjp backward
         y = (pallas_ln.layer_norm(x2, s1, b1, eps)
-             if pallas_ln.usable(lead, x2.shape[1])
+             if note_route("layer_norm", x2.shape,
+                           pallas_ln.usable(lead, x2.shape[1]))
              else pallas_ln._ln_ref(x2, s1, b1, eps))
         return {"Y": y.reshape(x.shape), "Mean": mean.reshape(lead),
                 "Variance": var.reshape(lead)}
@@ -976,9 +977,9 @@ def ffn_block_op(ctx):
     x = ctx.input("X")
     w1, b1 = ctx.input("W1"), ctx.input("B1")
     w2, b2 = ctx.input("W2"), ctx.input("B2")
-    from .pallas import ffn_block as FB
+    from .pallas import ffn_block as FB, note_route
 
-    if FB.usable(x, w1):
+    if note_route("ffn_block", x.shape, FB.usable(x, w1)):
         return {"Out": FB.ffn_block(x, w1, b1, w2, b2)}
     return {"Out": FB.ffn_block_reference(x, w1, b1, w2, b2)}
 
@@ -999,9 +1000,10 @@ def attention_block_op(ctx):
     if scale is None:
         scale = (x.shape[-1] // n_heads) ** -0.5
     causal = ctx.attr("causal", False)
-    from .pallas import attention_block as AB
+    from .pallas import attention_block as AB, note_route
 
-    if AB.usable(x, wqkv, n_heads):
+    if note_route("attention_block", x.shape,
+                  AB.usable(x, wqkv, n_heads)):
         out = AB.attention_block(x, wqkv, wo, n_heads, float(scale),
                                  bool(causal))
     else:
@@ -1042,7 +1044,8 @@ def attention(ctx):
     if ra.cp_applicable(qh, kh, vh, dropout_rate):
         return to_bhtd(ra.cp_attention(qh, kh, vh, scale, causal))
     if dropout_rate == 0.0:
-        if pallas_attn.sdpa_usable(qh, kh, vh):
+        if pallas.note_route("sdpa_short", qh.shape,
+                             pallas_attn.sdpa_usable(qh, kh, vh)):
             # short-T fused SDPA: scores never touch HBM and the
             # backward reuses the saved probabilities instead of
             # re-exping (the VPU exp rate is the floor at short T --
@@ -1050,7 +1053,9 @@ def attention(ctx):
             # transposes at every size it accepts.
             return to_bhtd(pallas_attn.sdpa_short(
                 qh, kh, vh, scale=scale, causal=causal))
-        if pallas_attn.usable(qh, kh, vh) and qh.shape[2] > 512:
+        if pallas.note_route(
+                "flash_attention", qh.shape,
+                qh.shape[2] > 512 and pallas_attn.usable(qh, kh, vh)):
             # flash wins only at long T (its b*h-programs grid is
             # launch-overhead-bound below that -- measured slower than
             # the jnp composition at T<=512 on v5e, either layout)

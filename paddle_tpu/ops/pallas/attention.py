@@ -120,6 +120,7 @@ def _flash_fwd_impl(q, k, v, scale, causal):
             jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
         ],
         interpret=_interp(),
+        name="flash_attention_fwd",
     )(q3, k3, v3)
     return out.reshape(b, h, tq, d), lse.reshape(b, h, tq)
 
@@ -208,6 +209,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, scale, causal):
         out_specs=pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
         interpret=_interp(),
+        name="flash_attention_dq",
     )(q3, k3, v3, g3, lse3, delta)
 
     dkv_kernel = functools.partial(_bwd_dkv_kernel, scale=scale,
@@ -233,6 +235,7 @@ def _flash_bwd_impl(q, k, v, out, lse, g, scale, causal):
             jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
         ],
         interpret=_interp(),
+        name="flash_attention_dkv",
     )(q3, k3, v3, g3, lse3, delta)
 
     return (dq.reshape(b, h, tq, d), dk.reshape(b, h, tk, d),
@@ -433,6 +436,7 @@ def _sdpa_short_fwd_impl(q, k, v, scale, causal, save_p):
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=_interp(),
+        name="sdpa_short_fwd",
     )(q3, k3, v3)
     if save_p:
         out, p = res
@@ -480,6 +484,7 @@ def _sdpa_short_bwd_impl(q, k, v, p, g, scale, causal):
             jax.ShapeDtypeStruct((bh, t, d), v.dtype),
         ],
         interpret=_interp(),
+        name="sdpa_short_bwd",
     )(q3, k3, v3, g3, p)
     return (dq.reshape(b, h, t, d), dk.reshape(b, h, t, d),
             dv.reshape(b, h, t, d))

@@ -116,6 +116,7 @@ def _fwd_impl(x, w1, b1, w2, b2):
         out_specs=[x_spec],
         out_shape=[jax.ShapeDtypeStruct((b, t, d), x.dtype)],
         interpret=_interp(),
+        name="ffn_block_fwd",
     )(x, w1, b1, w2, b2)
     return out
 
@@ -175,6 +176,7 @@ def _bwd_impl(x, w1, b1, w2, b2, g):
             jax.ShapeDtypeStruct((n_prog, d), jnp.float32),
         ],
         interpret=_interp(),
+        name="ffn_block_bwd",
     )(x, w1, w2, b1, g)
     return (dx,
             jnp.sum(dw1p, axis=0).astype(w1.dtype),

@@ -11,10 +11,20 @@ import jax
 import jax.numpy as jnp
 
 
-def usable(n: int, d: int) -> bool:
-    from . import on_tpu
+from . import on_tpu
+from .attention import _interp
 
-    return on_tpu() and d % 128 == 0 and n >= 8
+# row-block candidates, all multiples of the fp32 (8, 128) tile
+_ROW_BLOCKS = (256, 128, 64, 32, 16, 8)
+
+
+def usable(n: int, d: int) -> bool:
+    """Rows must tile by 8 (the fp32 sublane): a serve program's
+    `n_slots + 1` rows (9 at eight lanes) would need a (1, d) block,
+    so such shapes take `_ln_ref`, which XLA fuses into its
+    neighbours."""
+    return (on_tpu() or _interp()) and d % 128 == 0 and n % 8 == 0 \
+        and n >= 8
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -50,25 +60,29 @@ def _ln_impl(x, scale, bias, eps):
     from jax.experimental import pallas as pl
 
     n, d = x.shape
-    block_n = next((b for b in (256, 128, 64, 32, 8, 1) if n % b == 0))
+    block_n = next(b for b in _ROW_BLOCKS if n % b == 0)
 
     def kernel(x_ref, s_ref, b_ref, o_ref):
         xb = x_ref[...].astype(jnp.float32)
         mean = xb.mean(axis=1, keepdims=True)
         var = jnp.mean(jnp.square(xb - mean), axis=1, keepdims=True)
         y = (xb - mean) * jax.lax.rsqrt(var + eps)
-        y = y * s_ref[...].astype(jnp.float32)[None, :] \
-            + b_ref[...].astype(jnp.float32)[None, :]
+        y = y * s_ref[...].astype(jnp.float32) \
+            + b_ref[...].astype(jnp.float32)
         o_ref[...] = y.astype(o_ref.dtype)
 
+    # scale/bias ride as [1, D]: a block whose sublane dim equals the
+    # array's is always a legal tile, a rank-1 (d,) block is not
     return pl.pallas_call(
         kernel,
         grid=(n // block_n,),
         in_specs=[
             pl.BlockSpec((block_n, d), lambda i: (i, 0)),
-            pl.BlockSpec((d,), lambda i: (0,)),
-            pl.BlockSpec((d,), lambda i: (0,)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block_n, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
-    )(x, scale, bias)
+        interpret=_interp(),
+        name="layer_norm",
+    )(x, scale.reshape(1, d), bias.reshape(1, d))

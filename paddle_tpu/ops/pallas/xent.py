@@ -22,7 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import on_tpu
+from . import note_route, on_tpu
 from .attention import _interp
 
 _ROW_BLOCK = 32  # bn x V fp32 temps stay ~4 MB in VMEM at V=32k
@@ -88,6 +88,7 @@ def xent_forward(logits2d, label1d, eps=0.0, ignore_index=-100):
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         interpret=_interp(),
+        name="xent_forward",
     )(logits2d, label1d.astype(jnp.int32)[:, None])
     return loss[:, 0], lse[:, 0]
 
@@ -131,6 +132,7 @@ def xent_backward(logits2d, label1d, dloss1d, eps=0.0,
         out_specs=pl.BlockSpec((bn, v), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, v), logits2d.dtype),
         interpret=_interp(),
+        name="xent_backward",
     )(logits2d, label1d.astype(jnp.int32)[:, None],
       dloss1d.astype(jnp.float32)[:, None])
 
@@ -144,6 +146,6 @@ def maybe_route(logits, label):
         lab = lab[..., 0]
     l2 = logits.reshape(-1, logits.shape[-1])
     lab1 = lab.reshape(-1)
-    if usable(l2, lab1):
+    if note_route("xent", l2.shape, usable(l2, lab1)):
         return l2, lab1
     return None

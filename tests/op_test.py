@@ -95,11 +95,9 @@ class OpTest(unittest.TestCase):
         differences of the forward kernel, like op_test.py:45.
         Runs under x64 so the fd quotient is not drowned by fp32 noise
         (the reference computes numeric grads in float64 too)."""
-        # jax >= 0.4.3x removed the jax.enable_x64 alias; the context
-        # manager lives in jax.experimental
-        from jax.experimental import enable_x64
+        import jax
 
-        with enable_x64():
+        with jax.enable_x64():
             self._check_grad_impl(inputs_to_check, output_name,
                                   max_relative_error, delta, no_grad_set)
 

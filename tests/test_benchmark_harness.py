@@ -155,7 +155,8 @@ class TestMeasurementHarness:
 
         assert bench._telemetry_snapshot is harness.telemetry_snapshot
         assert bench._write_bench_self is harness.write_bench_self
-        assert bench._probe_backend is harness.probe_backend
+        # one process per chip: no child probes the backend
+        assert not hasattr(harness, "probe_backend")
 
     def test_committed_records_parse_with_schema_keys(self):
         # every committed BENCH_SELF record the configs would diff
@@ -293,13 +294,13 @@ class TestTrendSentinel:
                            bench_dir=str(tmp_path)) == 2
 
     def test_headline_extraction_covers_every_era(self):
-        # r02 results-list, r10 nested dict, r11+ flat — each era's
-        # committed records must yield at least one headline (r05/r06
-        # are TPU-outage rounds with no headline, excluded)
+        # r10 nested dict, r11+ flat — each era's committed records
+        # must yield at least one headline (the r02/r05/r06 records
+        # of the retired rig were deleted in PR 21)
         from benchmark import trend
 
         by_round = {r["round"]: r for r in trend.build_records()}
-        for rnd in (2, 7, 9, 10, 11, 12, 13, 14):
+        for rnd in (7, 9, 10, 11, 12, 13, 14):
             assert by_round[rnd]["headlines"], rnd
         # parity flags surfaced from both nesting styles
         assert any("parity" in k

@@ -189,6 +189,7 @@ class TestMeshMismatchDiscard:
             entry = {"magic": CC._MAGIC, "format": "aot",
                      "payload": b"\x00junk-not-an-executable",
                      "in_tree": None, "out_tree": None,
+                     "device_ids": [98, 99],
                      "meta": {"mesh": {"ndev": 2,
                                        "axes": [["tp", 2]],
                                        "device_ids": [98, 99]}}}
