@@ -1,0 +1,10 @@
+"""Device idle time a dispatch under the program's `slotpool.dispatch` span
+(the prepared run of the serve program, its `exe.*` spans inside).
+Layer: serving scheduler (inference/serving.py _loop and _cycle); moves
+serve_tokens_per_s."""
+from benchmark.chip import program_spans
+
+
+def read(obs):
+    return program_spans.idle_ms_per(obs, "slotpool.dispatch",
+                                     "dispatches")

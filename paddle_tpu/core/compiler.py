@@ -25,9 +25,10 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .executor import (RNG_VAR, _analyze_block, _build_step_fn,
-                       _coerce_feed, _to_fetch_names, _var_np_dtype,
-                       _global_seed)
+from ..observability.tracing import span as _span
+from .executor import (RNG_VAR, Executor, _analyze_block,
+                       _build_step_fn, _coerce_feed, _to_fetch_names,
+                       _var_np_dtype, _global_seed)
 from .program import Program, default_main_program
 from .scope import global_scope
 
@@ -193,27 +194,32 @@ class CompiledProgram:
         ndev = mesh.shape.get("dp", 1) if hasattr(mesh, "shape") \
             else mesh.devices.size
 
-        feed_arrays = {}
-        feed_specs = []
-        for name, val in feed.items():
-            arr = _coerce_feed(val, _var_np_dtype(block, name))
-            if arr.shape[0] % ndev != 0:
-                # drop remainder like fluid's ParallelExecutor feed split
-                arr = arr[: (arr.shape[0] // ndev) * ndev]
-            feed_arrays[name] = arr
-            feed_specs.append((name, arr.shape, str(arr.dtype)))
+        with _span("exe.feed"):
+            feed_arrays = {}
+            feed_specs = []
+            for name, val in feed.items():
+                arr = _coerce_feed(val, _var_np_dtype(block, name))
+                if arr.shape[0] % ndev != 0:
+                    # drop remainder like fluid's ParallelExecutor
+                    # feed split
+                    arr = arr[: (arr.shape[0] // ndev) * ndev]
+                feed_arrays[name] = arr
+                feed_specs.append((name, arr.shape, str(arr.dtype)))
         from .. import amp
         from .executor import _parallel_scope_token
 
-        key = (self._program._uid, self._program._version,
-               tuple(sorted(feed_specs)), tuple(fetch_names), ndev,
-               getattr(self, "_config_epoch", 0),
-               amp.state_token(), _parallel_scope_token())
-        compiled = self._cache.get(key)
-        if compiled is None:
-            compiled = self._compile(block, tuple(sorted(feed_arrays)),
-                                     fetch_names, mesh)
-            self._cache[key] = compiled
+        with _span("exe.lookup"):
+            key = (self._program._uid, self._program._version,
+                   tuple(sorted(feed_specs)), tuple(fetch_names), ndev,
+                   getattr(self, "_config_epoch", 0),
+                   amp.state_token(), _parallel_scope_token())
+            compiled = self._cache.get(key)
+            if compiled is None:
+                with _span("exe.compile"):
+                    compiled = self._compile(
+                        block, tuple(sorted(feed_arrays)), fetch_names,
+                        mesh)
+                self._cache[key] = compiled
         return compiled(scope, feed_arrays, return_numpy)
 
     def _run_pipeline(self, feed, fetch_names, scope, mesh,
@@ -316,63 +322,63 @@ class CompiledProgram:
         # key-scan + regex + NamedSharding build per step
         _targets: Dict[str, NamedSharding] = {}
 
+        def place(n, v):
+            # A previously-placed array is kept only if its sharding
+            # agrees with the CURRENT rules: after a reconfiguring
+            # with_data_parallel() call the new structural rules must
+            # apply to state placed under the old config too (the
+            # config epoch busts the executable cache, but the scope
+            # arrays live on).
+            target = _targets.get(n)
+            if target is None:
+                target = _targets[n] = param_sharding(n, v)
+            if _is_sharded(v):
+                eq = _sharding_matches(v, target)
+                if eq:
+                    return v
+                if eq is None:
+                    # the CHECK failed, not the placement: keeping
+                    # the array could silently run with a stale
+                    # sharding (VERDICT r4 weak #6) — warn and
+                    # re-place (device_put is a no-op when the
+                    # sharding already agrees)
+                    import warnings
+
+                    warnings.warn(
+                        f"sharding equivalence check failed for "
+                        f"{n!r}; re-placing it under the current "
+                        f"rules")
+            return jax.device_put(v, target)
+
         def run(scope, feed_arrays, return_numpy):
-            mut = {n: scope._get(n) for n in mutated}
-            const_st = {n: scope._get(n) for n in const}
-            for n, v in list(mut.items()) + list(const_st.items()):
-                if v is None:
-                    raise RuntimeError(
-                        f"Variable {n!r} used before initialization -- "
-                        f"run the startup program first")
-            # place feeds sharded over dp, params replicated
-            sharded_feeds = {
-                n: jax.device_put(v, batched)
-                for n, v in feed_arrays.items()}
-
-            def place(n, v):
-                # A previously-placed array is kept only if its sharding
-                # agrees with the CURRENT rules: after a reconfiguring
-                # with_data_parallel() call the new structural rules must
-                # apply to state placed under the old config too (the
-                # config epoch busts the executable cache, but the scope
-                # arrays live on).
-                target = _targets.get(n)
-                if target is None:
-                    target = _targets[n] = param_sharding(n, v)
-                if _is_sharded(v):
-                    eq = _sharding_matches(v, target)
-                    if eq:
-                        return v
-                    if eq is None:
-                        # the CHECK failed, not the placement: keeping
-                        # the array could silently run with a stale
-                        # sharding (VERDICT r4 weak #6) — warn and
-                        # re-place (device_put is a no-op when the
-                        # sharding already agrees)
-                        import warnings
-
-                        warnings.warn(
-                            f"sharding equivalence check failed for "
-                            f"{n!r}; re-placing it under the current "
-                            f"rules")
-                return jax.device_put(v, target)
-
-            mut = {n: place(n, v) for n, v in mut.items()}
-            const_st = {n: place(n, v) for n, v in const_st.items()}
-            rng = scope._get(RNG_VAR)
-            if rng is None:
-                rng = jax.random.PRNGKey(_global_seed[0])
-            if not _is_sharded(rng):
-                rng = jax.device_put(rng, repl)
-            with mesh:
+            with _span("exe.state"):
+                mut = {n: scope._get(n) for n in mutated}
+                const_st = {n: scope._get(n) for n in const}
+                for n, v in list(mut.items()) + list(const_st.items()):
+                    if v is None:
+                        raise RuntimeError(
+                            f"Variable {n!r} used before "
+                            f"initialization -- run the startup "
+                            f"program first")
+                mut = {n: place(n, v) for n, v in mut.items()}
+                const_st = {n: place(n, v)
+                            for n, v in const_st.items()}
+                rng = scope._get(RNG_VAR)
+                if rng is None:
+                    rng = jax.random.PRNGKey(_global_seed[0])
+                if not _is_sharded(rng):
+                    rng = jax.device_put(rng, repl)
+            with _span("exe.feed"):
+                # feeds sharded over dp (params above: by the rules)
+                sharded_feeds = {
+                    n: jax.device_put(v, batched)
+                    for n, v in feed_arrays.items()}
+            with _span("exe.call"), mesh:
                 new_state, fetches, rng_out = jitted(
                     mut, const_st, sharded_feeds, rng)
-            scope._set(RNG_VAR, rng_out)
-            for n, v in new_state.items():
-                scope._set(n, v)
-            if return_numpy:
-                return [np.asarray(v) for v in fetches]
-            return list(fetches)
+            return Executor._store_and_fetch(
+                scope, new_state, rng_out, fetches, fetch_names,
+                return_numpy)
 
         return run
 

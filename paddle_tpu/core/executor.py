@@ -21,6 +21,7 @@ caching (executor.py:451 _run cache).
 from __future__ import annotations
 
 import collections
+import functools
 import itertools
 import time
 from typing import Dict, List, Optional, Sequence
@@ -29,6 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..observability.tracing import cache_tier as _cache_tier
+from ..observability.tracing import span as _span
 from .program import Program, Variable, default_main_program
 from .registry import get_op_info, is_registered, run_op, EMPTY_VAR
 from .scope import Scope, global_scope
@@ -779,6 +782,23 @@ def _record_compile_event(kind, program, tier, t0, fn=None):
                                     **attrs)
 
 
+def _compile_spanned(resolve):
+    """Runs an in-memory-miss resolver of the Executor (a disk
+    rehydration or a trace + compile) under an `exe.compile` span,
+    beside the global compile event: the tier and the fingerprint are
+    worked out only when a sink takes the span."""
+    @functools.wraps(resolve)
+    def spanned(exe, program, *args):
+        c0, d0 = exe.compile_count, exe.disk_load_count
+        with _span("exe.compile") as sp:
+            out = resolve(exe, program, *args)
+            if sp.recording:
+                sp.attrs["fingerprint"] = program.fingerprint()[:16]
+                sp.attrs["tier"] = _cache_tier(exe, c0, d0)
+        return out
+    return spanned
+
+
 def _cost_probe_avals(compiled, scope, feed_arrays, write_only=None):
     """Aval tuple matching the compiled fn's call signature — the
     lazy cost-analysis probe (observability/costmodel.py): shape
@@ -1012,32 +1032,34 @@ class Executor:
         if isinstance(program, CompiledProgram):
             return program._run(self, feed, fetch_list, scope, return_numpy)
         scope = scope or global_scope()
-        feed = dict(feed or {})
-        fetch_names = _to_fetch_names(fetch_list)
-        block = program.global_block
-        for name in fetch_names:
-            if not block.has_var(name) and name not in feed:
-                raise KeyError(
-                    f"fetch target {name!r} does not exist in the "
-                    f"program")
-        for name, value in feed.items():
-            _check_feed_shape(block, name, value)
+        with _span("exe.feed"):
+            feed = dict(feed or {})
+            fetch_names = _to_fetch_names(fetch_list)
+            block = program.global_block
+            for name in fetch_names:
+                if not block.has_var(name) and name not in feed:
+                    raise KeyError(
+                        f"fetch target {name!r} does not exist in the "
+                        f"program")
+            for name, value in feed.items():
+                _check_feed_shape(block, name, value)
 
-        mesh = _program_mesh(program)
-        device = self.place.device() if mesh is None else None
-        feed_arrays = {}
-        feed_specs = []
-        for name, val in feed.items():
-            arr = _coerce_feed(val, _var_np_dtype(block, name))
-            feed_specs.append((name, arr.shape, str(arr.dtype)))
-            # commit feeds to the caller's place (one explicit
-            # transfer); a mesh program's shardings place them instead
-            if device is not None and not isinstance(arr, jax.Array):
-                arr = jax.device_put(arr, device)
-            feed_arrays[name] = arr
-
-        if any(op.type == "go" for op in block.ops):
-            self._launch_go_ops(block, scope, feed_arrays)
+            mesh = _program_mesh(program)
+            device = self.place.device() if mesh is None else None
+            feed_arrays = {}
+            feed_specs = []
+            for name, val in feed.items():
+                arr = _coerce_feed(val, _var_np_dtype(block, name))
+                feed_specs.append((name, arr.shape, str(arr.dtype)))
+                # commit feeds to the caller's place (one explicit
+                # transfer); a mesh program's shardings place them
+                # instead
+                if device is not None \
+                        and not isinstance(arr, jax.Array):
+                    arr = jax.device_put(arr, device)
+                feed_arrays[name] = arr
+            if any(op.type == "go" for op in block.ops):
+                self._launch_go_ops(block, scope, feed_arrays)
 
         from .. import amp
         from ..flags import FLAGS
@@ -1076,31 +1098,51 @@ class Executor:
                 out = [np.asarray(v) for v in out]
             return out
 
-        key = self._block_cache_key(program, feed_specs, fetch_names)
-        compiled = self._cache.get(key) if use_program_cache else None
-        if compiled is None:
-            compiled = self._resolve_block(
-                program, block, tuple(sorted(feed_specs)), fetch_names,
-                scope, feed_arrays)
-            if use_program_cache:
-                self._cache[key] = compiled
-        else:
-            self.cache_hit_count += 1
+        with _span("exe.lookup"):
+            key = self._block_cache_key(program, feed_specs,
+                                        fetch_names)
+            compiled = self._cache.get(key) if use_program_cache \
+                else None
+            if compiled is None:
+                compiled = self._resolve_block(
+                    program, block, tuple(sorted(feed_specs)),
+                    fetch_names, scope, feed_arrays)
+                if use_program_cache:
+                    self._cache[key] = compiled
+            else:
+                self.cache_hit_count += 1
 
-        mut = self._scope_state(scope, compiled.state_in, device, mesh)
-        const_st = self._scope_state(scope, compiled.const_in, device,
-                                     mesh)
-        rng = self._scope_rng(scope, program, mesh)
-        new_state, fetches, rng_out = compiled.fn(
-            mut, const_st, feed_arrays, rng)
-        if FLAGS.check_nan_inf:
-            _check_nan_inf(new_state, fetches, fetch_names)
-        scope._set(RNG_VAR, rng_out)
-        for n, v in new_state.items():
-            scope._set(n, v)
-        if return_numpy:
+        with _span("exe.state"):
+            mut = self._scope_state(scope, compiled.state_in, device,
+                                    mesh)
+            const_st = self._scope_state(scope, compiled.const_in,
+                                         device, mesh)
+            rng = self._scope_rng(scope, program, mesh)
+        with _span("exe.call"):
+            new_state, fetches, rng_out = compiled.fn(
+                mut, const_st, feed_arrays, rng)
+        return self._store_and_fetch(scope, new_state, rng_out,
+                                     fetches, fetch_names, return_numpy)
+
+    @staticmethod
+    def _store_and_fetch(scope, new_state, rng_out, fetches,
+                         fetch_names, return_numpy):
+        """The end of every dispatch: the new state and the advanced
+        key go back to the scope (`exe.store`), and with
+        `return_numpy` the host then blocks on the device for the
+        fetches (`exe.fetch`)."""
+        from ..flags import FLAGS
+
+        with _span("exe.store"):
+            if FLAGS.check_nan_inf:
+                _check_nan_inf(new_state, fetches, fetch_names)
+            scope._set(RNG_VAR, rng_out)
+            for n, v in new_state.items():
+                scope._set(n, v)
+        if not return_numpy:
+            return list(fetches)
+        with _span("exe.fetch"):
             return [np.asarray(v) for v in fetches]
-        return list(fetches)
 
     # ------------------------------------------------------------------
     def _scope_state(self, scope, names, device, mesh=None):
@@ -1226,8 +1268,49 @@ class Executor:
         mesh = _program_mesh(program)
         device = self.place.device() if mesh is None else None
 
+        with _span("exe.feed"):
+            feed_arrays, feed_specs = self._stage_scan_feeds(
+                block, feed, feeds_seq, device)
+
+        with _span("exe.lookup"):
+            key = self._scan_cache_key(program, feed_specs,
+                                       fetch_names, steps,
+                                       feeds_seq is not None)
+            compiled = self._cache.get(key) if use_program_cache \
+                else None
+            if compiled is None:
+                compiled = self._resolve_scan(
+                    program, block, tuple(sorted(feed_specs)),
+                    fetch_names, scope, steps, feeds_seq is not None,
+                    feed_arrays, device)
+                if use_program_cache:
+                    self._cache[key] = compiled
+            else:
+                self.cache_hit_count += 1
+
+        with _span("exe.state"):
+            carry = self._scope_state(scope, compiled.state_in, device,
+                                      mesh)
+            const_st = self._scope_state(scope, compiled.const_in,
+                                         device, mesh)
+            for n, spec in compiled.write_only_specs.items():
+                # zeros placeholder: step 1 overwrites it; the carry
+                # just needs a step-invariant structure
+                carry[n] = jnp.zeros(spec.shape, spec.dtype)
+            rng = self._scope_rng(scope, program, mesh)
+        with _span("exe.call"):
+            fin_state, ys, rng_out = compiled.fn(
+                carry, const_st, feed_arrays, rng)
+        return self._store_and_fetch(scope, fin_state, rng_out, ys,
+                                     fetch_names, return_numpy)
+
+    @staticmethod
+    def _stage_scan_feeds(block, feed, feeds_seq, device):
+        """(feed arrays, PER-STEP feed specs: what each scan body
+        sees) of a run_steps call: K batches stacked and staged in
+        one transfer, or the one shared batch."""
         feed_arrays = {}
-        feed_specs = []  # PER-STEP specs (what each scan body sees)
+        feed_specs = []
         if feeds_seq is not None:
             for name in sorted(feeds_seq[0]):
                 dt = _var_np_dtype(block, name)
@@ -1252,42 +1335,7 @@ class Executor:
                 if device is not None and not isinstance(arr, jax.Array):
                     arr = jax.device_put(arr, device)
                 feed_arrays[name] = arr
-
-        from .. import amp
-        from ..flags import FLAGS
-
-        key = self._scan_cache_key(program, feed_specs, fetch_names,
-                                   steps, feeds_seq is not None)
-        compiled = self._cache.get(key) if use_program_cache else None
-        if compiled is None:
-            compiled = self._resolve_scan(
-                program, block, tuple(sorted(feed_specs)), fetch_names,
-                scope, steps, feeds_seq is not None, feed_arrays,
-                device)
-            if use_program_cache:
-                self._cache[key] = compiled
-        else:
-            self.cache_hit_count += 1
-
-        carry = self._scope_state(scope, compiled.state_in, device,
-                                  mesh)
-        const_st = self._scope_state(scope, compiled.const_in, device,
-                                     mesh)
-        for n, spec in compiled.write_only_specs.items():
-            # zeros placeholder: step 1 overwrites it; the carry just
-            # needs a step-invariant structure
-            carry[n] = jnp.zeros(spec.shape, spec.dtype)
-        rng = self._scope_rng(scope, program, mesh)
-        fin_state, ys, rng_out = compiled.fn(
-            carry, const_st, feed_arrays, rng)
-        if FLAGS.check_nan_inf:
-            _check_nan_inf(fin_state, ys, fetch_names)
-        scope._set(RNG_VAR, rng_out)
-        for n, v in fin_state.items():
-            scope._set(n, v)
-        if return_numpy:
-            return [np.asarray(v) for v in ys]
-        return list(ys)
+        return feed_arrays, feed_specs
 
     def _warn_scan_fallback(self, program, reason):
         """Named-reason visibility: fallbacks are correct but slower;
@@ -1461,6 +1509,7 @@ class Executor:
         parts.update(version_token())
         return dcache, canonical_digest(parts)
 
+    @_compile_spanned
     def _resolve_block(self, program, block, feed_specs, fetch_names,
                        scope, feed_arrays):
         """In-memory-miss path for run(): rehydrate a serialized
@@ -1501,6 +1550,7 @@ class Executor:
                              program=program)
         return compiled
 
+    @_compile_spanned
     def _resolve_scan(self, program, block, feed_specs, fetch_names,
                       scope, steps, stacked, feed_arrays, device):
         """run_steps analogue of _resolve_block — the K-specialized
@@ -1982,18 +2032,19 @@ class PreparedProgram:
         re-validation."""
         exe = self.exe
         from .. import amp
-        from ..flags import FLAGS
 
-        if (self.program._version != self._pversion
-                or amp.state_token() != self._amp_tok
-                or _parallel_scope_token() != self._ptok):
-            self._bind()  # Pass.apply / AMP toggle / scope change:
-            # re-resolve instead of serving a stale executable
-        else:
-            # observability parity with Executor.run: a prepared call
-            # served from the bound executable is a cache hit (the
-            # serving stats/tests count hits per request)
-            exe.cache_hit_count += 1
+        with _span("exe.lookup"):
+            if (self.program._version != self._pversion
+                    or amp.state_token() != self._amp_tok
+                    or _parallel_scope_token() != self._ptok):
+                self._bind()  # Pass.apply / AMP toggle / scope
+                # change: re-resolve instead of serving a stale
+                # executable
+            else:
+                # observability parity with Executor.run: a prepared
+                # call served from the bound executable is a cache hit
+                # (the serving stats/tests count hits per request)
+                exe.cache_hit_count += 1
         if self._fallback_reason is not None:
             exe.last_run_steps_fallback = self._fallback_reason
             return exe._run_steps_fallback(
@@ -2004,46 +2055,45 @@ class PreparedProgram:
             exe.last_run_steps_fallback = None
         c = self._compiled
         scope, device = self.scope, self._device
-        feed = feed or {}
-        if set(feed) != set(c.feed_names):
-            unknown = sorted(set(feed) - set(c.feed_names))
-            missing = sorted(set(c.feed_names) - set(feed))
-            raise ValueError(
-                f"prepared program binds feeds "
-                f"{sorted(c.feed_names)}; got unknown={unknown} "
-                f"missing={missing}")
-        feed_arrays = {}
-        for name in c.feed_names:
-            arr = _coerce_feed(feed[name], self._np_dtypes[name])
-            want_shape, want_dt = self._check_specs[name]
-            got_dt = str(jax.dtypes.canonicalize_dtype(arr.dtype))
-            if tuple(arr.shape) != want_shape or got_dt != want_dt:
+        with _span("exe.feed"):
+            feed = feed or {}
+            if set(feed) != set(c.feed_names):
+                unknown = sorted(set(feed) - set(c.feed_names))
+                missing = sorted(set(c.feed_names) - set(feed))
                 raise ValueError(
-                    f"prepared program was bound for feed {name!r} "
-                    f"spec {want_shape}/{want_dt} but got "
-                    f"{tuple(arr.shape)}/{got_dt}; prepare() again "
-                    f"for new shapes (or use Executor.run)")
-            if device is not None and not isinstance(arr, jax.Array):
-                arr = jax.device_put(arr, device)
-            feed_arrays[name] = arr
+                    f"prepared program binds feeds "
+                    f"{sorted(c.feed_names)}; got unknown={unknown} "
+                    f"missing={missing}")
+            feed_arrays = {}
+            for name in c.feed_names:
+                arr = _coerce_feed(feed[name], self._np_dtypes[name])
+                want_shape, want_dt = self._check_specs[name]
+                got_dt = str(jax.dtypes.canonicalize_dtype(arr.dtype))
+                if tuple(arr.shape) != want_shape or got_dt != want_dt:
+                    raise ValueError(
+                        f"prepared program was bound for feed "
+                        f"{name!r} spec {want_shape}/{want_dt} but got "
+                        f"{tuple(arr.shape)}/{got_dt}; prepare() again "
+                        f"for new shapes (or use Executor.run)")
+                if device is not None \
+                        and not isinstance(arr, jax.Array):
+                    arr = jax.device_put(arr, device)
+                feed_arrays[name] = arr
 
-        mut = exe._scope_state(scope, c.state_in, device, self._mesh)
-        const_st = exe._scope_state(scope, c.const_in, device,
-                                    self._mesh)
-        rng = exe._scope_rng(scope, self.program, self._mesh)
-        if isinstance(c, _CompiledScan):
-            for n, spec in c.write_only_specs.items():
-                mut[n] = jnp.zeros(spec.shape, spec.dtype)
-        new_state, out, rng_out = c.fn(mut, const_st, feed_arrays,
-                                       rng)
-        if FLAGS.check_nan_inf:
-            _check_nan_inf(new_state, out, c.fetch_names)
-        scope._set(RNG_VAR, rng_out)
-        for n, v in new_state.items():
-            scope._set(n, v)
-        if return_numpy:
-            return [np.asarray(v) for v in out]
-        return list(out)
+        with _span("exe.state"):
+            mut = exe._scope_state(scope, c.state_in, device,
+                                   self._mesh)
+            const_st = exe._scope_state(scope, c.const_in, device,
+                                        self._mesh)
+            rng = exe._scope_rng(scope, self.program, self._mesh)
+            if isinstance(c, _CompiledScan):
+                for n, spec in c.write_only_specs.items():
+                    mut[n] = jnp.zeros(spec.shape, spec.dtype)
+        with _span("exe.call"):
+            new_state, out, rng_out = c.fn(mut, const_st, feed_arrays,
+                                           rng)
+        return exe._store_and_fetch(scope, new_state, rng_out, out,
+                                    c.fetch_names, return_numpy)
 
 
 class PreparedCache:

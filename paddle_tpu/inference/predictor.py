@@ -203,9 +203,7 @@ class AnalysisPredictor(PaddlePredictor):
         # the predictor-backed server path shares execute_span with
         # serving.ProgramRunner.run_batch, so the cache-tier
         # attribution convention has exactly one copy
-        with obs_tracing.execute_span(self._exe,
-                                      program=self._program,
-                                      feed=feed):
+        with obs_tracing.execute_span(self._exe):
             prepared = self._prepared.lookup(feed)
             if prepared is not None:
                 outs = prepared.run(feed, return_numpy=False)

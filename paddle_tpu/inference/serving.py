@@ -420,9 +420,7 @@ class ProgramRunner:
         # tracing is off: one thread-local lookup per span); the
         # execute_span helper stamps the cache-tier attr from counter
         # deltas, covering a prepared-lookup-miss compile
-        with obs_tracing.execute_span(self.executor,
-                                      program=self.program,
-                                      feed=feed):
+        with obs_tracing.execute_span(self.executor):
             # None = program not preparable (go ops / CompiledProgram
             # / native build): per-call Executor.run path
             prepared = self._prepared.lookup(feed)
@@ -1373,6 +1371,8 @@ class ContinuousGenerationServer:
         self._latencies = Histogram("paddle_tpu_request_latency_ms")
         self._ttft = Histogram("paddle_tpu_request_ttft_ms")
         self._per_token = Histogram("paddle_tpu_per_token_ms")
+        # arrival to admission, observed at every admission
+        self._queue_wait = Histogram("paddle_tpu_request_queue_wait_ms")
         self._t_first_arrival = None
         self._t_last_done = None
         self._t_start = time.monotonic()
@@ -1516,6 +1516,15 @@ class ContinuousGenerationServer:
           never extend the session history. Distinct generations need
           a sampled bundle — greedy branches are identical.
         """
+        with obs_tracing.span("slotpool.submit"):
+            return self._enqueue(src_ids, seed, session_id,
+                                 extend_tokens, n_best, stream,
+                                 stream_cb, deadline_ms)
+
+    def _enqueue(self, src_ids, seed, session_id, extend_tokens, n_best,
+                 stream, stream_cb, deadline_ms):
+        """submit()'s body, on the caller's thread: validation, the
+        request objects, the push under the scheduler lock."""
         arr = np.asarray(src_ids)
         if arr.ndim == 1:
             arr = arr[None]
@@ -1819,13 +1828,32 @@ class ContinuousGenerationServer:
             if self._lanes[slot] is None:
                 req = self._pop_next()
                 self._lanes[slot] = req
-                req.t_admit = t_admit
+                self._note_admit_locked(req, slot, t_admit,
+                                        self._admit_tier)
                 if req.trace is not None:
                     req.trace.add_span("slotpool.queue",
                                        req.t_arrival, t_admit,
                                        slot=slot)
                 admits.append((slot, req))
         return admits
+
+    # the admission flavor of the cycle being planned: the dense
+    # server has one (every admission prefills), the paged server
+    # decides one per cycle in its _plan_admissions_locked
+    _admit_tier = "miss"
+
+    def _note_admit_locked(self, req, slot, t_admit, tier):
+        """One admission: stamp the request, count its wait in the
+        queue, and mark it in a profile as a short `slotpool.admit`
+        span inside `slotpool.plan` (how a per-request number reaches
+        the device trace). Called under _cv."""
+        req.t_admit = t_admit
+        wait_s = t_admit - req.t_arrival
+        self._queue_wait.observe(wait_s * 1e3)
+        with obs_tracing.span("slotpool.admit") as sp:
+            if sp.recording:
+                sp.attrs.update(wait_us=round(wait_s * 1e6),
+                                tier=tier, slot=slot)
 
     def _plan_burst_locked(self, admits, drain, failures):
         """Burst policy for the coming cycle: (n_steps, min_active,
@@ -1926,30 +1954,46 @@ class ContinuousGenerationServer:
             if req.trace is not None and req.trace.owner == "server":
                 req.trace.finish(status="error", error=repr(exc))
 
+    def _idle_locked(self) -> bool:
+        """Nothing queued, no live lane, no background job: the
+        scheduler may sleep. Called under _cv."""
+        return self._running and not self._queue \
+            and all(l is None for l in self._lanes) \
+            and not self._has_background_work_locked()
+
     def _loop(self):
         while True:
             failures = []
             with self._cv:
-                while self._running and not self._queue \
-                        and all(l is None for l in self._lanes) \
-                        and not self._has_background_work_locked():
-                    self._cv.wait()
+                if self._idle_locked():
+                    with obs_tracing.span("slotpool.wait"):
+                        while self._idle_locked():
+                            self._cv.wait()
                 if not self._running:
                     return
-                cancels = self._shed_cancelled_locked(
-                    time.monotonic())
-                admits = self._plan_admissions_locked(failures)
-                drain = not self._queue
-                # empty queue: let the burst run — the device loop
-                # exits by itself once the pool drains
-                n_steps, min_active, run = self._plan_burst_locked(
-                    admits, drain, failures)
+                with obs_tracing.span("slotpool.plan") as sp:
+                    cancels = self._shed_cancelled_locked(
+                        time.monotonic())
+                    admits = self._plan_admissions_locked(failures)
+                    drain = not self._queue
+                    # empty queue: let the burst run — the device
+                    # loop exits by itself once the pool drains
+                    n_steps, min_active, run = \
+                        self._plan_burst_locked(admits, drain,
+                                                failures)
+                    if sp.recording:
+                        sp.attrs.update(
+                            admits=len(admits),
+                            queue_depth=len(self._queue),
+                            tier=self._admit_tier or "none")
                 if run:
                     self._busy = True  # drain() waits on this
             # failing futures fires their done-callbacks synchronously
             # — never under the scheduler lock
-            self._finalize_cancelled(cancels)
-            self._fail_requests(failures)
+            if cancels or failures:
+                with obs_tracing.span("slotpool.deliver"):
+                    self._finalize_cancelled(cancels)
+                    self._fail_requests(failures)
             if run:
                 try:
                     self._cycle(admits, n_steps, min_active)
@@ -1964,34 +2008,36 @@ class ContinuousGenerationServer:
         until n_steps ran or the live-lane count drops to min_active
         — admission cost scales with buckets, not requests, and the
         dispatch overhead amortizes over the whole burst."""
-        feed = {"n_steps": np.array([n_steps], np.int64),
-                "min_active": np.array([max(0, min_active)],
-                                       np.int64)}
-        key = 0
-        background = False
-        if admits:
-            key, extra = self._admission_feed(admits)
-            feed.update(extra)
-        else:
-            bg = self._background_feed()
-            if bg is not None:
-                key, extra = bg
+        with obs_tracing.span("slotpool.feed"):
+            feed = {"n_steps": np.array([n_steps], np.int64),
+                    "min_active": np.array([max(0, min_active)],
+                                           np.int64)}
+            key = 0
+            background = False
+            if admits:
+                key, extra = self._admission_feed(admits)
                 feed.update(extra)
-                background = True
-        k_used = self._spec_k
-        if self._spec_ctl is not None and not background:
-            # adaptive-k: the controller picks the rung the whole
-            # pool runs this dispatch; non-default rungs route
-            # through the pre-built ("k", kv, base) serve variant.
-            # Background (chunked-prefill) dispatches keep the
-            # default body — their phase programs have no k ladder.
-            for slot, _req in admits:
-                self._spec_ctl.reset_lane(slot)
-            kv = int(self._spec_ctl.choose())
-            if kv != self._spec_k and ("k", kv, key) in self._serves:
-                key = ("k", kv, key)
-                k_used = kv
-        self._pre_dispatch()
+            else:
+                bg = self._background_feed()
+                if bg is not None:
+                    key, extra = bg
+                    feed.update(extra)
+                    background = True
+            k_used = self._spec_k
+            if self._spec_ctl is not None and not background:
+                # adaptive-k: the controller picks the rung the whole
+                # pool runs this dispatch; non-default rungs route
+                # through the pre-built ("k", kv, base) serve variant.
+                # Background (chunked-prefill) dispatches keep the
+                # default body — their phase programs have no k ladder.
+                for slot, _req in admits:
+                    self._spec_ctl.reset_lane(slot)
+                kv = int(self._spec_ctl.choose())
+                if kv != self._spec_k \
+                        and ("k", kv, key) in self._serves:
+                    key = ("k", kv, key)
+                    k_used = kv
+            self._pre_dispatch()
         try:
             c0 = self.executor.compile_count
             d0 = self.executor.disk_load_count
@@ -2005,13 +2051,14 @@ class ContinuousGenerationServer:
                     outs = self._serves[key].run(feed,
                                                  return_numpy=True)
                     wall_s = time.monotonic() - t_run0
-                    sp.attrs["cache"] = _cache_tier(
-                        self.executor, c0, d0)
+                    if sp.recording:
+                        sp.attrs["cache"] = _cache_tier(
+                            self.executor, c0, d0)
                     if self._devtel.active:
                         # device-side burst interior: delta the
                         # telemetry counters and annotate the span
                         # the flight recorder retains (exit reason,
-                        # ticks, occupancy, expected-vs-actual)
+                        # ticks, occupancy)
                         self._absorb_devtel(key, outs, wall_s, sp)
                     if self._spec_names:
                         # delta the device-side spec counters for
@@ -2025,12 +2072,14 @@ class ContinuousGenerationServer:
                         if d["proposed"] > 0:
                             self._acc_hist.observe(
                                 d["accepted"] / d["proposed"])
-                            # per lane-tick (see stats()): a LOW
-                            # value explains a slow burst — the
-                            # draft stopped agreeing with the target
-                            sp.attrs["mean_accepted_len"] = round(
-                                d["emitted"] * k_used
-                                / d["proposed"], 3)
+                            if sp.recording:
+                                # per lane-tick (see stats()): a LOW
+                                # value explains a slow burst — the
+                                # draft stopped agreeing with the
+                                # target
+                                sp.attrs["mean_accepted_len"] = round(
+                                    d["emitted"] * k_used
+                                    / d["proposed"], 3)
                         if self._spec_ctl is not None:
                             sp.attrs["spec_k"] = k_used
         except BaseException as e:
@@ -2055,7 +2104,33 @@ class ContinuousGenerationServer:
                 if r.trace is not None and r.trace.owner == "server":
                     r.trace.finish(status="error", error=repr(e))
             return
-        self._post_dispatch(outs)
+        with obs_tracing.span("slotpool.retire"):
+            self._post_dispatch(outs)
+            retired, cancels, stream_out = self._retire_lanes(outs)
+        # ordered delivery, OUTSIDE the lock: every streamed token of
+        # a burst lands before its finish marker, which lands before
+        # the whole-response future resolves
+        with obs_tracing.span("slotpool.deliver"):
+            for req, first_seq, chunk in stream_out:
+                self._deliver_stream(req, first_seq, chunk)
+            for req, toks, fin in retired:
+                self._finish_stream(req, fin)
+                try:
+                    req.reply.set_result(toks)
+                except futures.InvalidStateError:
+                    pass
+                if req.trace is not None \
+                        and req.trace.owner == "server":
+                    req.trace.finish()
+            self._finalize_cancelled(cancels)
+
+    def _retire_lanes(self, outs):
+        """The sweep over the lanes after a dispatch, under the lock:
+        retire what finished, tear down what was cancelled or ran
+        past its deadline, slice every stream's fresh tokens. Returns
+        (retired [(req, row, finish reason)], cancels [(req, reason)],
+        stream chunks [(req, first seq, tokens)]) for the caller to
+        deliver outside the lock."""
         tok_buf, step, active, _fin = outs[:4]  # [4:] = spec counters
         done_t = time.monotonic()
         retired = []
@@ -2132,20 +2207,7 @@ class ContinuousGenerationServer:
                     req.emitted = hi
             self._n_ticks += 1
             self._occ_sum += occupied / self.n_slots
-        # ordered delivery, OUTSIDE the lock: every streamed token of
-        # a burst lands before its finish marker, which lands before
-        # the whole-response future resolves
-        for req, first_seq, chunk in stream_out:
-            self._deliver_stream(req, first_seq, chunk)
-        for req, toks, fin in retired:
-            self._finish_stream(req, fin)
-            try:
-                req.reply.set_result(toks)
-            except futures.InvalidStateError:
-                pass
-            if req.trace is not None and req.trace.owner == "server":
-                req.trace.finish()
-        self._finalize_cancelled(cancels)
+        return retired, cancels, stream_out
 
     def _absorb_spec_counters(self, outs) -> dict:
         """Read the fetched device-side speculative counters
@@ -2207,11 +2269,11 @@ class ContinuousGenerationServer:
 
     def _absorb_devtel(self, key, outs, wall_s, sp):
         """Delta the fetched device-telemetry counters for this
-        dispatch and annotate the burst span with the interior the
-        flight recorder retains: ticks actually run, the exit reason,
-        the occupancy integral, and — once the cost model has a
-        calibrated rate — expected-vs-actual tick time (model cost vs
-        this host's throttle weather)."""
+        dispatch, annotate the burst span with the interior the
+        flight recorder retains (ticks actually run, the exit reason,
+        the occupancy integral) and, at metrics level, feed the
+        burst's work and wall time to the cost model's rate
+        calibration (`expected_service_ms`)."""
         off = 4 + len(self._spec_names) + len(self._lane_names)
         with self._cv:
             deltas = self._devtel.absorb(
@@ -2219,32 +2281,22 @@ class ContinuousGenerationServer:
         ticks = deltas.get("tel_ticks", 0)
         if not ticks:
             return
-        sp.attrs["ticks"] = ticks
-        sp.attrs["occupancy_integral"] = deltas.get("tel_occupancy",
-                                                    0)
-        reason = obs_devtel.DeviceTelemetry.exit_reason(deltas)
-        if reason is not None:
-            sp.attrs["exit_reason"] = reason
+        if sp.recording:
+            sp.attrs["ticks"] = ticks
+            sp.attrs["occupancy_integral"] = deltas.get(
+                "tel_occupancy", 0)
+            reason = obs_devtel.DeviceTelemetry.exit_reason(deltas)
+            if reason is not None:
+                sp.attrs["exit_reason"] = reason
         if not obs_metrics.metrics_on():
             return
         # per-tick cost comes from the KEY-0 serve snapshot — the
         # pure-burst program (no admission body), so its one-While-
         # body cost IS one tick. A per-key snapshot would fold the
         # admission prologue (A full encoder prefills on a miss key)
-        # into every tick of the burst, overstating expected_ms and
-        # inflating the calibrated rate by ticks x prologue.
-        snap = self._cost_snapshot(0) or {}
-        flops = snap.get("flops")
-        actual_tick_ms = wall_s * 1e3 / ticks
-        # expectation from the rate calibrated BEFORE this dispatch:
-        # this burst's own sample must not vouch for itself
-        expected = obs_costmodel.expected_ms(flops)
-        sp.attrs["actual_tick_ms"] = round(actual_tick_ms, 3)
-        if expected is not None:
-            sp.attrs["expected_tick_ms"] = round(expected, 3)
-            if expected > 0:
-                sp.attrs["tick_time_ratio"] = round(
-                    actual_tick_ms / expected, 3)
+        # into every tick of the burst, inflating the calibrated rate
+        # by ticks x prologue.
+        flops = (self._cost_snapshot(0) or {}).get("flops")
         if flops:
             # the While body is costed once, so tick-flops x ticks is
             # the burst's work — but an admission dispatch's wall
@@ -2358,6 +2410,7 @@ class ContinuousGenerationServer:
                 "warmed_compiles": self._warmed_compiles,
                 "latency_ms": _pct_dict(self._latencies),
                 "ttft_ms": _pct_dict(self._ttft),
+                "queue_wait_ms": _pct_dict(self._queue_wait),
                 "per_token_ms": _pct_dict(self._per_token),
                 "tokens": self._n_tokens,
                 "retired_per_s": (
@@ -2386,6 +2439,7 @@ class ContinuousGenerationServer:
                 self._occ_sum = 0.0
                 self._latencies.clear()
                 self._ttft.clear()
+                self._queue_wait.clear()
                 self._per_token.clear()
                 self._acc_hist.clear()
                 self._spec_base = dict(self._spec_tot)
@@ -2423,6 +2477,8 @@ class ContinuousGenerationServer:
                 ("paddle_tpu_request_latency_ms", lab,
                  self._latencies),
                 ("paddle_tpu_request_ttft_ms", lab, self._ttft),
+                ("paddle_tpu_request_queue_wait_ms", lab,
+                 self._queue_wait),
                 ("paddle_tpu_per_token_ms", lab, self._per_token),
             ]
             if self._spec_k > 0:
@@ -3034,19 +3090,18 @@ class PagedContinuousGenerationServer(ContinuousGenerationServer):
                 self._tab[slot, h] = blk
                 self._pref[slot] = entry
                 self._lanes[slot] = req
-                req.t_admit = t_admit
+                self._note_admit_locked(req, slot, t_admit, flavor)
                 req.radix = (hist, h * self._bs, P)
                 self._radix_admits += 1
                 self._hit_depth.observe(float(h))
                 if req.trace is not None:
                     # blocks_reused is the radix win (KV pages NOT
-                    # recomputed); blocks_cowed is 0 by construction
-                    # on this path — serving admissions never write
-                    # a shared block (COW lives in PagedBeamDecoder)
+                    # recomputed); none is ever copied on this path —
+                    # serving admissions never write a shared block
+                    # (COW lives in PagedBeamDecoder)
                     req.trace.add_span(
                         "slotpool.queue", req.t_arrival, t_admit,
-                        slot=slot, prefix="radix", blocks_reused=h,
-                        blocks_cowed=0)
+                        slot=slot, prefix="radix", blocks_reused=h)
                 admits.append((slot, req))
                 continue
             blk = self._alloc_block_locked()
@@ -3074,7 +3129,7 @@ class PagedContinuousGenerationServer(ContinuousGenerationServer):
             self._tab[slot, 0] = blk
             self._pref[slot] = entry
             self._lanes[slot] = req
-            req.t_admit = t_admit
+            self._note_admit_locked(req, slot, t_admit, flavor)
             if req.trace is not None:
                 # the prefix tier is what explains slow (miss: full
                 # encoder prefill) vs fast (hit: lane reset only)

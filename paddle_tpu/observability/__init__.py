@@ -18,7 +18,9 @@ Three sub-modules, one gate:
   the same instruments.
 * ``tracing`` — ``Trace``/``Span`` per request, propagated
   Router.submit -> tenant queue -> batcher -> Executor dispatch ->
-  execute -> readback; compile events annotated with
+  execute -> readback; every ``span`` also lands in a running JAX
+  profile as ``paddle_tpu:<name>``, on the device trace's clock, at
+  any flag level; compile events annotated with
   ``Program.fingerprint()``, cache tier, ``memory_analysis()`` sizes;
   ``dump_trace(path)`` merges host RecordEvent spans (profiler.py,
   absorbed) and request trees into ONE chrome-trace JSON.
@@ -33,8 +35,9 @@ Three sub-modules, one gate:
   admission+burst dispatch used to be.
 * ``costmodel`` — static per-executable ``cost_analysis()`` /
   ``memory_analysis()`` snapshots keyed on ``Program.fingerprint()``
-  plus a median achieved-rate calibration, so retained slow bursts
-  carry expected-vs-actual tick time (model cost vs host throttle).
+  plus a median achieved-rate calibration: the expected time of a
+  tick, from which ``expected_service_ms()`` makes the estimate the
+  router sheds by.
 
 Gate: ``FLAGS_observability = off | metrics | trace`` (flags.py),
 read per call so ``set_flags`` flips the level mid-process. The layer

@@ -31,18 +31,16 @@ static side:
   XLA's HLO cost analysis counts a While body ONCE (trip counts are
   dynamic), so a decode-burst serve program's ``flops`` is its
   per-TICK cost plus the admission prologue — exactly the unit the
-  expected-vs-actual annotation needs.
+  per-tick service-time estimate needs.
 
 * **Calibration** — ``observe(flops, seconds)`` feeds achieved-rate
   samples (the serving layer reports ``snapshot-flops x ticks`` per
   burst dispatch); ``flops_per_s()`` is the MEDIAN of a bounded
   window, which the 3x throttle swings cannot drag around the way a
-  mean would. ``expected_ms(flops)`` divides by it: the flight
-  recorder's retained bursts then carry expected-vs-actual tick time,
-  separating model cost (the flops moved) from host weather (the
-  rate achieved) — and giving the PERF.md real-chip arithmetic a
-  machine-readable basis (on the v5e the same snapshot divides by
-  the chip's envelope instead of a calibrated CPU rate).
+  mean would. ``expected_ms(flops)`` divides by it: the expected
+  time of one tick, which the servers' ``expected_service_ms()``
+  multiplies by a request's ticks for the router's deadline
+  shedding.
 
 Everything here is per-call gated by the callers on
 ``FLAGS_observability`` (lookups at ``off`` return the cached dict or

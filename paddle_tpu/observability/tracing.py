@@ -33,9 +33,18 @@ request spend its 300 ms" — so this module adds:
   span trees, and global compile events (tools/timeline.py:273
   parity, extended with the request axis).
 
-Everything here is always compiled in and gated per call on
-``FLAGS_observability=trace``; at ``off``/``metrics`` no span is
-recorded and ``dump_trace`` writes an empty trace.
+* **The device trace's clock** — ``span`` also enters a
+  ``jax.profiler.TraceAnnotation`` named ``paddle_tpu:<name>`` with
+  its attributes as metadata. The profiler is that sink's own gate:
+  while a JAX profile is being taken, at any flag level, every program
+  span lands in the ``.xplane.pb`` on the host thread that ran it, on
+  the clock of the device's operations (benchmark/chip/program_spans.py
+  reads them back).
+
+Everything here is always compiled in. ``FLAGS_observability``
+decides the in-process sinks: at ``off``/``metrics`` no request trace
+is opened, no span is kept in the process and ``dump_trace`` writes an
+empty trace.
 """
 from __future__ import annotations
 
@@ -47,18 +56,19 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from .metrics import metrics_on, trace_on
 
 __all__ = ["Span", "Trace", "Tracer", "TRACER", "trace_on",
            "metrics_on", "start_request", "current_request_trace",
            "request_context", "ambient", "ambient_traces", "span",
-           "record_global_event", "dump_trace", "reset"]
+           "record_global_event", "dump_trace", "reset", "SPAN_PREFIX"]
 
-# perf_counter_ns (profiler.py's clock) -> monotonic seconds offset so
-# host events and request spans share one timebase in the dump. On
-# Linux both read CLOCK_MONOTONIC, but the offset is measured rather
-# than assumed.
-_PC_NS_MINUS_MONO_NS = time.perf_counter_ns() - time.monotonic_ns()
+# what every program span is called in a profiler trace
+SPAN_PREFIX = "paddle_tpu:"
+# True while a JAX profile is being taken: the gate of that sink
+_profiling = TraceAnnotation.is_enabled
 
 
 class Span:
@@ -269,21 +279,43 @@ def cache_tier(exe, compiles_before, disk_loads_before) -> str:
 
 class span:
     """Context manager recording one (name, t0, t1) span into every
-    ambient trace. Near-free when tracing is off or no batch is
-    ambient (one attr lookup)."""
+    ambient trace and, while a JAX profile is being taken, into the
+    profiler's trace as ``paddle_tpu:<name>`` (reference
+    platform/profiler.h:81 RecordEvent, which feeds the reference's
+    device tracer the same way). With neither sink it costs one
+    thread-local lookup and one call into the profiler's gate.
 
-    __slots__ = ("name", "attrs", "_traces", "_t0")
+    Attributes that cost anything to compute are set late, and only
+    for a sink: ``if sp.recording: sp.attrs[...] = ...`` inside the
+    block."""
+
+    __slots__ = ("name", "attrs", "_traces", "_t0", "_ann")
 
     def __init__(self, name: str, **attrs):
         self.name = name
         self.attrs = attrs
 
+    @property
+    def recording(self) -> bool:
+        """True inside the block when some sink takes the span."""
+        return bool(self._traces) or self._ann is not None
+
     def __enter__(self):
-        self._traces = ambient_traces()
-        self._t0 = time.monotonic() if self._traces else 0.0
+        traces = self._traces = getattr(_tls, "batch_traces", None)
+        if traces:
+            self._t0 = time.monotonic()
+        if _profiling():
+            self._ann = TraceAnnotation(SPAN_PREFIX + self.name)
+            self._ann.__enter__()
+        else:
+            self._ann = None
         return self
 
     def __exit__(self, *exc):
+        if self._ann is not None:
+            if self.attrs:
+                self._ann.set_metadata(**self.attrs)
+            self._ann.__exit__(*exc)
         if self._traces:
             t1 = time.monotonic()
             for tr in self._traces:
@@ -298,24 +330,13 @@ class execute_span(span):
     serving.ProgramRunner.run_batch and
     predictor.AnalysisPredictor._run_feed. Open it BEFORE the
     prepared-cache lookup: a lookup miss is itself the compile the
-    tier must attribute.
+    tier must attribute."""
 
-    With ``program=`` the span also carries the executable cost
-    model's expected flops/bytes (observability/costmodel.py) — the
-    static side a retained slow request is compared against. The
-    lookup is a dict read after the program's first resolution; only
-    that first trace-level lookup may resolve a lazy probe (one extra
-    trace, never a compile). ``feed=`` (the dispatch's feed dict)
-    selects the spec-exact snapshot, so a program compiled at several
-    bucket shapes annotates each request with ITS bucket's cost."""
+    __slots__ = ("_exe", "_c0", "_d0")
 
-    __slots__ = ("_exe", "_c0", "_d0", "_program", "_feed")
-
-    def __init__(self, exe, program=None, feed=None, **attrs):
+    def __init__(self, exe, **attrs):
         super().__init__("execute", **attrs)
         self._exe = exe
-        self._program = program
-        self._feed = feed
 
     def __enter__(self):
         self._c0 = self._exe.compile_count
@@ -323,15 +344,9 @@ class execute_span(span):
         return super().__enter__()
 
     def __exit__(self, *exc):
-        self.attrs["cache"] = cache_tier(self._exe, self._c0, self._d0)
-        if self._traces and self._program is not None:
-            from . import costmodel
-
-            snap = costmodel.lookup(self._program,
-                                    feed_arrays=self._feed) or {}
-            for field in ("flops", "bytes_accessed"):
-                if snap.get(field) is not None:
-                    self.attrs[field] = snap[field]
+        if self.recording:
+            self.attrs["cache"] = cache_tier(self._exe, self._c0,
+                                             self._d0)
         return super().__exit__(*exc)
 
 
@@ -377,10 +392,9 @@ def dump_trace(path: str) -> dict:
     from .. import profiler
 
     for name, t0_ns, t1_ns, tid in profiler._snapshot_events():
-        mono_us = (t0_ns - _PC_NS_MINUS_MONO_NS) / 1e3
         events.append({
             "name": name, "ph": "X", "pid": 0, "tid": tid,
-            "ts": mono_us, "dur": (t1_ns - t0_ns) / 1e3,
+            "ts": t0_ns / 1e3, "dur": (t1_ns - t0_ns) / 1e3,
             "cat": "host"})
 
     with TRACER._lock:

@@ -38,6 +38,8 @@ __all__ = ["profiler", "start_profiler", "stop_profiler", "reset_profiler",
 
 import collections
 
+from jax.profiler import TraceAnnotation
+
 # Bounded like every other observability sink (TRACER rings,
 # FlightRecorder): with FLAGS_observability=trace capture runs outside
 # any start/stop_profiler window, so an unbounded list would grow with
@@ -65,24 +67,35 @@ def _capture_on() -> bool:
 
 
 class RecordEvent:
-    """RAII host annotation (reference platform/profiler.h:81)."""
+    """RAII host annotation (reference platform/profiler.h:81). While
+    a JAX profile is being taken the event also lands in the
+    profiler's trace under its own name, beside the program's
+    `paddle_tpu:` spans (observability/tracing.py). Stamps
+    `time.monotonic_ns`, the clock of the request spans."""
 
     def __init__(self, name):
         self.name = name
         self._t0 = None
         self._record = False
+        self._ann = None
 
     def __enter__(self):
         self._record = _capture_on()
-        self._t0 = time.perf_counter_ns()
+        if TraceAnnotation.is_enabled():
+            self._ann = TraceAnnotation(self.name)
+            self._ann.__enter__()
+        self._t0 = time.monotonic_ns()
         return self
 
     def __exit__(self, *a):
         if self._record:
-            t1 = time.perf_counter_ns()
+            t1 = time.monotonic_ns()
             with _lock:
                 _events.append((self.name, self._t0, t1,
                                 threading.get_ident()))
+        if self._ann is not None:
+            self._ann.__exit__(*a)
+            self._ann = None
         return False
 
 

@@ -19,8 +19,8 @@ What must hold:
   compile story;
 * **flight-recorder interior** — a forced slow burst (lone request
   outgrowing a tiny paged pool) retains an incident whose span tree
-  carries exit reason, tick count, occupancy integral, and the
-  expected-vs-actual cost annotation (observability/costmodel.py);
+  carries exit reason, tick count and occupancy integral, and whose
+  bursts calibrated the cost model (observability/costmodel.py);
 * **cost model units** — snapshot capture, lazy probe gating on
   FLAGS_observability, and the median-rate calibration arithmetic.
 
@@ -390,8 +390,7 @@ class TestFlightRecorderInterior:
         """The forced slow burst: a lone no-EOS request outgrows a
         2-block pool — pause-free growth, then hard exhaustion. The
         retained incident's span tree must explain the burst
-        interior: exit reason, tick count, occupancy integral, and
-        the expected-vs-actual cost annotation."""
+        interior: exit reason, tick count, occupancy integral."""
         import paddle_tpu.observability as observability
 
         obs("trace")
@@ -421,16 +420,11 @@ class TestFlightRecorderInterior:
             assert a["ticks"] == 2
             assert a["occupancy_integral"] == 2  # lone lane
             assert a["exit_reason"] == "n_steps"
-            assert a["actual_tick_ms"] > 0
-        # calibration exists from burst 2 on (burst 1 admits; its
-        # sample is prologue-corrected via the key snapshot):
-        # expected-vs-actual
-        annotated = [b for b in bursts
-                     if "expected_tick_ms" in b["attrs"]]
-        assert annotated and len(annotated) >= len(bursts) - 1
-        for b in annotated:
-            assert b["attrs"]["expected_tick_ms"] > 0
-            assert b["attrs"]["tick_time_ratio"] > 0
+        # every burst fed the cost model's rate calibration (burst 1
+        # admits; its sample is prologue-corrected via the key
+        # snapshot): what expected_service_ms() sheds by
+        assert obs_costmodel.flops_per_s() > 0
+        assert srv.expected_service_ms() > 0
         # the queue span carries the prefix tier (r13) so the whole
         # slow-admission story reads from one timeline
         queue = [s for s in inc["spans"]

@@ -23,6 +23,12 @@ Covers the tentpole and its satellites:
   coarse timelines with O(1) cost.
 * **Schema stability** — golden key-sets for ``stats_json()`` and the
   metric families in ``expose()`` so dashboards don't silently break.
+* **The device trace's clock** (PR 25) — one JAX profile on the CPU
+  backend around a tiny paged server and a few ``Executor.run`` steps
+  at ``FLAGS_observability=off``: every program span is in the
+  ``.xplane.pb`` under the ``paddle_tpu:`` prefix, nested as the code
+  nests; with no profiler and the flag off a span reaches no sink;
+  the queue-wait counter exists at every level.
 """
 import bisect
 import json
@@ -32,8 +38,9 @@ import numpy as np
 import pytest
 
 import paddle_tpu as fluid
-from paddle_tpu import observability as obs
-from paddle_tpu import profiler
+from paddle_tpu import layers, observability as obs
+from paddle_tpu import profiler, unique_name
+from paddle_tpu.core.scope import Scope
 from paddle_tpu.flags import FLAGS
 from paddle_tpu.inference.runtime import ServingRuntime, zoo
 from paddle_tpu.inference.serving import _pct, _pct_dict
@@ -659,3 +666,226 @@ class TestSchemaStability:
                     if ln and not ln.startswith("#")}
         missing = self.EXPOSE_FAMILIES - families
         assert not missing, f"expose() lost families: {sorted(missing)}"
+
+
+# --------------------------------------------------------------------
+# the program's spans in a JAX profile (PR 25)
+# --------------------------------------------------------------------
+EXE_SPANS = {"exe.feed", "exe.lookup", "exe.compile", "exe.state",
+             "exe.call", "exe.store", "exe.fetch"}
+SLOTPOOL_SPANS = {"slotpool.wait", "slotpool.plan", "slotpool.admit",
+                  "slotpool.feed", "slotpool.dispatch",
+                  "slotpool.retire", "slotpool.deliver",
+                  "slotpool.submit"}
+CONTINUOUS_STATS_KEYS = {
+    "requests", "completed", "queue_depth", "slots", "slot_occupancy",
+    "ticks", "steps_per_tick", "uptime_s", "window_s", "compile_count",
+    "cache_hit_count", "disk_load_count", "cache_evict_count",
+    "warmed_compiles", "latency_ms", "ttft_ms", "queue_wait_ms",
+    "per_token_ms", "tokens", "retired_per_s", "cancelled",
+    "deadline_expired", "device_telemetry"}
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    """(dense bundle, paged bundle, executor, scope, prompts): the
+    2017 transformer at toy widths with initialized, untrained
+    weights and an end token the argmax cannot emit, so every request
+    decodes to the end of its buffer."""
+    from paddle_tpu.models import transformer as T
+    from paddle_tpu.models.decode_engine import CacheConfig
+
+    v, s_len = 16, 8
+    scope = Scope()
+    model = dict(seq_len=s_len, d_model=32, n_heads=2, n_layers=1,
+                 d_inner=64, vocab=v)
+    with unique_name.guard():
+        _, startup, _ = T.build_program(
+            with_optimizer=False, dropout_rate=0.0, **model)
+    exe = fluid.Executor(fluid.TPUPlace(0))
+    exe.run(startup, scope=scope)
+    kw = dict(n_slots=2, admit_buckets=[1, 2], max_out_len=8,
+              start_id=2, end_id=v + 7, **model)
+    with unique_name.guard():
+        dense = T.build_decode_step_program(
+            state_prefix="@obsd/", **kw)
+    with unique_name.guard():
+        paged = T.build_decode_step_program(
+            state_prefix="@obsp/", cache=CacheConfig(
+                layout="paged", block_size=4, n_blocks=8,
+                n_prompt_entries=4), **kw)
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(3, v, (1, s_len)).astype(np.int64)
+               for _ in range(3)]
+    return dense, paged, exe, scope, prompts
+
+
+@pytest.fixture(scope="module")
+def profiled(tiny):
+    """{"spans": [[name, thread, start_ns, duration_ns, metadata]],
+    "user_events": names outside the prefix} of ONE JAX profile (CPU
+    backend) taken at FLAGS_observability=off around: a fresh
+    program's first Executor.run (a compile) and two more steps, a
+    user's `profiler.record_event`, and a paged server that starts,
+    serves three streamed requests and closes."""
+    import glob
+    import tempfile
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from benchmark.chip import program_spans
+    from paddle_tpu.inference.serving import \
+        PagedContinuousGenerationServer
+
+    saved = FLAGS._values["observability"]
+    FLAGS._values["observability"] = "off"
+    _dense, paged, exe, scope, prompts = tiny
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = layers.data("x", shape=[4], dtype="float32")
+        y = layers.fc(x, 8)
+    step_scope = Scope()
+    exe.run(startup, scope=step_scope)
+    # bound (and its programs compiled) before the profile starts
+    srv = PagedContinuousGenerationServer(
+        paged, executor=exe, scope=scope, start=False,
+        steps_per_tick=2, drain_steps=2)
+    srv.start()
+    srv.submit(prompts[0]).result(120.0)
+    srv.close()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0     # the host's TraceMe events only
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d, profiler_options=options)
+        try:
+            for _ in range(3):
+                exe.run(main, feed={"x": np.ones((2, 4), "float32")},
+                        fetch_list=[y], scope=step_scope)
+            with profiler.record_event("user_scope"):
+                pass
+            srv.start()
+            time.sleep(0.05)    # the scheduler finds nothing: it waits
+            replies = [srv.submit(p, stream=True) for p in prompts]
+            streamed = [[tok for _seq, tok in r] for r in replies]
+            srv.close()
+        finally:
+            jax.profiler.stop_trace()
+            FLAGS._values["observability"] = saved
+        path, = glob.glob(d + "/plugins/profile/*/*.xplane.pb")
+        loaded = program_spans.load(path)
+        user = {ev.name for plane in ProfileData.from_file(path).planes
+                for line in plane.lines for ev in line.events
+                if ev.name == "user_scope"}
+    assert all(len(toks) == 7 for toks in streamed), streamed
+    return {"spans": loaded["spans"], "user_events": user}
+
+
+def _inside(inner, outer):
+    return inner[1] == outer[1] and outer[2] <= inner[2] \
+        and inner[2] + inner[3] <= outer[2] + outer[3]
+
+
+class TestProfilerClock:
+    @pytest.mark.parametrize("name", sorted(EXE_SPANS | SLOTPOOL_SPANS))
+    def test_every_span_is_in_the_profile(self, profiled, name):
+        assert any(ev[0] == name for ev in profiled["spans"]), sorted(
+            {ev[0] for ev in profiled["spans"]})
+
+    def test_record_event_lands_unprefixed(self, profiled):
+        assert profiled["user_events"] == {"user_scope"}
+
+    def test_executor_spans_nest_inside_the_dispatch(self, profiled):
+        spans = profiled["spans"]
+        dispatches = [ev for ev in spans if ev[0] == "slotpool.dispatch"]
+        assert dispatches
+        assert len({ev[1] for ev in dispatches}) == 1   # one thread
+        for d in dispatches:
+            inner = {ev[0] for ev in spans
+                     if ev[0].startswith("exe.") and _inside(ev, d)}
+            assert EXE_SPANS - {"exe.compile"} <= inner, (d, inner)
+        # and in the order the code runs them
+        first = dispatches[0]
+        order = [ev[0] for ev in sorted(
+            (ev for ev in spans if ev[0].startswith("exe.")
+             and _inside(ev, first)), key=lambda ev: ev[2])]
+        assert order == ["exe.lookup", "exe.feed", "exe.state",
+                         "exe.call", "exe.store", "exe.fetch"]
+
+    def test_compile_is_inside_the_first_lookup_only(self, profiled):
+        spans = profiled["spans"]
+        compiles = [ev for ev in spans if ev[0] == "exe.compile"]
+        assert len(compiles) == 1       # the fresh program's first run
+        assert compiles[0][4]["tier"] == "cold"
+        assert len(compiles[0][4]["fingerprint"]) == 16
+        lookups = [ev for ev in spans if ev[0] == "exe.lookup"
+                   and _inside(compiles[0], ev)]
+        assert len(lookups) == 1
+
+    def test_admissions_carry_their_wait_and_tier(self, profiled):
+        spans = profiled["spans"]
+        admits = [ev for ev in spans if ev[0] == "slotpool.admit"]
+        assert len(admits) == 3
+        plans = [ev for ev in spans if ev[0] == "slotpool.plan"]
+        for a in admits:
+            assert a[4]["wait_us"] >= 0
+            assert a[4]["tier"] in ("miss", "hit", "radix")
+            assert a[4]["slot"] in (0, 1)
+            assert any(_inside(a, p) for p in plans)
+        assert {"admits", "queue_depth", "tier"} <= set(plans[0][4])
+        # two lanes, three requests: the third waited for a lane
+        assert sorted(a[4]["wait_us"] for a in admits)[-1] > 0
+
+    def test_submit_runs_on_the_callers_thread(self, profiled):
+        spans = profiled["spans"]
+        sched = {ev[1] for ev in spans if ev[0] == "slotpool.dispatch"}
+        submits = {ev[1] for ev in spans if ev[0] == "slotpool.submit"}
+        assert submits and not submits & sched
+
+
+class TestNoSinkNoRecord:
+    def test_off_and_no_profiler_reaches_no_sink(self):
+        """The flag off and no profile running: a span is entered and
+        left and nothing anywhere keeps it."""
+        _set_level("off")
+        from paddle_tpu.observability import tracing
+
+        with tracing.span("slotpool.plan", admits=1) as sp:
+            assert not sp.recording
+            with tracing.execute_span(fluid.Executor(
+                    fluid.TPUPlace(0))) as ex:
+                assert not ex.recording
+        with profiler.record_event("user_scope"):
+            pass
+        assert "cache" not in ex.attrs   # nobody to compute it for
+        assert not obs.TRACER.completed and not obs.TRACER.global_events
+        assert not profiler._snapshot_events()
+        assert obs.RECORDER.recorded_total == 0
+
+    @pytest.mark.parametrize("level", ["off", "metrics", "trace"])
+    @pytest.mark.parametrize("kind", ["dense", "paged"])
+    def test_queue_wait_counter_at_every_level(self, tiny, level,
+                                               kind):
+        from paddle_tpu.inference.serving import (
+            ContinuousGenerationServer, PagedContinuousGenerationServer)
+
+        _set_level(level)
+        dense, paged, exe, scope, prompts = tiny
+        cls, bundle = {
+            "dense": (ContinuousGenerationServer, dense),
+            "paged": (PagedContinuousGenerationServer, paged)}[kind]
+        with cls(bundle, executor=exe, scope=scope) as srv:
+            for r in [srv.submit(p) for p in prompts]:
+                r.result(120.0)
+            st = srv.stats()
+            assert set(st) - {"block_pool"} == CONTINUOUS_STATS_KEYS
+            assert ("block_pool" in st) == (kind == "paged")
+            assert set(st["queue_wait_ms"]) == {"p50", "p99"}
+            assert st["queue_wait_ms"]["p50"] is not None
+            assert srv._queue_wait.count == 3
+            families = {name for name, _lab, _v
+                        in srv._metrics_samples()}
+            assert "paddle_tpu_request_queue_wait_ms" in families
+            assert srv.stats(reset=True)["queue_wait_ms"]["p50"] \
+                is not None
+            assert srv.stats()["queue_wait_ms"]["p50"] is None
