@@ -454,7 +454,7 @@ register_pool_index_source(
     "COW copy destinations: blocks freshly popped from "
     "HostBlockPool.alloc (refcount==1, exclusive) for this copy "
     "dispatch, pairwise-distinct and disjoint from every live "
-    "chain; padded rows aim at -1 (the trash row) under gate 0 — "
+    "chain; padded rows aim at -1 (out of range: dropped) under gate 0 — "
     "the exclusive write window a lane diverges into when it "
     "branches off a shared prefix",
     TS_EXCLUSIVE, assumption="HostBlockPool.cow-fresh-exclusive")

@@ -1842,8 +1842,8 @@ def check_pool_access_provenance(program: Program):
     * **in-bounds** — when the indexed axis extent is static, the
       index fact's bound must fit it (ERROR when the bound provably
       exceeds the axis; WARNING when no bound is derivable for a
-      READ — the write kernel clamps out-of-range rows into its
-      trash row, reads have no such net)."""
+      READ — the write kernel drops out-of-range rows, reads have
+      no such net)."""
     from . import absint
 
     facts = absint.analyze(program)

@@ -26,7 +26,7 @@ negative constants mint no fact at all, subtraction drops the bound
 unless the subtrahend is provably >= 0 and marks its own result
 possibly-negative, and products/selections require non-negative
 operands before certifying a bound. (Negative indices at a WRITE are
-clamped into the trash row by the masked_pool_write kernel,
+dropped by the masked_pool_write kernel,
 ops/paged_ops.py; reads have no such net — which is why the read
 bound proof must not lie.)
 One-hot semantics: ``onehot`` promises at most one nonzero in each

@@ -16,10 +16,12 @@ public signatures and delegate here:
 
 * **Cache layout** — ``CacheConfig`` selects ``dense`` (per-lane
   ``[rows, H, maxT, Dh]`` KV buffers, the r10 design) or ``paged``
-  (a SHARED block pool ``[n_blocks, block_size, H, Dh]`` per layer +
-  per-lane int32 block-table rows; cross-attention K/V lives in a
-  refcounted prompt-entry pool so identical prompts prefill ONCE).
-  Reads go through one-hot/gather composition of existing ops; writes
+  (a SHARED block pool ``[n_blocks * block_size, H * Dh]`` per layer,
+  one row a cache cell, + per-lane int32 block-table rows;
+  cross-attention K/V lives in a refcounted prompt-entry pool so
+  identical prompts prefill ONCE). Pools are stored in the shape they
+  are addressed in: reads gather rows (cells, prompt entries) along
+  axis 0, so no tick copies or relayouts a pool or the table; writes
   go through the ``masked_pool_write`` registry op whose disjoint
   one-hot masks are the lane-exclusivity contract checker PTA110
   enforces (shared-pool aliasing is the silent cross-request KV
@@ -78,7 +80,7 @@ class ShardingConfig:
 
     * self/cross KV state sharded along HEADS — dense per-lane
       buffers ``[R, H/tp, T, Dh]``, the paged pools
-      ``[n_blocks, block_size, H/tp, Dh]`` — so per-device KV bytes
+      ``[n_blocks * block_size, (H/tp) * Dh]`` — so per-device KV bytes
       drop ~1/tp. Block tables / prompt refs stay host-owned and
       REPLICATED: ``HostBlockPool`` and the PTA190/191 ownership
       proofs are untouched.
@@ -383,6 +385,21 @@ def heads_of(x, t, n_heads, head_dim):
         perm=[0, 2, 1, 3])
 
 
+def cells_of_blocks(blocks, block_size, offs):
+    """[..., n] float32 block ids -> flat int32 ids of their cache
+    cells, block-major: block b owns the pool rows b*BS..b*BS+BS-1
+    (``offs`` is the float32 ``arange(BS)``). The paged step bodies
+    (a lane's block table -> its maxT cells) and the COW program share
+    it, so 'which rows are a block' is written once."""
+    nd = len(blocks.shape)
+    base = layers.expand(
+        layers.unsqueeze(layers.scale(blocks, scale=float(block_size)),
+                         [nd]), [1] * nd + [block_size])
+    return layers.cast(
+        layers.reshape(layers.elementwise_add(base, offs, axis=nd),
+                       [-1]), "int32")
+
+
 # ---------------------------------------------------------------------------
 # Cache-access objects: the ONE place layout differences live.
 # ---------------------------------------------------------------------------
@@ -412,29 +429,36 @@ class _DenseLaneCache:
 class _PagedLaneCache:
     """Per-layer paged self-KV access: writes go through the
     ``masked_pool_write`` registry op (disjoint one-hot scatter into
-    the SHARED ``[NB, BS, H, Dh]`` pool at each lane's block-table
-    address, gated by the active mask so idle/dustbin lanes never
+    the SHARED ``[NB * BS, H * Dh]`` pool at each lane's block-table
+    cell, gated by the active mask so idle/dustbin lanes never
     touch the pool — the PTA110 exclusivity contract), reads gather
     every lane's maxT cache positions back into the dense
     ``[R, H, maxT, Dh]`` view the shared attention math expects.
     Positions a lane has not written yet hold stale pool bytes; the
     caller's validity bias (-1e9 past position t) masks them exactly
     like the dense layout masks its zeros, so the softmax sees
-    identical values — token-exact parity with dense."""
+    identical values — token-exact parity with dense.
+
+    ``q`` > 1 is the multi-position verify write: the q positions of
+    every lane flatten to R*q masked_pool_write rows (distinct cells —
+    positions within a lane are distinct, lanes own disjoint blocks
+    via the host table: the PTA110 exclusivity story is unchanged),
+    with ``write_idx``/``gate`` [R*q] and the gate extended by
+    per-position validity so positions past the buffer end never
+    touch the pool."""
 
     def __init__(self, pool_k, pool_v, write_idx, gate, flat_pos,
-                 rows, n_heads, head_dim, maxT, n_cells):
+                 rows, n_heads, head_dim, maxT, q=1):
         self.pool_k, self.pool_v = pool_k, pool_v
         self.write_idx, self.gate = write_idx, gate
         self.flat_pos = flat_pos          # [rows*maxT] int32 cell addrs
-        self.rows, self.maxT = rows, maxT
+        self.rows, self.maxT, self.q = rows, maxT, q
         self.n_heads, self.head_dim = n_heads, head_dim
-        self.n_cells = n_cells            # NB * BS
 
     def _view(self, pool):
-        flat = layers.reshape(pool, [self.n_cells,
-                                     self.n_heads * self.head_dim])
-        rows_kv = layers.gather(flat, self.flat_pos)
+        # the pool is gathered as it is stored, one row a cell: only
+        # the gathered rows are ever reshaped, never the pool
+        rows_kv = layers.gather(pool, self.flat_pos)
         return layers.transpose(
             layers.reshape(rows_kv, [self.rows, self.maxT,
                                      self.n_heads, self.head_dim]),
@@ -442,10 +466,14 @@ class _PagedLaneCache:
 
     def update(self, kh, vh):
         for pool, new in ((self.pool_k, kh), (self.pool_v, vh)):
+            # [R,H,q,Dh] -> [R*q, H*Dh] write rows
             layers.masked_pool_write(
                 pool,
-                layers.reshape(new, [0, self.n_heads, self.head_dim]),
-                self.write_idx, gate=self.gate, leading_dims=2,
+                layers.reshape(
+                    layers.transpose(new, perm=[0, 2, 1, 3]),
+                    [self.rows * self.q,
+                     self.n_heads * self.head_dim]),
+                self.write_idx, gate=self.gate, leading_dims=1,
                 exclusive_via="block_table")
         return self._view(self.pool_k), self._view(self.pool_v)
 
@@ -473,45 +501,6 @@ class _DenseSpanCache:
                 layers.elementwise_mul(var, self.keep_mask), scat),
                 output=var)
         return self.kc, self.vc
-
-
-class _PagedSpanCache:
-    """Per-layer paged self-KV access for the multi-position verify
-    write: the q positions of every lane flatten to R*q
-    masked_pool_write rows (distinct cells — positions within a lane
-    are distinct, lanes own disjoint blocks via the host table: the
-    PTA110 exclusivity story is unchanged), with the gate extended by
-    per-position validity so positions past the buffer end never
-    touch the pool. Reads reuse the full dense-view gather."""
-
-    def __init__(self, pool_k, pool_v, write_idx_rq, gate_rq,
-                 flat_pos, rows, q, n_heads, head_dim, maxT, n_cells):
-        self.pool_k, self.pool_v = pool_k, pool_v
-        self.write_idx, self.gate = write_idx_rq, gate_rq  # [R*q]
-        self.flat_pos = flat_pos
-        self.rows, self.q, self.maxT = rows, q, maxT
-        self.n_heads, self.head_dim = n_heads, head_dim
-        self.n_cells = n_cells
-
-    def _view(self, pool):
-        flat = layers.reshape(pool, [self.n_cells,
-                                     self.n_heads * self.head_dim])
-        rows_kv = layers.gather(flat, self.flat_pos)
-        return layers.transpose(
-            layers.reshape(rows_kv, [self.rows, self.maxT,
-                                     self.n_heads, self.head_dim]),
-            perm=[0, 2, 1, 3])
-
-    def update(self, kh, vh):
-        for pool, new in ((self.pool_k, kh), (self.pool_v, vh)):
-            # [R,H,q,Dh] -> [R*q, H, Dh] write rows
-            rows_new = layers.reshape(
-                layers.transpose(new, perm=[0, 2, 1, 3]),
-                [self.rows * self.q, self.n_heads, self.head_dim])
-            layers.masked_pool_write(
-                pool, rows_new, self.write_idx, gate=self.gate,
-                leading_dims=2, exclusive_via="block_table")
-        return self._view(self.pool_k), self._view(self.pool_v)
 
 
 @dataclass(frozen=True)
@@ -666,9 +655,9 @@ def cached_decoder_step(x, caches, cross_kv, att_bias, d_model,
     IDENTICAL math; their token-for-token parity is structural, not
     coincidental).
 
-    ``caches``: per-layer cache-access objects (_DenseLaneCache /
-    _PagedLaneCache for q=1; the span caches for the speculative
-    q=k+1 verify step) owning the self-attention KV write+view.
+    ``caches``: per-layer cache-access objects (_DenseLaneCache for
+    q=1, _DenseSpanCache for the speculative q=k+1 verify step,
+    _PagedLaneCache for either) owning the self-attention KV write+view.
     ``cross_kv``: per-layer (ck, cv) [R,H,S,Dh] encoder projections
     (vars for dense, pool gathers for paged). ``att_bias`` is the
     0/-1e9 validity bias added to the [R,H,q,maxT] attention scores —
@@ -1185,7 +1174,7 @@ class DecodeStepBundle:
     def cow_feed_spec(self) -> List[tuple]:
         """Feed signature of the COW block-copy program (``cow``):
         per-row (src shared block, dst fresh exclusive block, gate).
-        Padded rows feed gate 0 and dst -1 (the trash row)."""
+        Padded rows feed gate 0 and dst -1 (out of range: dropped)."""
         rows = self.n_slots + 1
         return [("cow_src", (rows,), "int64"),
                 ("cow_dst", (rows,), "int64"),
@@ -1329,13 +1318,14 @@ def _slot_state_specs(prefix, rows, maxT, seq_len, n_heads,
         # host-side beam branching reads it instead of re-running the
         # decoder outside the bundle
         specs[f"{prefix}probe_probs"] = ((rows, vocab), "float32")
+    # one row a cache cell, heads x head_dim flat on the minor axis: the
+    # shape the tick gathers and scatters in, and one the TPU neither
+    # pads (a minor axis of Dh=64 fills half of its 128 lanes) nor
+    # relayouts on the way into and out of a dispatch
+    cells = (cache.n_blocks * cache.block_size, n_heads * head_dim)
     for li in range(n_layers):
-        specs[f"{prefix}self_k{li}{POOL_MARK}"] = (
-            (cache.n_blocks, cache.block_size, n_heads, head_dim),
-            "float32")
-        specs[f"{prefix}self_v{li}{POOL_MARK}"] = (
-            (cache.n_blocks, cache.block_size, n_heads, head_dim),
-            "float32")
+        specs[f"{prefix}self_k{li}{POOL_MARK}"] = (cells, "float32")
+        specs[f"{prefix}self_v{li}{POOL_MARK}"] = (cells, "float32")
         # +1: the dustbin entry padded admission rows scatter into
         specs[f"{prefix}cross_k{li}{POOL_MARK}"] = (
             (E + 1, n_heads, seq_len, head_dim), "float32")
@@ -1411,9 +1401,10 @@ def interleave_qkv_params(scope, n_layers: int, n_heads: int,
 def _tp_state_placements(state_prefix, n_layers, cache, sharding
                          ) -> Dict[str, dict]:
     """{slot-state name -> {dim: axis}}: KV sharded along heads (dim
-    1 of the dense ``[R, H, T, Dh]`` lane buffers; dim 2 of the paged
-    ``[NB, BS, H, Dh]`` self pool, dim 1 of the ``[E+1, H, S, Dh]``
-    cross pool). Tables/masks/counters/draft state stay replicated —
+    1 of the dense ``[R, H, T, Dh]`` lane buffers, of the paged
+    ``[NB*BS, H*Dh]`` self pool, whose heads are the major part of
+    that axis, and of the ``[E+1, H, S, Dh]`` cross pool).
+    Tables/masks/counters/draft state stay replicated —
     block tables in particular remain host-owned replicated int32, so
     the ownership story (PTA190/191) is untouched."""
     ax = sharding.axis
@@ -1425,8 +1416,8 @@ def _tp_state_placements(state_prefix, n_layers, cache, sharding
             out[f"{state_prefix}cross_k{li}"] = {1: ax}
             out[f"{state_prefix}cross_v{li}"] = {1: ax}
         else:
-            out[f"{state_prefix}self_k{li}{POOL_MARK}"] = {2: ax}
-            out[f"{state_prefix}self_v{li}{POOL_MARK}"] = {2: ax}
+            out[f"{state_prefix}self_k{li}{POOL_MARK}"] = {1: ax}
+            out[f"{state_prefix}self_v{li}{POOL_MARK}"] = {1: ax}
             out[f"{state_prefix}cross_k{li}{POOL_MARK}"] = {1: ax}
             out[f"{state_prefix}cross_v{li}{POOL_MARK}"] = {1: ax}
     return out
@@ -2267,14 +2258,8 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
             # view) and for the current write position (scatter)
             tabf = layers.cast(sv[f"{state_prefix}block_tab"],
                                "float32")                  # [R,NP]
-            base = layers.expand(
-                layers.unsqueeze(layers.scale(tabf, scale=float(BS)),
-                                 [2]),
-                [1, 1, BS])                                # [R,NP,BS]
             offs = layers.assign(np.arange(BS, dtype="float32"))
-            flat_posf = layers.elementwise_add(base, offs, axis=2)
-            flat_pos = layers.cast(
-                layers.reshape(flat_posf, [rows * maxT]), "int32")
+            flat_pos = cells_of_blocks(tabf, BS, offs)     # [R*maxT]
             # current position's page/offset one-hots from t_mask
             t_pages = layers.reshape(t_mask, [rows, NP, BS])
             page_oh = layers.reduce_sum(t_pages, dim=2)    # [R,NP]
@@ -2295,7 +2280,7 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
                 sv[f"{state_prefix}self_k{li}{POOL_MARK}"],
                 sv[f"{state_prefix}self_v{li}{POOL_MARK}"],
                 write_idx, gate, flat_pos, rows, n_heads, head_dim,
-                maxT, NB * BS) for li in range(n_layers)]
+                maxT) for li in range(n_layers)]
             pref = sv[f"{state_prefix}prompt_ref"]
             cross_kv = []
             for li in range(n_layers):
@@ -2303,11 +2288,8 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
                 for tag in ("k", "v"):
                     pool = sv[f"{state_prefix}cross_{tag}{li}"
                               f"{POOL_MARK}"]
-                    flat = layers.reshape(
-                        pool, [E + 1, n_heads * seq_len * head_dim])
-                    got = layers.gather(flat, pref)        # [R, HSD]
-                    pair.append(layers.reshape(
-                        got, [rows, n_heads, seq_len, head_dim]))
+                    # entries along axis 0 of the stored 4-D table
+                    pair.append(layers.gather(pool, pref))  # [R,H,S,Dh]
                 cross_kv.append(tuple(pair))
         x = cached_decoder_step(x, caches, cross_kv, att_bias,
                                 d_model, n_heads, d_inner,
@@ -2661,15 +2643,8 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
         else:
             tabf = layers.cast(sv[f"{state_prefix}block_tab"],
                                "float32")                  # [R,NP]
-            base = layers.expand(
-                layers.unsqueeze(layers.scale(tabf, scale=float(BS)),
-                                 [2]),
-                [1, 1, BS])                                # [R,NP,BS]
             offs = layers.assign(np.arange(BS, dtype="float32"))
-            flat_pos = layers.cast(
-                layers.reshape(
-                    layers.elementwise_add(base, offs, axis=2),
-                    [rows * maxT]), "int32")
+            flat_pos = cells_of_blocks(tabf, BS, offs)     # [R*maxT]
             t_pages_q = layers.reshape(t_mask_q, [rows, Q, NP, BS])
             page_oh = layers.reduce_sum(t_pages_q, dim=3)  # [R,Q,NP]
             off_oh = layers.reduce_sum(t_pages_q, dim=2)   # [R,Q,BS]
@@ -2691,11 +2666,11 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
                 layers.elementwise_mul(
                     layers.reshape(layers.cast(act, "float32"),
                                    [rows, 1]), validq), [rows * Q])
-            caches = [_PagedSpanCache(
+            caches = [_PagedLaneCache(
                 sv[f"{state_prefix}self_k{li}{POOL_MARK}"],
                 sv[f"{state_prefix}self_v{li}{POOL_MARK}"],
-                write_idx, gate, flat_pos, rows, Q, n_heads,
-                head_dim, maxT, NB * BS) for li in range(n_layers)]
+                write_idx, gate, flat_pos, rows, n_heads, head_dim,
+                maxT, q=Q) for li in range(n_layers)]
             pref = sv[f"{state_prefix}prompt_ref"]
             cross_kv = []
             for li in range(n_layers):
@@ -2703,11 +2678,7 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
                 for tag in ("k", "v"):
                     pool = sv[f"{state_prefix}cross_{tag}{li}"
                               f"{POOL_MARK}"]
-                    flat = layers.reshape(
-                        pool, [E + 1, n_heads * seq_len * head_dim])
-                    got = layers.gather(flat, pref)
-                    pair.append(layers.reshape(
-                        got, [rows, n_heads, seq_len, head_dim]))
+                    pair.append(layers.gather(pool, pref))  # [R,H,S,Dh]
                 cross_kv.append(tuple(pair))
         x = cached_decoder_step(x, caches, cross_kv, bias, d_model,
                                 n_heads, d_inner, q=Q,
@@ -3161,11 +3132,13 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
     # --- COW block copy (paged only): gather the SHARED source rows
     # and masked-write them into freshly allocated EXCLUSIVE blocks —
     # the one lowering through which a lane may diverge from a shared
-    # chain (beam branching, partial-page session resume). Operating
-    # on the whole [NB, BS, H, Dh] pool along dim 0 only keeps it
-    # layout-oblivious under tp (the sharded heads axis is never
-    # reshaped or reduced). Padded rows feed gate 0 AND dst -1 (the
-    # trash row), so one fixed-shape program serves any copy count. --
+    # chain (beam branching, partial-page session resume). A block is
+    # the BS consecutive cell rows b*BS..b*BS+BS-1 of the [NB*BS, H*Dh]
+    # pool, so the copy addresses rows along dim 0 only and stays
+    # layout-oblivious under tp (the sharded minor axis is never
+    # reshaped or reduced). Padded rows feed gate 0 AND dst -1 (every
+    # cell of it negative: dropped), so one fixed-shape program serves
+    # any copy count. --
     cow_prog = None
     if paged:
         cow_prog = fluid.Program()
@@ -3186,13 +3159,20 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
             # exclusive allocations (the COW window)
             absint.mark_pool_index_source(csrc, "cow_src", bound=NB)
             absint.mark_pool_index_source(cdst, "cow_dst", bound=NB)
+            offs = layers.assign(np.arange(BS, dtype="float32"))
+            src_cells, dst_cells = (
+                cells_of_blocks(layers.cast(blocks, "float32"), BS,
+                                offs) for blocks in (csrc, cdst))
+            cell_gate = layers.reshape(
+                layers.expand(layers.unsqueeze(cgate, [1]), [1, BS]),
+                [rows * BS])
             for li in range(n_layers):
                 for tag in ("k", "v"):
                     pool = sv[f"{state_prefix}self_{tag}{li}"
                               f"{POOL_MARK}"]
-                    src_rows = layers.gather(pool, csrc)
                     layers.masked_pool_write(
-                        pool, src_rows, cdst, cgate, leading_dims=1,
+                        pool, layers.gather(pool, src_cells),
+                        dst_cells, cell_gate, leading_dims=1,
                         exclusive_via="cow_dst")
             _tel_add(sv, "tel_cow_blocks",
                      layers.reduce_sum(layers.cast(cgate, "int64"),

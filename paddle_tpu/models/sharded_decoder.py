@@ -17,7 +17,7 @@ rationale):
 
 * self/cross KV state sharded along heads (dim 1 of the dense
   ``[rows, H, maxT, Dh]`` lane buffers; the paged pools shard
-  ``[n_blocks, block_size, H/tp, Dh]``) — per-device KV bytes exactly
+  ``[n_blocks * block_size, (H/tp) * Dh]``) — per-device KV bytes exactly
   1/tp (tests/test_memory_plan.py);
 * row-parallel attention out-projections + column/row-parallel ffn
   (their psums are the PTA161-proof obligations), column-parallel

@@ -35,9 +35,12 @@ def masked_pool_write(ctx):
     allocated exclusive blocks a COW copy diverges a lane into —
     checker PTA110 requires it).
 
-    Out-of-range and gated-off rows write nothing (they scatter into
-    a trash row that is sliced away), and cells hit by a gated row
-    take EXACTLY the new value. The lowering is an indexed row
+    Out-of-range and gated-off rows write nothing (their index
+    becomes n, past the last cell, and the scatter drops it), and
+    cells hit by a gated row take EXACTLY the new value. Only the
+    ``leading_dims`` axes merge: the tail axes, which the TPU tiles,
+    keep their stored shape, so a write moves R rows and nothing the
+    size of the pool. The lowering is an indexed row
     scatter — O(R x cell) instead of the O(n_cells x R x cell)
     one-hot matmul, which MEASURED as ~3x the cost of the attention
     itself per decode tick at small head dims; the semantics are the
@@ -54,8 +57,8 @@ def masked_pool_write(ctx):
     disagrees with the proven chain, an index of unknown provenance
     (PTA190), or an index reaching a REFCOUNTED shared entry
     (PTA192 write-while-shared, the COW contract) are build-time
-    errors. The trash-row clamp covers out-of-range WRITES; reads
-    have no such net, which is why PTA190 also proves gather bounds.
+    errors. Dropping covers out-of-range WRITES; reads have no such
+    net, which is why PTA190 also proves gather bounds.
     """
     pool = ctx.input("Pool")
     new = ctx.input("New")
@@ -65,18 +68,14 @@ def masked_pool_write(ctx):
     n = 1
     for d in pool.shape[:lead]:
         n *= int(d)
-    pool_flat = pool.reshape(n, -1)
+    tail = pool.shape[lead:]
     rows = new.shape[0]
-    new_flat = new.reshape(rows, -1).astype(pool_flat.dtype)
     idx = idx.reshape(rows).astype(jnp.int32)
     keep = (idx >= 0) & (idx < n)
     if gate is not None:
         keep = keep & (gate.reshape(rows) > 0)
-    safe = jnp.where(keep, idx, n)  # n = the trash row below
-    padded = jnp.concatenate(
-        [pool_flat, jnp.zeros((1,) + pool_flat.shape[1:],
-                              pool_flat.dtype)], axis=0)
-    out = padded.at[safe].set(new_flat,
-                              unique_indices=False,
-                              indices_are_sorted=False)[:n]
+    # a negative index would wrap before mode="drop" looks at it
+    safe = jnp.where(keep, idx, n)
+    out = pool.reshape((n,) + tail).at[safe].set(
+        new.reshape((rows,) + tail).astype(pool.dtype), mode="drop")
     return out.reshape(pool.shape)

@@ -568,23 +568,53 @@ class TestHostAllocators:
         assert pc.lookup(p2) == ("hit", e2)
 
 
+def _pool_write_oracle(pool, new, idx, gate, lead):
+    """Row r lands on cell idx[r] iff its gate is on and the index is
+    in range; every other cell keeps the input pool's bits."""
+    n = int(np.prod(pool.shape[:lead]))
+    want = pool.reshape((n,) + pool.shape[lead:]).copy()
+    for r in range(len(idx)):
+        if (gate is None or gate[r]) and 0 <= idx[r] < n:
+            want[idx[r]] = new[r]
+    return want.reshape(pool.shape)
+
+
 class TestMaskedPoolWriteOp:
-    def test_numpy_oracle(self):
-        """Kernel semantics vs a numpy oracle: gated rows land, keep
-        mask preserves untouched cells, out-of-range indices drop,
-        gate-0 rows write nothing."""
+    # (index rows, gate rows or None) against a pool of n = 12 cells;
+    # -1 with gate 0 is what the COW program feeds for a padded row,
+    # and the ungated -1 is why the kernel cannot lean on mode="drop"
+    # alone (a negative index wraps before it is looked at)
+    CASES = {
+        "in_range": ([0, 7, 11, 3], [1.0, 1.0, 1.0, 1.0]),
+        "first_past_end": ([0, 12, 5, 3], [1.0, 1.0, 1.0, 1.0]),
+        "far_past_end": ([99, 7, 2 ** 31 - 1, 3], [1.0, 1.0, 1.0, 1.0]),
+        "minus_one_padded": ([4, -1, -1, 9], [1.0, 0.0, 0.0, 1.0]),
+        "minus_one_gate_on": ([-1, 7, -12, 3], [1.0, 1.0, 1.0, 1.0]),
+        "gated_off": ([0, 7, 11, 3], [1.0, 0.0, 1.0, 0.0]),
+        "all_dropped": ([12, -1, 5, 40], [1.0, 1.0, 0.0, 1.0]),
+        "no_gate_input": ([0, -1, 12, 3], None),
+    }
+
+    @pytest.mark.parametrize("lead", [1, 2])
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_numpy_oracle(self, lead, case):
+        """Kernel semantics vs a numpy oracle, bit for bit: gated
+        rows land, out-of-range and negative indices drop, gate-0 rows
+        write nothing, and every cell no row lands on is the input
+        pool's (so nothing but the addressed rows may move)."""
         from op_test import OpTest
 
         rng = np.random.RandomState(0)
-        pool = rng.randn(3, 4, 2, 5).astype(np.float32)  # lead 2 -> 12
-        new = rng.randn(4, 2, 5).astype(np.float32)
-        idx = np.array([0, 7, 99, 3], np.int32)   # 99 out of range
-        gate = np.array([1.0, 1.0, 1.0, 0.0], np.float32)
-        want = pool.reshape(12, 10).copy()
-        for r in range(4):
-            if gate[r] and 0 <= idx[r] < 12:
-                want[idx[r]] = new[r].reshape(10)
-        want = want.reshape(3, 4, 2, 5)
+        shape = {1: (12, 2, 3, 5), 2: (3, 4, 2, 5)}[lead]
+        pool = rng.randn(*shape).astype(np.float32)
+        new = rng.randn(4, *shape[lead:]).astype(np.float32)
+        idx_rows, gate_rows = self.CASES[case]
+        idx = np.array(idx_rows, np.int32)
+        gate = None if gate_rows is None \
+            else np.array(gate_rows, np.float32)
+        want = _pool_write_oracle(pool, new, idx, gate, lead)
+        if case == "all_dropped":
+            assert np.array_equal(want, pool)
 
         class T(OpTest):
             def runTest(self):
@@ -593,12 +623,200 @@ class TestMaskedPoolWriteOp:
         t = T()
         t.setUp()
         t.op_type = "masked_pool_write"
-        t.inputs = {"Pool": pool, "New": new, "Index": idx,
-                    "Gate": gate}
-        t.attrs = {"leading_dims": 2,
+        t.inputs = {"Pool": pool, "New": new, "Index": idx}
+        if gate is not None:
+            t.inputs["Gate"] = gate
+        t.attrs = {"leading_dims": lead,
                    "exclusive_via": "block_table"}
         t.outputs = {"Out": want}
-        t.check_output()
+        t.check_output(atol=0, rtol=0)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold
+    (the jitted call, the decode While's body, branches)."""
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+def _pool_sized_moves(closed, kept_tail):
+    """What a traced program does to a pool beyond moving rows.
+    `kept_tail` maps a pool's stored shape to the trailing axes every
+    reshape of it must keep (only leading axes may merge: under the
+    TPU's tiling that is a bitcast, anything else a copy of the
+    pool). A concatenate, pad or slice as large as the smallest pool
+    is the old lowering's trash row coming back."""
+    least = min(int(np.prod(s)) for s in kept_tail)
+    found = []
+    for e in _eqns(closed.jaxpr):
+        name = e.primitive.name
+        if name in ("concatenate", "pad", "slice", "dynamic_slice"):
+            for o in e.outvars:
+                if int(np.prod(o.aval.shape)) >= least:
+                    found.append((name, tuple(o.aval.shape)))
+        elif name == "reshape":
+            src = tuple(e.invars[0].aval.shape)
+            dst = tuple(e.outvars[0].aval.shape)
+            tail = kept_tail.get(src)
+            if tail is not None and dst[len(dst) - len(tail):] != tail:
+                found.append((name, src, dst))
+    return found
+
+
+class TestPoolAddressedAsStored:
+    """The guard that keeps the flatten and the trash row from coming
+    back (ISSUE 26): on the TPU they were nine of a tick's ten largest
+    operations, each a copy of a whole pool or of the prompt table."""
+
+    def test_masked_pool_write_lowering_moves_rows_only(self):
+        import jax
+
+        from paddle_tpu.core.registry import get_op_info
+
+        kernel = get_op_info("masked_pool_write").kernel
+
+        class Ctx:
+            def __init__(self, inputs, attrs):
+                self._inputs, self._attrs = inputs, attrs
+
+            def input(self, slot):
+                return self._inputs.get(slot)
+
+            def attr(self, name, default=None):
+                return self._attrs.get(name, default)
+
+        for lead, shape in ((1, (7, 2, 8, 64)), (1, (256, 128)),
+                            (2, (32, 8, 2, 64))):
+            def lowered(pool, new, idx, gate):
+                return kernel(Ctx({"Pool": pool, "New": new,
+                                   "Index": idx, "Gate": gate},
+                                  {"leading_dims": lead}))
+
+            closed = jax.make_jaxpr(lowered)(
+                np.zeros(shape, np.float32),
+                np.zeros((5,) + shape[lead:], np.float32),
+                np.zeros((5,), np.int32), np.ones((5,), np.float32))
+            assert _pool_sized_moves(
+                closed, {shape: shape[lead:]}) == [], (lead, shape)
+            assert any(e.primitive.name == "scatter"
+                       for e in _eqns(closed.jaxpr))
+
+    def test_tick_program_at_rehearsal_sizes(self, trained):
+        """The serve cell's tick-only program (BENCHMARK.json's
+        transformer-big-serve at its rehearsal sizes), traced to a
+        jaxpr: no pool-sized concatenate, pad or slice, and no reshape
+        of a pool that touches its trailing axes."""
+        import json
+        import os
+
+        import jax
+
+        from paddle_tpu import unique_name
+        from paddle_tpu.core.executor import RNG_VAR
+        from paddle_tpu.core.scope import Scope
+        from paddle_tpu.models import transformer as T
+        from paddle_tpu.models.decode_engine import POOL_MARK
+
+        with open(os.path.join(
+                os.path.dirname(__file__), "..", "benchmark", "chip",
+                "configs", "transformer-big-serve.json")) as f:
+            cfg = json.load(f)
+        c = {**cfg["sizes"], **cfg["rehearsal"]}
+        model = dict(seq_len=c["seq_len"], d_model=c["d_model"],
+                     n_heads=c["n_heads"], n_layers=c["n_layers"],
+                     d_inner=c["d_inner"], vocab=c["vocab"])
+        scope, exe = Scope(), trained["exe"]
+        with unique_name.guard():
+            _, startup, _ = T.build_program(
+                with_optimizer=False, dropout_rate=0.0, **model)
+        exe.run(startup, scope=scope)
+        with unique_name.guard():
+            bundle = T.build_decode_step_program(
+                n_slots=c["n_slots"], state_prefix="@rehearse/",
+                cache=CacheConfig(
+                    layout="paged", block_size=c["block_size"],
+                    n_blocks=c["n_blocks"],
+                    n_prompt_entries=c["n_prompt_entries"]),
+                max_out_len=c["max_out_len"], start_id=2, end_id=1,
+                **model)
+        pools = {name: tuple(shape)
+                 for name, (shape, _) in bundle._state_specs.items()
+                 if POOL_MARK in name}
+        assert len(pools) == 4 * c["n_layers"]
+        # one row a cell, heads x head_dim flat on the minor axis: a
+        # [NB, BS, H, Dh] pool costs the TPU twice its bytes inside
+        # the loop (Dh 64 on 128 lanes) and two relayouts a dispatch
+        head_dim = c["d_model"] // c["n_heads"]
+        assert {s for n, s in pools.items() if "/self_" in n} == {
+            (c["n_blocks"] * c["block_size"],
+             c["n_heads"] * head_dim)}
+        assert {s for n, s in pools.items() if "/cross_" in n} == {
+            (c["n_prompt_entries"] + 1, c["n_heads"], c["seq_len"],
+             head_dim)}
+        srv = PagedContinuousGenerationServer(
+            bundle, executor=exe, scope=scope, start=False)
+        try:
+            comp = srv._serves[0]._compiled
+            assert set(pools) <= set(comp.state_in) | set(comp.const_in)
+            rng = scope._get(RNG_VAR)
+            closed = jax.make_jaxpr(comp.fn)(
+                exe._scope_state(scope, comp.state_in, None),
+                exe._scope_state(scope, comp.const_in, None),
+                {"n_steps": np.array([1], np.int64),
+                 "min_active": np.array([0], np.int64)},
+                jax.random.PRNGKey(0) if rng is None else rng)
+        finally:
+            srv.close()
+        names = {e.primitive.name for e in _eqns(closed.jaxpr)}
+        assert {"while", "scatter", "gather"} <= names
+        assert _pool_sized_moves(
+            closed, {s: s[1:] for s in pools.values()}) == []
+
+
+class TestCowProgram:
+    def test_copies_whole_blocks_and_nothing_else(self, trained):
+        """The COW block-copy program against a numpy oracle, bit for
+        bit: every gated row's destination block holds its source
+        block's cells, padded rows (gate 0, dst -1) and everything
+        else leave the pools as they were."""
+        from paddle_tpu.models.decode_engine import POOL_MARK
+
+        bundle, exe = trained["paged"], trained["exe"]
+        scope = trained["scope"]
+        bundle.init_slot_state(scope)
+        rng = np.random.RandomState(31)
+        names = [n for n in bundle._state_specs
+                 if POOL_MARK in n and "/self_" in n]
+        assert len(names) == 2 * L
+        before = {}
+        for n in names:
+            shape, dt = bundle._state_specs[n]
+            before[n] = rng.randn(*shape).astype(dt)
+            scope._set(n, before[n])
+        rows = N_SLOTS + 1
+        csrc = np.zeros((rows,), np.int64)
+        cdst = np.full((rows,), -1, np.int64)
+        cgate = np.zeros((rows,), np.float32)
+        csrc[:3], cdst[:3], cgate[:3] = [2, 2, NB - 1], [5, 0, 9], 1.0
+        cow = exe.prepare(bundle.cow, feed=bundle.cow_feed_spec(),
+                          fetch_list=[bundle.state["step"]],
+                          scope=scope)
+        try:
+            cow.run({"cow_src": csrc, "cow_dst": cdst,
+                     "cow_gate": cgate}, return_numpy=True)
+            for n in names:
+                want = before[n].reshape(NB, BS, -1).copy()
+                want[[5, 0, 9]] = want[[2, 2, NB - 1]]
+                got = np.asarray(scope._get(n))
+                assert got.shape == before[n].shape
+                assert np.array_equal(got.reshape(NB, BS, -1), want), n
+        finally:
+            bundle.init_slot_state(scope)
 
 
 class TestPagedAttentionKernel:
