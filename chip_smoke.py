@@ -508,8 +508,8 @@ def sweep_cases(tiny):
 
     from paddle_tpu.ops import pallas
     from paddle_tpu.ops.pallas import (attention, attention_block,
-                                       ffn_block, layer_norm,
-                                       paged_attention, xent)
+                                       ffn_block, grouped_matmul,
+                                       layer_norm, paged_attention, xent)
 
     key = jax.random.PRNGKey(SEED)
 
@@ -600,6 +600,37 @@ def sweep_cases(tiny):
         attn_case("flash_attention", attention.flash_attention,
                   attention.usable, 1 if tiny else 4, 2 if tiny else 8,
                   t_long, 64, causal)
+
+    # grouped-query attention at the long sequence the LFM2 cell
+    # trains on: a key-value head under four query heads, read in place
+    tq, hq, hkv = (1024, 4, 2) if tiny else (8192, 4, 1)
+    q, k, vv = (rnd(i, (1, h, tq, 64), jnp.bfloat16)
+                for i, h in ((27, hq), (28, hkv), (29, hkv)))
+
+    def gqa_reference(q, k, v):
+        return pallas.reference_attention(
+            q, jnp.repeat(k, hq // hkv, 1), jnp.repeat(v, hq // hkv, 1),
+            0.125, True)
+    cases.append((
+        "flash_attention", f"B1 H{hq} Hkv{hkv} T{tq} Dh64 bf16 causal",
+        attention.usable(q, k, vv), True,
+        fwd_bwd(lambda *a: attention.flash_attention(*a, 0.125, True),
+                gqa_reference, (q, k, vv), (0, 1, 2)), 3e-2))
+
+    # the dropless expert layer's grouped products: rows sorted by
+    # expert, the last group's rows belong to experts held elsewhere
+    rows_, dk, dn, g = (256, 128, 256, 4) if tiny else (8192, 2048, 3072, 8)
+    lhs = rnd(30, (rows_, dk), jnp.bfloat16)
+    rhs = rnd(31, (g, dk, dn), jnp.bfloat16, dk ** -0.5)
+    cut = np.sort(np.random.RandomState(SEED).randint(0, rows_ // 2, g))
+    sizes = jnp.asarray(np.diff(np.r_[0, cut, rows_]), jnp.int32)
+    cases.append((
+        "grouped_matmul", f"({rows_},{dk}) x ({g},{dk},{dn}) bf16, "
+        f"{int(cut[-1])} rows in {g} groups",
+        grouped_matmul.usable(lhs, rhs), True,
+        fwd_bwd(lambda a, b_: grouped_matmul.grouped_matmul(a, b_, sizes),
+                lambda a, b_: grouped_matmul.grouped_matmul_reference(
+                    a, b_, sizes), (lhs, rhs), (0, 1)), 3e-2))
 
     b, t, d, f, heads = (2, 16, 128, 256, 2) if tiny \
         else (16, 256, 512, 2048, 8)

@@ -14,7 +14,7 @@ from . import ops as _ops  # registers all kernels
 from .core.program import (Program, Block, Variable, Operator,
                            default_main_program, default_startup_program,
                            program_guard, switch_main_program,
-                           switch_startup_program)
+                           switch_startup_program, device_scope)
 from .core.executor import (Executor, TPUPlace, CPUPlace, CUDAPlace,
                             CUDAPinnedPlace,
                             seed)

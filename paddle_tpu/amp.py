@@ -49,7 +49,7 @@ WHITE_LIST = {
 BLACK_LIST = {
     "softmax", "log_softmax",
     "cross_entropy", "sigmoid_cross_entropy_with_logits",
-    "layer_norm", "batch_norm", "group_norm", "instance_norm",
+    "layer_norm", "rms_norm", "batch_norm", "group_norm", "instance_norm",
     "data_norm", "l2_normalize", "norm", "lrn",
     "mean", "reduce_mean", "reduce_sum", "reduce_prod", "sum",
     "exp", "log", "pow", "square", "rsqrt", "sqrt",
@@ -69,7 +69,14 @@ KEEP_LIST = {"cast", "fill_constant", "assign", "one_hot", "range",
              "share_data", "print", "is_empty", "shape",
              # manages its own precision: bf16 [N,V] logits stay put,
              # reductions accumulate fp32 in-register (nn_ops.py swce)
-             "softmax_with_cross_entropy"}
+             "softmax_with_cross_entropy",
+             # the router reads its input as it comes (float32 from an
+             # RMS norm: a choice made from rounded scores wanders) and
+             # the experts run in bfloat16 (ops/lm_ops.py moe_dropless)
+             "moe_dropless",
+             # float32 inside, its input's dtype out; the filter is not
+             # rounded on the way in
+             "short_conv"}
 
 _enabled = [os.environ.get("FLAGS_use_bf16", "") in
             ("1", "true", "True")]

@@ -236,6 +236,16 @@ def _moe_transformer():
     return {"main": main, "startup": startup}, []
 
 
+def _lfm2_moe():
+    from ..models import lfm2_moe
+
+    main, startup, _ = lfm2_moe.build_program(
+        seq_len=16, vocab=256, d_model=64, n_heads=4, n_kv_heads=2,
+        n_layers=5, n_dense_layers=1, d_dense=128, d_expert=64,
+        n_experts=8, top_k=2, experts_held=(2, 4))
+    return {"main": main, "startup": startup}, []
+
+
 def _ctr():
     from ..models import ctr
 
@@ -344,6 +354,7 @@ MODEL_BUILDERS: Dict[str, Callable] = {
     "machine_translation": _machine_translation,
     "transformer": _transformer,
     "moe_transformer": _moe_transformer,
+    "lfm2_moe": _lfm2_moe,
     "ctr": _ctr,
     "word2vec": _word2vec,
     "recommender": _recommender,

@@ -1124,6 +1124,38 @@ class Executor:
         return self._store_and_fetch(scope, new_state, rng_out,
                                      fetches, fetch_names, return_numpy)
 
+    def compiled_text(self, program, feed, fetch_list,
+                      scope: Optional[Scope] = None) -> str:
+        """HLO of the step `run(program, feed, fetch_list)` dispatches,
+        as the backend's compiler left it: every instruction under the
+        name a device profile shows it by, with the `op_name` path
+        (`jax.named_scope`s and `device_scope`s included) in its
+        metadata. Diagnostics only (no reference counterpart): the
+        step must have run once; it is lowered again at the same
+        shapes and compiled again, which the compilation cache
+        answers."""
+        scope = scope or global_scope()
+        block = program.global_block
+        fetch_names = _to_fetch_names(fetch_list)
+        feed_specs, feed_avals = [], {}
+        for name, val in dict(feed).items():
+            arr = _coerce_feed(val, _var_np_dtype(block, name))
+            feed_specs.append((name, arr.shape, str(arr.dtype)))
+            feed_avals[name] = _as_aval(arr)
+        compiled = self._cache.get(self._block_cache_key(
+            program, feed_specs, fetch_names))
+        if compiled is None:
+            raise RuntimeError("compiled_text: this step has not run "
+                               "on this executor yet")
+        mesh = _program_mesh(program)
+        device = self.place.device() if mesh is None else None
+        state = self._scope_state(scope, compiled.state_in, device, mesh)
+        const = self._scope_state(scope, compiled.const_in, device, mesh)
+        rng = self._scope_rng(scope, program, mesh)
+        state, const, rng = jax.tree.map(_as_aval, (state, const, rng))
+        return compiled.fn.lower(state, const, feed_avals,
+                                 rng).compile().as_text()
+
     @staticmethod
     def _store_and_fetch(scope, new_state, rng_out, fetches,
                          fetch_names, return_numpy):

@@ -138,6 +138,27 @@ def grad_var_name(name: str) -> str:
     return name + GRAD_SUFFIX
 
 
+# Name under which an op's kernel is traced (`jax.named_scope`), so that
+# a device profile can tell a model's parts apart. Grad ops copy their
+# forward op's attrs and so land under the same name.
+DEVICE_SCOPE_ATTR = "_device_scope"
+_DEVICE_SCOPE = [None]
+
+
+@contextlib.contextmanager
+def device_scope(name: str):
+    """`with device_scope("lfm2.attn"):` -- every op built inside is
+    traced under `jax.named_scope(name)`, forward and backward (no
+    reference counterpart: fluid's name_scope names variables, this
+    names the device's operations)."""
+    prev = _DEVICE_SCOPE[0]
+    _DEVICE_SCOPE[0] = name
+    try:
+        yield
+    finally:
+        _DEVICE_SCOPE[0] = prev
+
+
 class Operator:
     """One op invocation (reference framework.py:877 / op_desc.h).
 
@@ -160,6 +181,8 @@ class Operator:
         self.inputs = {k: list(v) for k, v in inputs.items()}
         self.outputs = {k: list(v) for k, v in outputs.items()}
         self.attrs = dict(attrs or {})
+        if _DEVICE_SCOPE[0] is not None:
+            self.attrs.setdefault(DEVICE_SCOPE_ATTR, _DEVICE_SCOPE[0])
 
     def input(self, slot):
         return self.inputs.get(slot, [])

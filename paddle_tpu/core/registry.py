@@ -26,7 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .program import GRAD_SUFFIX, Block, Operator, grad_var_name
+from .program import (DEVICE_SCOPE_ATTR, GRAD_SUFFIX, Block, Operator,
+                      grad_var_name)
 from .types import to_jnp_dtype
 
 
@@ -405,7 +406,12 @@ def run_op(op: Operator, env: Dict, rng_cell=None, rng_salt=0) -> None:
     if amp.enabled():
         inputs = amp.cast_op_inputs(op.type, inputs)
     ctx = OpContext(op, inputs, rng_cell=rng_cell, rng_salt=rng_salt)
-    raw = info.kernel(ctx)
+    scope = op.attrs.get(DEVICE_SCOPE_ATTR)
+    if scope:
+        with jax.named_scope(scope):
+            raw = info.kernel(ctx)
+    else:
+        raw = info.kernel(ctx)
     outs = _normalize_outputs(op, raw)
     for slot, names in op.outputs.items():
         vals = outs.get(slot)
@@ -616,8 +622,12 @@ def make_vjp_grad_kernel(fwd_type: str):
                     if g.dtype != v.dtype:
                         g = g.astype(v.dtype)
                     slot_cots.append(g)
-                else:
+                elif _is_float_dtype(v):
                     slot_cots.append(jnp.zeros_like(v))
+                else:
+                    # an integer output (chosen experts, counts) takes
+                    # no cotangent: jax.vjp wants float0 there
+                    slot_cots.append(np.zeros(v.shape, jax.dtypes.float0))
             cots[slot] = slot_cots
         (grads,) = vjp_fn(cots)
         result: Dict[str, List] = {}

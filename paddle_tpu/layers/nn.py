@@ -1309,7 +1309,11 @@ def switch_moe(input, num_experts, d_inner, top_k=1,
                return_drop_frac=False):
     """Switch/GShard mixture-of-experts FFN (beyond-reference; routing
     math + expert-parallel dataflow in parallel/moe.py, lowered by the
-    `switch_moe` op). Returns (out, aux_loss): add
+    `switch_moe` op). This is the CAPACITY routing, which DROPS tokens:
+    every expert takes at most `capacity_factor * top_k * tokens /
+    num_experts` of them and the rest get no expert (their output is
+    zero). `moe_dropless` below is the routing that drops none.
+    Returns (out, aux_loss): add
     ``aux_loss * coeff`` (Switch uses coeff=0.01) onto the training
     loss or routing collapses onto one expert.
     With ``return_drop_frac=True`` returns (out, aux_loss, drop_frac)
@@ -1663,3 +1667,111 @@ __all__.extend([
     "autoincreased_step_counter", "image_resize_short", "lod_reset",
     "mean_iou", "similarity_focus", "merge_selected_rows",
     "get_tensor_from_selected_rows", "tree_conv"])
+
+
+# ---------------------------------------------------------------------------
+# decoder-only language-model layers (ops/lm_ops.py; no reference
+# counterpart: Fluid 1.x predates them)
+# ---------------------------------------------------------------------------
+def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
+    """y = x / sqrt(mean(x^2) + eps) * scale over the last axis, scale
+    a [D] parameter initialised to one."""
+    helper = LayerHelper("rms_norm", input=input, param_attr=param_attr,
+                         name=name)
+    scale = helper.create_parameter(
+        helper.param_attr, [input.shape[-1]], input.dtype,
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op("rms_norm", {"X": input, "Scale": scale},
+                     {"Y": out}, {"epsilon": float(epsilon)})
+    return out
+
+
+def rotary_embedding(x, theta=10000.0, name=None):
+    """Rotary positions 0..T-1 on x [B, T, H, D]."""
+    return _single_out(LayerHelper("rotary_embedding", input=x, name=name),
+                       "rotary_embedding", {"X": x},
+                       {"theta": float(theta)})
+
+
+def swiglu(x, name=None):
+    """silu(a) * b for x = [a, b] side by side on the last axis."""
+    return _single_out(LayerHelper("swiglu", input=x, name=name),
+                       "swiglu", {"X": x})
+
+
+def short_conv(x, taps=3, param_attr=None, name=None):
+    """The inside of a gated short convolution: x [B, T, 3D] holds
+    [b, c, z]; returns c * causal_depthwise_conv(b * z) [B, T, D] with
+    a [D, taps] filter parameter."""
+    helper = LayerHelper("short_conv", input=x, param_attr=param_attr,
+                         name=name)
+    d = x.shape[-1] // 3
+    w = helper.create_parameter(
+        helper.param_attr, [d, taps], x.dtype,
+        default_initializer=NormalInitializer(0.0, taps ** -0.5))
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("short_conv", {"X": x, "Filter": w}, {"Out": out},
+                     {})
+    return out
+
+
+def moe_dropless(input, num_experts, d_inner, top_k, experts_held=None,
+                 norm_topk=True, scaling=1.0, name=None,
+                 scope="moe"):
+    """Routed expert layer that drops NO token (parallel/moe.py
+    `moe_dropless`; `switch_moe` above is the capacity routing, which
+    drops what does not fit). A sigmoid router over all `num_experts`
+    picks `top_k` a token by score plus a per-expert bias (a buffer,
+    not trained: `<name>_bias`); `experts_held` = (first, count) says
+    which contiguous experts this layer holds (default: all), and the
+    output is their part of the result. Experts are gated feed-forward
+    blocks W2(silu(W1 x) * W3 x) of width `d_inner`; parameters
+    `<name>_gate.w` [D, E], `<name>_w13` [held, D, 2*d_inner] (W1 and
+    W3 side by side), `<name>_w2` [held, d_inner, D].
+    Returns (out, chosen [N, top_k] int32, load [held] int32: pairs
+    each held expert received, pairs_here [1] int32); the last three
+    cost nothing unless fetched."""
+    from ..param_attr import ParamAttr
+
+    helper = LayerHelper("moe_dropless", input=input, name=name)
+    d = input.shape[-1]
+    prefix = name or helper.name
+    first, held = experts_held or (0, num_experts)
+    if not (0 <= first and first + held <= num_experts):
+        raise ValueError(f"experts_held {experts_held} is not a range "
+                         f"of {num_experts} experts")
+    wg = helper.create_parameter(
+        ParamAttr(name=f"{prefix}_gate.w"), [d, num_experts],
+        input.dtype, default_initializer=NormalInitializer(0.0, 0.02))
+    bias = helper.create_parameter(
+        ParamAttr(name=f"{prefix}_bias", trainable=False),
+        [num_experts], input.dtype,
+        default_initializer=ConstantInitializer(0.0))
+    w13 = helper.create_parameter(
+        ParamAttr(name=f"{prefix}_w13"), [held, d, 2 * d_inner],
+        input.dtype, default_initializer=NormalInitializer(0.0, d ** -0.5))
+    w2 = helper.create_parameter(
+        ParamAttr(name=f"{prefix}_w2"), [held, d_inner, d], input.dtype,
+        default_initializer=NormalInitializer(0.0, d_inner ** -0.5))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    extras = {}
+    for slot, tag in (("Chosen", "chosen"), ("Load", "load"),
+                      ("PairsHere", "pairs_here")):
+        var = helper.create_variable(name=f"{prefix}_{tag}",
+                                     dtype="int32", persistable=False)
+        var.stop_gradient = True
+        extras[slot] = var
+    helper.append_op(
+        "moe_dropless",
+        {"X": input, "GateW": wg, "ExpertBias": bias, "W13": w13,
+         "W2": w2},
+        {"Out": out, **extras},
+        {"first_held": int(first), "top_k": int(top_k),
+         "norm_topk": bool(norm_topk), "scaling": float(scaling),
+         "scope": scope})
+    return out, extras["Chosen"], extras["Load"], extras["PairsHere"]
+
+
+__all__.extend(["rms_norm", "rotary_embedding", "swiglu", "short_conv",
+                "moe_dropless"])

@@ -1041,6 +1041,19 @@ def attention(ctx):
         return jnp.swapaxes(x, 1, 2) if layout == "bthd" else x
 
     qh, kh, vh = to_bhtd(q), to_bhtd(k), to_bhtd(v)
+    group = qh.shape[1] // kh.shape[1]
+    if group > 1:
+        # grouped-query attention: a key-value head is shared by
+        # `group` query heads. The flash kernel reads it in place;
+        # every other path sees the key-value heads repeated.
+        if dropout_rate == 0.0 and pallas.note_route(
+                "flash_attention", qh.shape,
+                qh.shape[2] > 512 and pallas_attn.usable(qh, kh, vh)):
+            return to_bhtd(pallas_attn.flash_attention(
+                qh, kh, vh, scale=scale, causal=causal))
+        k, v = (jnp.repeat(x, group, axis=2 if layout == "bthd" else 1)
+                for x in (k, v))
+        kh, vh = to_bhtd(k), to_bhtd(v)
     if ra.cp_applicable(qh, kh, vh, dropout_rate):
         return to_bhtd(ra.cp_attention(qh, kh, vh, scale, causal))
     if dropout_rate == 0.0:

@@ -61,14 +61,17 @@ def usable(q, k, v) -> bool:
     if not (on_tpu() or _interp()):
         return False
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    hkv, tk = k.shape[1], k.shape[2]
     return (_pick_block(tq) >= 8 and _pick_block(tk) >= 8
+            and h % hkv == 0 and k.shape == v.shape
             and d in (64, 128, 256) and q.dtype == k.dtype == v.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def flash_attention(q, k, v, scale=1.0, causal=False):
-    """q,k,v: [B,H,T,D] -> [B,H,T,D]."""
+    """q: [B,H,T,D]; k, v: [B,Hkv,T,D] with H a multiple of Hkv (each
+    key-value head shared by H/Hkv query heads, read in place) ->
+    [B,H,T,D]."""
     out, _ = _flash_fwd_impl(q, k, v, scale, causal)
     return out
 
@@ -86,17 +89,49 @@ def _flash_bwd(scale, causal, res, g):
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _dot_nt(a, b):
+    """a [m, d] x b [n, d]^T -> [m, n], operands as stored (bfloat16
+    runs on the MXU at its own rate), float32 accumulation."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _dot_tn(a, b):
+    """a [m, n]^T x b [m, d] -> [n, d]."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _dot(a, b):
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
+def _causal_keep(q0, k0, block_q, block_k, offset):
+    """Bottom-right alignment: query row i sees keys <= i + offset."""
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    return q_pos + offset >= k_pos
+
+
+def _causal_key_blocks(qi, block_q, block_k, offset, n_blocks):
+    """Key blocks any row of query block qi sees: those after them lie
+    wholly above the diagonal and are never visited."""
+    return jnp.clip(((qi + 1) * block_q + offset + block_k - 1) // block_k,
+                    0, n_blocks)
+
+
 def _flash_fwd_impl(q, k, v, scale, causal):
     from jax.experimental import pallas as pl
 
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    hkv, tk = k.shape[1], k.shape[2]
+    group = h // hkv
     block_q = _pick_block(tq)
     block_k = _pick_block(tk)
     bh = b * h
     q3 = q.reshape(bh, tq, d)
-    k3 = k.reshape(bh, tk, d)
-    v3 = v.reshape(bh, tk, d)
+    k3 = k.reshape(b * hkv, tk, d)
+    v3 = v.reshape(b * hkv, tk, d)
 
     grid = (bh, tq // block_q)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
@@ -106,8 +141,9 @@ def _flash_fwd_impl(q, k, v, scale, causal):
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),
+            # query head i reads key-value head i // group
+            pl.BlockSpec((1, tk, d), lambda i, j: (i // group, 0, 0)),
+            pl.BlockSpec((1, tk, d), lambda i, j: (i // group, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
@@ -129,7 +165,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
                 tq, tk, block_k):
     from jax.experimental import pallas as pl
 
-    q = q_ref[0].astype(jnp.float32) * scale  # [BQ, D]
+    q = q_ref[0]                              # [BQ, D]
     block_q = q.shape[0]
     qi = pl.program_id(1)
     m = jnp.full((block_q,), -jnp.inf, dtype=jnp.float32)
@@ -141,29 +177,27 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
 
     def body(kb, carry):
         m, l, acc = carry
-        k_blk = k_ref[0, pl.dslice(kb * block_k, block_k)].astype(
-            jnp.float32)
-        v_blk = v_ref[0, pl.dslice(kb * block_k, block_k)].astype(
-            jnp.float32)
-        s = q @ k_blk.T  # [BQ, BK]
+        k_blk = k_ref[0, pl.dslice(kb * block_k, block_k)]
+        v_blk = v_ref[0, pl.dslice(kb * block_k, block_k)]
+        s = _dot_nt(q, k_blk) * scale         # [BQ, BK] float32
         if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos + offset >= k_pos, s, -jnp.inf)
+            s = jnp.where(_causal_keep(qi * block_q, kb * block_k,
+                                       block_q, block_k, offset),
+                          s, -jnp.inf)
         m_new = jnp.maximum(m, s.max(axis=1))
         # rows with no valid key yet keep m=-inf; guard the exp
         m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(s - m_safe[:, None])
-        p = jnp.where(jnp.isfinite(s), p, 0.0)
-        correction = jnp.where(jnp.isfinite(m),
-                               jnp.exp(m - m_safe), 0.0)
+        p = jnp.where(jnp.isfinite(s), jnp.exp(s - m_safe[:, None]), 0.0)
+        correction = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
         l_new = l * correction + p.sum(axis=1)
-        acc_new = acc * correction[:, None] + p @ v_blk
+        acc_new = acc * correction[:, None] \
+            + _dot(p.astype(v_blk.dtype), v_blk)
         return m_new, l_new, acc_new
 
     n_blocks = tk // block_k
+    if causal:
+        n_blocks = _causal_key_blocks(qi, block_q, block_k, offset,
+                                      n_blocks)
     m, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m, l, acc))
     safe_l = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc / safe_l[:, None]).astype(o_ref.dtype)
@@ -176,15 +210,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
 # ---------------------------------------------------------------------------
 def _flash_bwd_impl(q, k, v, out, lse, g, scale, causal):
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     b, h, tq, d = q.shape
-    tk = k.shape[2]
+    hkv, tk = k.shape[1], k.shape[2]
+    group = h // hkv
     block_q = _pick_block(tq)
     block_k = _pick_block(tk)
     bh = b * h
     q3 = q.reshape(bh, tq, d)
-    k3 = k.reshape(bh, tk, d)
-    v3 = v.reshape(bh, tk, d)
+    k3 = k.reshape(b * hkv, tk, d)
+    v3 = v.reshape(b * hkv, tk, d)
     g3 = g.reshape(bh, tq, d)
     lse3 = lse.reshape(bh, 1, tq)
     # delta_i = rowsum(dO_i * O_i); tiny elementwise+reduce, XLA fuses
@@ -200,8 +236,8 @@ def _flash_bwd_impl(q, k, v, out, lse, g, scale, causal):
         grid=(bh, tq // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, tk, d), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, tk, d), lambda i, j: (i // group, 0, 0)),
+            pl.BlockSpec((1, tk, d), lambda i, j: (i // group, 0, 0)),
             pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
             pl.BlockSpec((1, 1, block_q), lambda i, j: (i, 0, j)),
@@ -212,42 +248,47 @@ def _flash_bwd_impl(q, k, v, out, lse, g, scale, causal):
         name="flash_attention_dq",
     )(q3, k3, v3, g3, lse3, delta)
 
+    # one program a (key-value head, key block, query head of its
+    # group): the group's query heads follow one another on the last
+    # grid axis and add into one accumulator, written after the last
     dkv_kernel = functools.partial(_bwd_dkv_kernel, scale=scale,
                                    causal=causal, tq=tq, tk=tk,
-                                   block_q=block_q)
+                                   block_q=block_q, group=group)
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        grid=(bh, tk // block_k),
+        grid=(b * hkv, tk // block_k, group),
         in_specs=[
-            pl.BlockSpec((1, tq, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, tq, d), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, 1, tq), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, 1, tq), lambda i, j: (i, 0, 0)),
+            pl.BlockSpec((1, tq, d), lambda i, j, r: (i * group + r, 0, 0)),
+            pl.BlockSpec((1, block_k, d), lambda i, j, r: (i, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda i, j, r: (i, j, 0)),
+            pl.BlockSpec((1, tq, d), lambda i, j, r: (i * group + r, 0, 0)),
+            pl.BlockSpec((1, 1, tq), lambda i, j, r: (i * group + r, 0, 0)),
+            pl.BlockSpec((1, 1, tq), lambda i, j, r: (i * group + r, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda i, j, r: (i, j, 0)),
+            pl.BlockSpec((1, block_k, d), lambda i, j, r: (i, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
+            jax.ShapeDtypeStruct((b * hkv, tk, d), k.dtype),
+            jax.ShapeDtypeStruct((b * hkv, tk, d), v.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=_interp(),
         name="flash_attention_dkv",
     )(q3, k3, v3, g3, lse3, delta)
 
-    return (dq.reshape(b, h, tq, d), dk.reshape(b, h, tk, d),
-            dv.reshape(b, h, tk, d))
+    return (dq.reshape(b, h, tq, d), dk.reshape(b, hkv, tk, d),
+            dv.reshape(b, hkv, tk, d))
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, *, scale, causal, tq, tk, block_k):
     from jax.experimental import pallas as pl
 
-    q = q_ref[0].astype(jnp.float32)          # [BQ, D]
-    do = do_ref[0].astype(jnp.float32)        # [BQ, D]
+    q = q_ref[0]                              # [BQ, D]
+    do = do_ref[0]                            # [BQ, D]
     lse = lse_ref[0, 0]                       # [BQ]
     delta = delta_ref[0, 0]                   # [BQ]
     block_q = q.shape[0]
@@ -257,66 +298,75 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq = jnp.zeros(q.shape, dtype=jnp.float32)
 
     def body(kb, dq):
-        k_blk = k_ref[0, pl.dslice(kb * block_k, block_k)].astype(
-            jnp.float32)
-        v_blk = v_ref[0, pl.dslice(kb * block_k, block_k)].astype(
-            jnp.float32)
-        s = (q @ k_blk.T) * scale
+        k_blk = k_ref[0, pl.dslice(kb * block_k, block_k)]
+        v_blk = v_ref[0, pl.dslice(kb * block_k, block_k)]
+        s = _dot_nt(q, k_blk) * scale
+        p = jnp.exp(s - lse_safe)
         if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos + offset >= k_pos, s, -jnp.inf)
-        p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse_safe), 0.0)
-        dp = do @ v_blk.T                     # [BQ, BK]
+            p = jnp.where(_causal_keep(qi * block_q, kb * block_k,
+                                       block_q, block_k, offset), p, 0.0)
+        dp = _dot_nt(do, v_blk)               # [BQ, BK]
         ds = p * (dp - delta[:, None]) * scale
-        return dq + ds @ k_blk
+        return dq + _dot(ds.astype(k_blk.dtype), k_blk)
 
     n_blocks = tk // block_k
+    if causal:
+        n_blocks = _causal_key_blocks(qi, block_q, block_k, offset,
+                                      n_blocks)
     dq = jax.lax.fori_loop(0, n_blocks, body, dq)
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, scale, causal, tq, tk, block_q):
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal, tq,
+                    tk, block_q, group):
     from jax.experimental import pallas as pl
 
-    k = k_ref[0].astype(jnp.float32)          # [BK, D]
-    v = v_ref[0].astype(jnp.float32)          # [BK, D]
+    k = k_ref[0]                              # [BK, D]
+    v = v_ref[0]                              # [BK, D]
     block_k = k.shape[0]
     ki = pl.program_id(1)
+    r = pl.program_id(2)
     offset = tk - tq
-    dk = jnp.zeros(k.shape, dtype=jnp.float32)
-    dv = jnp.zeros(v.shape, dtype=jnp.float32)
+
+    @pl.when(r == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def body(qb, carry):
         dk, dv = carry
-        q_blk = q_ref[0, pl.dslice(qb * block_q, block_q)].astype(
-            jnp.float32)
-        do_blk = do_ref[0, pl.dslice(qb * block_q, block_q)].astype(
-            jnp.float32)
+        q_blk = q_ref[0, pl.dslice(qb * block_q, block_q)]
+        do_blk = do_ref[0, pl.dslice(qb * block_q, block_q)]
         lse_blk = lse_ref[0, 0, pl.dslice(qb * block_q, block_q)]
         delta_blk = delta_ref[0, 0, pl.dslice(qb * block_q, block_q)]
         lse_safe = jnp.where(jnp.isfinite(lse_blk), lse_blk, 0.0)[:, None]
-        s = (q_blk @ k.T) * scale             # [BQ, BK]
+        s = _dot_nt(q_blk, k) * scale         # [BQ, BK]
+        p = jnp.exp(s - lse_safe)
         if causal:
-            q_pos = qb * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            k_pos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(q_pos + offset >= k_pos, s, -jnp.inf)
-        p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse_safe), 0.0)
-        dv = dv + p.T @ do_blk
-        dp = do_blk @ v.T                     # [BQ, BK]
+            p = jnp.where(_causal_keep(qb * block_q, ki * block_k,
+                                       block_q, block_k, offset), p, 0.0)
+        dv = dv + _dot_tn(p.astype(do_blk.dtype), do_blk)
+        dp = _dot_nt(do_blk, v)               # [BQ, BK]
         ds = p * (dp - delta_blk[:, None]) * scale
-        dk = dk + ds.T @ q_blk
+        dk = dk + _dot_tn(ds.astype(q_blk.dtype), q_blk)
         return dk, dv
 
-    n_blocks = tq // block_q
-    dk, dv = jax.lax.fori_loop(0, n_blocks, body, (dk, dv))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    n_q = tq // block_q
+    first = 0
+    if causal:
+        # query blocks wholly before this key block never see it
+        first = jnp.clip((ki * block_k - offset) // block_q, 0, n_q)
+    dk, dv = jax.lax.fori_loop(
+        first, n_q, body, (jnp.zeros(k.shape, jnp.float32),
+                           jnp.zeros(v.shape, jnp.float32)))
+    dk_acc[...] += dk
+    dv_acc[...] += dv
+
+    @pl.when(r == group - 1)
+    def _():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 # ---------------------------------------------------------------------------

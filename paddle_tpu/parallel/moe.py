@@ -1,17 +1,39 @@
-"""Mixture-of-Experts with expert parallelism over an 'ep' mesh axis.
+"""Mixture-of-Experts: two routings, and expert parallelism over an
+'ep' mesh axis.
 
-Beyond-reference capability (SURVEY.md §2.4: expert parallelism ABSENT):
-Switch-Transformer-style routing (top-1, Fedus et al. '21) and GShard
-top-2 (Lepikhin et al. '20) with fixed expert capacity, the
+Beyond-reference capability (SURVEY.md §2.4: expert parallelism ABSENT).
+
+**Capacity routing, which DROPS tokens** (`route_tokens`, `moe_dense`,
+`moe_local`, `moe_apply`; the `switch_moe` op and `layers.switch_moe`):
+Switch-Transformer-style top-1 (Fedus et al. '21) and GShard top-2
+(Lepikhin et al. '20) over softmax gates, every expert a fixed number
+of slots (`capacity_factor`), tokens over an expert's capacity left
+out (their output is zero, `drop_frac` counts them), the
 load-balancing auxiliary loss (Switch eq. 4), experts sharded over
-'ep', token dispatch/return as `lax.all_to_all` over ICI -- the
-standard TPU MoE dataflow (dispatch einsum -> a2a -> expert FFN -> a2a
--> combine einsum), fully differentiable.
+'ep' with dispatch and return as `lax.all_to_all` -- the standard TPU
+MoE dataflow (dispatch einsum -> a2a -> expert FFN -> a2a -> combine
+einsum), fully differentiable. `models/moe_transformer.py` trains on
+it.
 
-Three entry points:
-* `route_tokens` -- router math shared by every path: top-k selection,
-  priority-ordered capacity assignment, dispatch/combine tensors, aux
-  loss. Pure and mesh-free.
+**Dropless routing, which drops NOTHING** (`route_dropless`,
+`moe_dropless`; the `moe_dropless` op and `layers.moe_dropless`):
+sigmoid scores over all E experts, the top k by score plus a
+per-expert bias that enters the choice only, the chosen scores
+normalised; every (token, expert) pair is computed, by sorting the
+pairs by expert and running grouped matrix products over the ragged
+groups (ops/pallas/grouped_matmul.py). The layer is TOLD which experts
+it holds (`first_held`, and as many as its weights have): it routes
+over all E and returns the part of the result its own experts give,
+which is what one rank of an expert-parallel job computes before the
+exchange. Held = all E is the uncut layer; the shares of all ranks add
+up to it. There is no exchange across chips here yet (ROADMAP M3), and
+no auxiliary loss: the family balances by the bias.
+`models/lfm2_moe.py` trains on it.
+
+Entry points of the capacity routing:
+* `route_tokens` -- router math shared by every capacity path: top-k
+  selection, priority-ordered capacity assignment, dispatch/combine
+  tensors, aux loss. Pure and mesh-free.
 * `moe_apply` / `moe_local` -- the shard_map expert-parallel form.
 * the `switch_moe` graph op (ops/nn_ops.py) + `layers.switch_moe` --
   the Program path; inside a `with expert_parallel(mesh):` scope the op
@@ -38,7 +60,8 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["route_tokens", "moe_local", "moe_apply", "expert_parallel",
-           "active_expert_parallel", "moe_dense", "RoutingResult"]
+           "active_expert_parallel", "moe_dense", "RoutingResult",
+           "route_dropless", "moe_dropless"]
 
 
 class RoutingResult(NamedTuple):
@@ -250,6 +273,124 @@ def moe_apply(x, wg, w1, w2, mesh: Mesh, axis: str = "ep",
     if t_pad:
         out = out[:t]
     return out, aux, drop
+
+
+# --- dropless routing ------------------------------------------------------
+def route_dropless(x, wg, bias, top_k: int, norm_topk: bool = True,
+                   scaling: float = 1.0):
+    """Sigmoid router that drops nothing. x: [t, d]; wg: [d, E]; bias:
+    [E], added to the scores for the choice only (no gradient reaches
+    it). Returns (idx [t, k] int32, weight [t, k] float32): the k
+    experts with the largest score + bias, and their scores over the
+    sum of the chosen scores + 1e-6 (when `norm_topk`), times
+    `scaling`. All of it float32 with the product at full precision:
+    a choice made from rounded scores wanders from the exact one."""
+    logits = jnp.dot(x.astype(jnp.float32), wg.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = lax.top_k(scores + lax.stop_gradient(
+        bias.astype(jnp.float32)), top_k)
+    weight = jnp.take_along_axis(scores, idx, axis=-1)
+    if norm_topk:
+        weight = weight / (weight.sum(-1, keepdims=True) + 1e-6)
+    return idx.astype(jnp.int32), weight * scaling
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_pairs(x, order, inverse, top_k):
+    """Rows of x [t, d] for every pair in sorted order: x[order // k].
+    `inverse` [t*k] is each pair's place in the sorted order; the
+    backward pass is a gather by it and a sum over each token's k
+    pairs, never a scatter."""
+    return x[order // top_k]
+
+
+def _take_pairs_fwd(x, order, inverse, top_k):
+    return x[order // top_k], (inverse, x.shape[0])
+
+
+def _take_pairs_bwd(top_k, res, g):
+    inverse, t = res
+    return g[inverse].reshape(t, top_k, -1).sum(1), None, None
+
+
+_take_pairs.defvjp(_take_pairs_fwd, _take_pairs_bwd)
+
+
+@jax.custom_vjp
+def _untake_pairs(y, inverse, order):
+    """Every pair's row of y [t*k, d] (sorted order) back in the pairs'
+    own order. The backward pass is the gather by `order`, the sorting
+    permutation."""
+    return y[inverse]
+
+
+def _untake_pairs_fwd(y, inverse, order):
+    return y[inverse], order
+
+
+def _untake_pairs_bwd(order, g):
+    return g[order], None, None
+
+
+_untake_pairs.defvjp(_untake_pairs_fwd, _untake_pairs_bwd)
+
+
+def moe_dropless(x, wg, bias, w13, w2, first_held: int, top_k: int,
+                 norm_topk: bool = True, scaling: float = 1.0,
+                 compute_dtype=None, scope: str = "moe"):
+    """One rank's share of a dropless expert layer.
+
+    x: [t, d]; wg: [d, E]; bias: [E]; w13: [n_held, d, 2f] (each held
+    expert's W1 and W3 side by side); w2: [n_held, f, d]. The layer
+    holds experts first_held .. first_held + n_held of E, routes every
+    token over all E, and returns the sum over a token's chosen experts
+    THAT ARE HELD HERE of weight * W2(silu(W1 x) * W3 x); n_held = E is
+    the whole layer. Returns (out [t, d], idx [t, k] int32, load
+    [n_held] int32: pairs each held expert received, pairs_here [1]
+    int32). The router is float32; the experts run in `compute_dtype`
+    (default: x's). Device scopes: `<scope>.route`, `.experts`,
+    `.combine`.
+
+    Nothing is dropped whatever the routing: all t*k pairs are sorted,
+    the held experts' first, on row buffers that hold every pair; the
+    rows after the held experts' are never computed."""
+    from ..ops.pallas.grouped_matmul import grouped_matmul
+
+    t, d = x.shape
+    n_held = w13.shape[0]
+    f = w2.shape[1]
+    cd = compute_dtype or x.dtype
+    with jax.named_scope(f"{scope}.route"):
+        idx, weight = route_dropless(x, wg, bias, top_k, norm_topk,
+                                     scaling)
+        local = idx.reshape(-1) - first_held            # [t*k]
+        held = (local >= 0) & (local < n_held)
+        # pairs of held experts first, by expert; the others behind
+        key = jnp.where(held, local, n_held)
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=jnp.int32))
+        # rows a group: the held experts' pairs, then the rest as one
+        # last group that has no weights here and is not computed
+        sizes = jnp.bincount(key, length=n_held + 1).astype(jnp.int32)
+        load = sizes[:n_held]
+        here = held.reshape(t, top_k)
+        w_here = jnp.where(here, weight, 0.0)
+    xc, w13c, w2c = x.astype(cd), w13.astype(cd), w2.astype(cd)
+    with jax.named_scope(f"{scope}.experts"):
+        xs = _take_pairs(xc, order, inverse, top_k)
+        h = grouped_matmul(xs, w13c, sizes)              # [t*k, 2f]
+        a = (jax.nn.silu(h[:, :f].astype(jnp.float32))
+             * h[:, f:].astype(jnp.float32)).astype(cd)
+        y = grouped_matmul(a, w2c, sizes)                # [t*k, d]
+    with jax.named_scope(f"{scope}.combine"):
+        yp = _untake_pairs(y, inverse, order).reshape(t, top_k, d)
+        # a select, not a product with 0: what the rows of experts held
+        # elsewhere contain is the kernel's business
+        yp = jnp.where(here[..., None], yp.astype(jnp.float32), 0.0)
+        out = jnp.einsum("tkd,tk->td", yp, w_here).astype(cd)
+    return out, idx, load, load.sum().reshape(1)
 
 
 # --- expert-parallel activation scope --------------------------------------
