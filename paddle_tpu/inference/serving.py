@@ -3603,6 +3603,10 @@ class PagedContinuousGenerationServer(ContinuousGenerationServer):
             "radix_inserts": self._radix.inserts,
             "radix_adoptions": self._radix.adoptions,
             "radix_evicted_blocks": self._radix.evicted_blocks,
+            # leaves examined over blocks evicted is what an eviction
+            # costs: about 1, plus the leaves live lanes pin
+            "radix_evict_calls": self._radix.evict_calls,
+            "radix_evict_candidates": self._radix.evict_candidates,
             "radix_admissions": self._radix_admits,
             "plain_radix_admissions": self._plain_radix_admits,
             "sessions_open": len(self._sessions),
@@ -3676,6 +3680,10 @@ class PagedContinuousGenerationServer(ContinuousGenerationServer):
              self._radix.hit_blocks),
             ("paddle_tpu_blockpool_radix_evicted_blocks_total", lab,
              self._radix.evicted_blocks),
+            ("paddle_tpu_blockpool_radix_evict_calls_total", lab,
+             self._radix.evict_calls),
+            ("paddle_tpu_blockpool_radix_evict_candidates_total", lab,
+             self._radix.evict_candidates),
             ("paddle_tpu_blockpool_radix_admissions_total", lab,
              self._radix_admits),
             ("paddle_tpu_blockpool_sessions_open", lab,

@@ -44,6 +44,7 @@ serving scheduler (inference/serving.py).
 from __future__ import annotations
 
 import dataclasses
+import heapq
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
@@ -3721,13 +3722,24 @@ class PromptPrefixCache:
 
 
 class _RadixNode:
-    __slots__ = ("chunk", "block", "children", "parent")
+    __slots__ = ("chunk", "block", "children", "parent", "order",
+                 "queued")
 
-    def __init__(self, chunk, block, parent):
+    def __init__(self, chunk, block, parent, seq):
         self.chunk = chunk        # the BS-token tuple this edge spells
+        #                           (a root sentinel: its prompt key)
         self.block = block        # pool block holding its self-KV
         self.children = {}        # chunk tuple -> _RadixNode
         self.parent = parent
+        # eviction rank (RadixBlockTree._leaves): a root carries its
+        # insertion number, a node its parent's rank plus its own
+        # insertion number NEGATED, so the rank is one longer than the
+        # node is deep — among equal depths the smallest rank is the
+        # oldest root's leaf that a depth-first descent through each
+        # node's newest child meets first
+        self.order = (seq,) if parent is None \
+            else parent.order + (-seq,)
+        self.queued = False       # one entry of _leaves names it
 
 
 class RadixBlockTree:
@@ -3754,16 +3766,42 @@ class RadixBlockTree:
     ``evict`` drops such LEAF nodes (never an interior node: its
     children's KV transitively depends on it), which is exactly the
     "eviction only unpins refcount-0 subtrees" invariant
-    tests/test_block_pool_model.py property-checks."""
+    tests/test_block_pool_model.py property-checks.
+
+    No call but ``tree_blocks`` (the tests' oracle) visits every
+    node: ``_leaves`` is a heap of the leaves, deepest first, kept up
+    to date as ``insert`` and ``evict`` add and drop nodes. Lanes
+    drop their refs through ``HostBlockPool.decref``, so the tree is
+    never told when a block returns to refcount 1: the heap holds
+    every leaf, pinned or not, and ``evict`` reads the refcount of
+    each candidate it takes."""
 
     def __init__(self, pool: "HostBlockPool", block_size: int):
         self.pool = pool
         self.block_size = max(1, int(block_size))
         self._roots: Dict[tuple, _RadixNode] = {}
+        # (-len(order), order, node), at most one entry a node (its
+        # ``queued``); an entry whose node has grown a child since is
+        # stale and dropped when it surfaces
+        self._leaves: List[tuple] = []
+        self._seq = 0             # insertion numbers, roots and nodes
+        self.n_nodes = 0
         self.inserts = 0
         self.adoptions = 0
         self.hit_blocks = 0
         self.evicted_blocks = 0
+        self.evict_calls = 0
+        self.evict_candidates = 0  # leaves examined, pinned included
+
+    def _new_node(self, chunk, block, parent):
+        self._seq += 1
+        return _RadixNode(chunk, block, parent, self._seq)
+
+    def _queue_leaf(self, node):
+        if not node.queued:
+            node.queued = True
+            heapq.heappush(self._leaves,
+                           (-len(node.order), node.order, node))
 
     def _chunks(self, tokens):
         toks = tuple(int(t) for t in tokens)
@@ -3831,52 +3869,63 @@ class RadixBlockTree:
                 f"KV block would serve garbage to every later hit")
         root = self._roots.get(key)
         if root is None:
-            root = self._roots[key] = _RadixNode(None, None, None)
-        node, adopted = root, 0
-        for chunk, block in zip(chunks, blocks):
-            nxt = node.children.get(chunk)
-            if nxt is None:
-                self.pool.incref(block)
-                nxt = _RadixNode(chunk, block, node)
-                node.children[chunk] = nxt
-                adopted += 1
-            node = nxt
+            root = self._roots[key] = self._new_node(key, None, None)
+        node, tip, adopted = root, None, 0
+        try:
+            for chunk, block in zip(chunks, blocks):
+                nxt = node.children.get(chunk)
+                if nxt is None:
+                    self.pool.incref(block)
+                    nxt = tip = self._new_node(chunk, block, node)
+                    node.children[chunk] = nxt
+                    adopted += 1
+                node = nxt
+        finally:
+            # of the nodes this call made only the last is a leaf; the
+            # node it hangs from stopped being one (its entry is stale)
+            if tip is not None:
+                self._queue_leaf(tip)
+            elif not root.children:
+                del self._roots[key]
+            self.n_nodes += adopted
+            self.adoptions += adopted
         self.inserts += 1
-        self.adoptions += adopted
         return adopted
 
     def evict(self, need: int) -> int:
         """Free >= ``need`` blocks by unpinning tree-only (refcount
-        1) LEAF nodes, deepest first. Returns how many were freed;
-        pinned subtrees (any lane ref anywhere below) are never
-        touched."""
-        freed = 0
-        while freed < need:
-            victim = None
-            for root in self._roots.values():
-                stack = [(c, 1) for c in root.children.values()]
-                best = None
-                while stack:
-                    n, d = stack.pop()
-                    if n.children:
-                        stack.extend((c, d + 1)
-                                     for c in n.children.values())
-                    elif self.pool.refcount(n.block) == 1:
-                        if best is None or d > best[1]:
-                            best = (n, d)
-                if best is not None and (
-                        victim is None or best[1] > victim[1]):
-                    victim = best
-            if victim is None:
-                break
-            node = victim[0]
-            del node.parent.children[node.chunk]
-            self.pool.decref(node.block)
+        1) LEAF nodes, deepest first; among equal depths the oldest
+        root's, and inside a root the one a depth-first descent
+        through each node's newest child meets first
+        (``_RadixNode.order``). Returns how many were freed; pinned
+        subtrees (any lane ref anywhere below) are never touched: a
+        pinned leaf is examined, left out for this call and put back."""
+        self.evict_calls += 1
+        leaves, pool = self._leaves, self.pool
+        freed, pinned = 0, []
+        while freed < need and leaves:
+            entry = heapq.heappop(leaves)
+            node = entry[2]
+            if node.children:
+                node.queued = False
+                continue
+            self.evict_candidates += 1
+            if pool.refcount(node.block) != 1:
+                pinned.append(entry)
+                continue
+            parent = node.parent
+            del parent.children[node.chunk]
+            pool.decref(node.block)
             freed += 1
-            self.evicted_blocks += 1
-        for key in [k for k, r in self._roots.items()
-                    if not r.children]:
-            del self._roots[key]
+            if not parent.children:
+                if parent.parent is None:
+                    del self._roots[parent.chunk]
+                else:
+                    self._queue_leaf(parent)
+        for entry in pinned:
+            heapq.heappush(leaves, entry)
+        self.n_nodes -= freed
+        self.evicted_blocks += freed
         return freed
 
     def tree_blocks(self) -> set:
@@ -3890,10 +3939,6 @@ class RadixBlockTree:
                 out.add(n.block)
                 stack.extend(n.children.values())
         return out
-
-    @property
-    def n_nodes(self) -> int:
-        return len(self.tree_blocks())
 
 
 __all__ = ["CacheConfig", "SamplingConfig", "DraftConfig",

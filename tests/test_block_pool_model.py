@@ -482,3 +482,293 @@ class TestRadixBlockTreeModel:
         tree.release(held)
         assert tree.evict(99) == 2       # unpinned: deepest first
         assert pool.free_count == pool.n_blocks
+
+
+def _evict_by_walk(tree, need):
+    """The eviction ``RadixBlockTree`` had before it kept an index of
+    its leaves, kept here as the ORACLE: one pass over every node of
+    every root for each block freed, the deepest tree-only leaf first
+    (``_roots``' insertion order between roots; inside a root the
+    first leaf of that depth a descent through each node's newest
+    child meets). Reads and edits the node structure only — none of
+    the index's fields. Returns the freed blocks in order."""
+    freed = []
+    while len(freed) < need:
+        victim = None
+        for root in tree._roots.values():
+            stack = [(c, 1) for c in root.children.values()]
+            best = None
+            while stack:
+                n, d = stack.pop()
+                if n.children:
+                    stack.extend((c, d + 1)
+                                 for c in n.children.values())
+                elif tree.pool.refcount(n.block) == 1:
+                    if best is None or d > best[1]:
+                        best = (n, d)
+            if best is not None and (
+                    victim is None or best[1] > victim[1]):
+                victim = best
+        if victim is None:
+            break
+        node = victim[0]
+        del node.parent.children[node.chunk]
+        tree.pool.decref(node.block)
+        freed.append(node.block)
+    for key in [k for k, r in tree._roots.items() if not r.children]:
+        del tree._roots[key]
+    return freed
+
+
+class _RadixSystem:
+    """One pool, one tree and the lanes over them, driven by a history
+    of operations that names lanes and token streams only — so two
+    systems fed one history stay comparable block for block."""
+
+    def __init__(self, n_blocks, block_size, evict):
+        self.pool = HostBlockPool(n_blocks)
+        self.tree = RadixBlockTree(self.pool, block_size)
+        self.lanes = {}
+        self._evict = evict
+        self.freed = []           # every evicted block, in order
+
+    def evict(self, need):
+        before = len(self.pool._free)
+        got = self._evict(self.tree, need)
+        self.freed += self.pool._free[before:]
+        return got
+
+    def admit(self, lid, prompt, toks, want_tail):
+        shared = self.tree.acquire(prompt, toks)
+        tail = []
+        while len(tail) < want_tail:
+            b = self.pool.alloc()
+            if b is None and self.evict(1):
+                # the server's _alloc_block_locked: cache before work
+                b = self.pool.alloc()
+            if b is None:
+                break
+            tail.append(b)
+        if len(tail) < want_tail:
+            for b in reversed(tail):
+                self.pool.decref(b)
+            self.tree.release(shared)
+            return
+        self.lanes[lid] = {"prompt": prompt, "toks": toks,
+                           "shared": shared, "tail": tail}
+
+    def insert(self, lid):
+        ln = self.lanes[lid]
+        chain = ln["shared"] + ln["tail"]
+        bs = self.tree.block_size
+        return self.tree.insert(ln["prompt"],
+                                ln["toks"][:len(chain) * bs], chain)
+
+    def retire(self, lid):
+        ln = self.lanes.pop(lid)
+        self.tree.release(ln["shared"])
+        for b in reversed(ln["tail"]):
+            self.pool.decref(b)
+
+    def snapshot(self):
+        return (list(self.pool._free), list(self.pool._refs),
+                list(self.pool._state), self.tree.tree_blocks(),
+                protomodel.tree_fingerprint(self.tree),
+                {k: (v["shared"], v["tail"])
+                 for k, v in self.lanes.items()}, self.freed)
+
+
+class TestRadixLeafIndex:
+    """``RadixBlockTree.evict`` takes its victims from an index of the
+    leaves and walks nothing. Held to the walk it replaced: the same
+    blocks freed in the same order under one random history, and a
+    bounded number of leaves examined for each block freed."""
+
+    N_PROMPTS = 4
+
+    def _streams(self, rng, bs):
+        """prompt -> token streams that share a trunk and fork at
+        different depths (divergent children under one node, at the
+        root's first chunk and deeper)."""
+        out = {}
+        for p in range(self.N_PROMPTS):
+            trunk = [rng.randrange(3, 90)
+                     for _ in range(bs * rng.randint(0, 3))]
+            fork = trunk + [rng.randrange(100, 190)
+                            for _ in range(bs * rng.randint(1, 2))]
+            out[(100 + p, 200 + p)] = [
+                base + [rng.randrange(3, 90) + 200 * i
+                        for _ in range(bs * 6)]
+                for i, base in enumerate((trunk, trunk, fork, fork))]
+        return out
+
+    @pytest.mark.parametrize("block_size", [1, 16])
+    @pytest.mark.parametrize("seed", range(8))
+    def test_index_frees_what_the_walk_frees_in_its_order(
+            self, seed, block_size):
+        rng = random.Random(7000 + 31 * seed + block_size)
+        n_blocks = rng.randint(14, 40)
+        idx = _RadixSystem(n_blocks, block_size,
+                           lambda tree, need: tree.evict(need))
+        ref = _RadixSystem(n_blocks, block_size,
+                           lambda tree, need: len(
+                               _evict_by_walk(tree, need)))
+        streams = self._streams(rng, block_size)
+        next_lane = 0
+        seen = {"pinned_leaf": 0, "pinned_interior": 0, "ties": 0,
+                "roots": 0, "evicted": 0}
+        for step in range(400):
+            r = rng.random()
+            live = sorted(idx.lanes)
+            if r < 0.3 and len(live) < 5:   # admit: map a shared
+                prompt = rng.choice(sorted(streams))  # prefix, own
+                toks = rng.choice(streams[prompt])    # a tail
+                toks = toks[:block_size * rng.choice(
+                    (0, 2, 3, 3, 4, 4, 9))]
+                tail = rng.randint(1, 3)
+                for s in (idx, ref):
+                    s.admit(next_lane, prompt, toks, tail)
+                next_lane += 1
+            elif r < 0.4 and live:    # harvest; the lane lives on,
+                lid = rng.choice(live)  # so the new tip is PINNED
+                assert idx.insert(lid) == ref.insert(lid)
+            elif r < 0.7 and live:    # the lane's decrefs, which the
+                lid = rng.choice(live)  # tree is never told about
+                if rng.random() < 0.6:  # (the server harvests first)
+                    assert idx.insert(lid) == ref.insert(lid)
+                for s in (idx, ref):
+                    s.retire(lid)
+            else:
+                need = n_blocks if r > 0.98 else rng.randint(1, 3)
+                self._census(idx, seen)
+                assert idx.evict(need) == ref.evict(need), step
+            a, b = idx.snapshot(), ref.snapshot()
+            assert a == b, (step, a, b)
+            assert idx.tree.n_nodes == len(idx.tree.tree_blocks())
+        for s in (idx, ref):
+            for lid in sorted(s.lanes):
+                s.retire(lid)
+            s.evict(n_blocks)
+            assert s.pool.free_count == n_blocks
+            assert s.tree.tree_blocks() == set() and not s.tree._roots
+        assert idx.freed == ref.freed and idx.tree.n_nodes == 0
+        assert idx.tree.evicted_blocks == len(idx.freed)
+        # the history really held what the index must get right
+        seen["evicted"] = len(idx.freed)
+        assert all(seen.values()), seen
+
+    @staticmethod
+    def _census(s, seen):
+        """Count, before an eviction, the shapes the history is meant
+        to contain (so a generator that stops making them fails)."""
+        leaves, pinned_interior = [], 0
+        seen["roots"] += len(s.tree._roots) > 1
+        for root in s.tree._roots.values():
+            stack = [(c, 1) for c in root.children.values()]
+            while stack:
+                n, d = stack.pop()
+                stack.extend((c, d + 1) for c in n.children.values())
+                pinned = s.pool.refcount(n.block) > 1
+                if not n.children:
+                    leaves.append((d, pinned))
+                elif pinned:
+                    pinned_interior += 1
+        seen["pinned_interior"] += pinned_interior
+        seen["pinned_leaf"] += sum(p for _, p in leaves)
+        free = [d for d, p in leaves if not p]
+        seen["ties"] += bool(free) and free.count(max(free)) > 1
+
+    def test_regrown_old_root_goes_before_a_younger_roots_tip(self):
+        # the tie the order key exists for: "the order in which nodes
+        # became leaves" would free the younger root's tip first
+        idx = _RadixSystem(12, 1, lambda t, n: t.evict(n))
+        ref = _RadixSystem(12, 1,
+                           lambda t, n: len(_evict_by_walk(t, n)))
+        for s in (idx, ref):
+            s.admit(0, (1,), [5, 6, 7], 3)
+            s.insert(0)
+            s.retire(0)
+            assert s.evict(1) == 1        # the old root's chain trimmed
+            s.admit(1, (2,), [8, 9, 10], 3)
+            s.insert(1)                   # a younger root, depth 3
+            s.retire(1)
+            s.admit(2, (1,), [5, 6, 7], 1)
+            s.insert(2)                   # the old root grows again
+            s.retire(2)
+            s.evict(2)
+        assert idx.freed == ref.freed
+        assert idx.snapshot() == ref.snapshot()
+
+    def test_insert_that_raises_midway_leaves_its_nodes_evictable(
+            self):
+        # a chain whose third block was already freed (a caller's
+        # fault): incref raises after two adoptions, and those two
+        # must still be reclaimable — the second is a leaf the index
+        # has to know, or its block is lost to the pool for good
+        pool = HostBlockPool(6)
+        tree = RadixBlockTree(pool, 1)
+        chain = [pool.alloc() for _ in range(3)]
+        pool.decref(chain[2])
+        with pytest.raises(BlockLifetimeError, match="refcount 0"):
+            tree.insert((1,), [5, 6, 7], chain)
+        assert tree.tree_blocks() == set(chain[:2])
+        assert tree.n_nodes == 2 and tree.adoptions == 2
+        with pytest.raises(BlockLifetimeError, match="refcount 0"):
+            tree.insert((2,), [5], [chain[2]])
+        assert (2,) not in tree._roots   # no empty root left behind
+        for b in chain[:2]:
+            pool.decref(b)
+        assert tree.evict(6) == 2
+        assert pool.free_count == 6 and not tree._roots
+
+    @staticmethod
+    def _steady_state(n_chains=51, depth=15, bs=16, n_blocks=1280):
+        """The serve cells' tree once the pool is full: 51 retired
+        generations of 15 full blocks each, 765 nodes in 1,280."""
+        pool = HostBlockPool(n_blocks)
+        tree = RadixBlockTree(pool, bs)
+
+        def adopt(p):
+            chain = []
+            for _ in range(depth):
+                b = pool.alloc()
+                if b is None:
+                    assert tree.evict(1) == 1
+                    b = pool.alloc()
+                chain.append(b)
+            toks = [(p * 7 + i) % 251 for i in range(depth * bs)]
+            assert tree.insert((p,), toks, chain) == depth
+            for b in reversed(chain):
+                pool.decref(b)
+            return toks
+
+        toks = [adopt(p) for p in range(n_chains)]
+        assert tree.n_nodes == n_chains * depth == \
+            len(tree.tree_blocks())
+        return pool, tree, adopt, toks
+
+    def test_evict_32_examines_32_leaves_and_the_pinned(self):
+        pool, tree, _, toks = self._steady_state()
+        held = [tree.acquire((p,), toks[p]) for p in range(5)]
+        assert all(len(h) == 15 for h in held)   # five pinned tips
+        c0 = tree.evict_candidates
+        assert tree.evict(32) == 32
+        assert tree.evict_candidates - c0 <= 32 + len(held)
+        assert tree.evict_calls == 1
+        assert tree.n_nodes == 765 - 32 == len(tree.tree_blocks())
+        for h in held:
+            tree.release(h)
+
+    def test_steady_eviction_examines_under_two_leaves_a_block(self):
+        pool, tree, adopt, _ = self._steady_state()
+        c0, e0 = tree.evict_candidates, tree.evicted_blocks
+        for i in range(600):
+            assert tree.evict(1) == 1
+            if i % 15 == 14:
+                adopt(1000 + i)    # a retirement: 15 blocks, of which
+                #                    the pool's free list gives some
+        freed = tree.evicted_blocks - e0
+        assert freed >= 600
+        assert (tree.evict_candidates - c0) / freed < 2
+        # the index holds no more than a node an entry
+        assert len(tree._leaves) <= tree.n_nodes

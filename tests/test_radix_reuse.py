@@ -86,7 +86,15 @@ def trained():
             cache=CacheConfig(layout="paged", block_size=BS,
                               n_blocks=NB, n_prompt_entries=E),
             **kwargs)
-    cands = rng.randint(3, V, (12, S)).astype(np.int64)
+    # candidates shaped like the training prompts (content, then the
+    # terminator to the end): the model was taught to copy up to the
+    # first terminator and stop, so only the longest, 8 or 9 tokens,
+    # can decode the 10 tokens and more that cross a block boundary
+    # (a full-length prompt, which training never ended, stops only
+    # by chance)
+    cands = rng.randint(3, V, (24, S)).astype(np.int64)
+    cands[0::2, S - 1:] = END_ID
+    cands[1::2, S - 2:] = END_ID
     p1 = cold = None
     with PagedContinuousGenerationServer(paged, executor=exe,
                                          scope=scope) as srv:
@@ -97,7 +105,8 @@ def trained():
                     and out[n - 1] == END_ID:
                 p1, cold = c, np.asarray(out)
                 break
-    assert p1 is not None, "no candidate generated 10..24 tokens"
+    assert p1 is not None, (
+        f"none of {len(cands)} candidates generated 10..24 tokens")
     return {"exe": exe, "scope": scope, "paged": paged,
             "kwargs": kwargs, "T": T, "unique_name": unique_name,
             "p1": p1, "cold": cold, "rng": rng}
@@ -168,6 +177,48 @@ class TestSessions:
                 NB, held, srv._blocks.free_count)
             assert srv._radix.evict(held) == held
             assert srv._blocks.free_count == NB
+
+    def test_server_evicts_by_its_leaf_index_and_counts_it(
+            self, trained):
+        """Plain generations fill the pool with retired chains until
+        the server's own allocations evict (``_alloc_block_locked``,
+        the admission watermark): the eviction counters reach
+        ``pool_stats()`` and the pull provider, and an evicted block
+        cost about one examined leaf."""
+        from paddle_tpu import observability as obs
+        from paddle_tpu.flags import FLAGS, set_flags
+
+        rng = np.random.RandomState(23)
+        prompts = rng.randint(3, V, (2 * NB, S)).astype(np.int64)
+        prompts[:, S - 1:] = END_ID   # 9 tokens: one full block each
+        prev = FLAGS.observability
+        set_flags({"FLAGS_observability": "metrics"})
+        try:
+            with _server(trained) as srv:
+                for p in prompts:
+                    srv.submit(p).result(120.0)
+                st = srv.pool_stats()
+                label = srv._obs_id
+                expo = obs.metrics.expose()
+                assert st["radix_nodes"] == len(
+                    srv._radix.tree_blocks())
+                held = st["radix_nodes"]
+                assert srv._radix.evict(NB) == held
+                assert srv._blocks.free_count == NB
+        finally:
+            set_flags({"FLAGS_observability": prev})
+        assert st["radix_evicted_blocks"] >= 1, st
+        assert 1 <= st["radix_evict_calls"], st
+        assert st["radix_evicted_blocks"] \
+            <= st["radix_evict_candidates"] \
+            < 3 * st["radix_evicted_blocks"], st
+        for name, key in (("evicted_blocks", "radix_evicted_blocks"),
+                          ("evict_calls", "radix_evict_calls"),
+                          ("evict_candidates",
+                           "radix_evict_candidates")):
+            line = (f'paddle_tpu_blockpool_radix_{name}_total'
+                    f'{{server="{label}"}} {st[key]}')
+            assert line in expo, line
 
     def test_best_of_n_shares_prompt_entry_greedy_identical(
             self, trained):
