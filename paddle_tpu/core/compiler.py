@@ -26,9 +26,10 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..observability.tracing import span as _span
-from .executor import (RNG_VAR, Executor, _analyze_block,
-                       _build_step_fn, _coerce_feed, _to_fetch_names,
-                       _var_np_dtype, _global_seed)
+from .executor import (_BoundStep, _CompiledBlock, _Placement,
+                       _analyze_block, _build_step_fn,
+                       _check_fetch_names, _stage_feeds,
+                       _to_fetch_names)
 from .program import Program, default_main_program
 from .scope import global_scope
 
@@ -84,6 +85,8 @@ class CompiledProgram:
         self._share_vars_from = None
         self._places = None
         self._cache: Dict = {}
+        # (config epoch, program version) -> the dp _Placement
+        self._placement_kept = (None, None)
 
     def with_data_parallel(self, loss_name=None, build_strategy=None,
                            exec_strategy=None, share_vars_from=None,
@@ -191,20 +194,21 @@ class CompiledProgram:
             # (VERDICT r3 weak #4: PP must not be a side-car object)
             return self._run_pipeline(feed, fetch_names, scope, mesh,
                                       return_numpy)
+        _check_fetch_names(block, fetch_names, feed)
         ndev = mesh.shape.get("dp", 1) if hasattr(mesh, "shape") \
             else mesh.devices.size
 
-        with _span("exe.feed"):
-            feed_arrays = {}
-            feed_specs = []
-            for name, val in feed.items():
-                arr = _coerce_feed(val, _var_np_dtype(block, name))
-                if arr.shape[0] % ndev != 0:
-                    # drop remainder like fluid's ParallelExecutor
-                    # feed split
-                    arr = arr[: (arr.shape[0] // ndev) * ndev]
-                feed_arrays[name] = arr
-                feed_specs.append((name, arr.shape, str(arr.dtype)))
+        def whole_shards(name, arr):
+            # drop the remainder rows like fluid's ParallelExecutor
+            # feed split; the declared shape is not checked here (a
+            # mismatch surfaces from the trace)
+            if arr.shape[0] % ndev != 0:
+                arr = arr[: (arr.shape[0] // ndev) * ndev]
+            return arr
+
+        placement = self._placement(mesh)
+        feed_arrays, feed_specs = _stage_feeds(feed, block, placement,
+                                               whole_shards)
         from .. import amp
         from .executor import _parallel_scope_token
 
@@ -213,14 +217,14 @@ class CompiledProgram:
                    tuple(sorted(feed_specs)), tuple(fetch_names), ndev,
                    getattr(self, "_config_epoch", 0),
                    amp.state_token(), _parallel_scope_token())
-            compiled = self._cache.get(key)
-            if compiled is None:
+            step = self._cache.get(key)
+            if step is None:
                 with _span("exe.compile"):
-                    compiled = self._compile(
+                    step = self._compile(
                         block, tuple(sorted(feed_arrays)), fetch_names,
-                        mesh)
-                self._cache[key] = compiled
-        return compiled(scope, feed_arrays, return_numpy)
+                        mesh, placement)
+                self._cache[key] = step
+        return step.dispatch(scope, feed_arrays, return_numpy)
 
     def _run_pipeline(self, feed, fetch_names, scope, mesh,
                       return_numpy):
@@ -292,15 +296,42 @@ class CompiledProgram:
                 results.append(next(rest))
         return results
 
-    def _compile(self, block, feed_names, fetch_names, mesh):
+    def _compile(self, block, feed_names, fetch_names, mesh, placement):
         mutated, const, state_out = _analyze_block(block, feed_names,
                                                    fetch_names)
         step = _build_step_fn(block, feed_names, mutated, const,
                               state_out, fetch_names,
                               on_mesh=mesh.devices.size > 1)
+        # No explicit loss scaling needed: the program computes the GLOBAL
+        # batch mean, so XLA's SPMD partitioner inserts the psum with the
+        # right coefficient -- fluid's CoeffNumDevice scale_loss_grad op
+        # (details/scale_loss_grad_op_handle.cc) is subsumed.
+        jitted = jax.jit(step, donate_argnums=(0,))
+
+        def call(*args):
+            with mesh:
+                return jitted(*args)
+
+        return _BoundStep(
+            _CompiledBlock(call, feed_names, mutated, const, state_out,
+                           fetch_names),
+            self._program, placement)
+
+    def _placement(self, mesh):
+        """The data-parallel placement policy (executor._Placement's
+        third): feeds split over 'dp', state by the param rules.
+        Rules and mesh are fixed for a placement config and a program
+        version, so it is kept for them, and with it each name's
+        target sharding: the steady state pays one dict hit + an
+        is_equivalent_to check per array, not a spec_for key-scan +
+        regex + NamedSharding build per step."""
+        token = (getattr(self, "_config_epoch", 0),
+                 self._program._version)
+        if self._placement_kept[0] == token:
+            return self._placement_kept[1]
         repl = NamedSharding(mesh, P())
-        batched = NamedSharding(mesh, P("dp"))
         rules = self._param_rules()
+        targets: Dict[str, NamedSharding] = {}
 
         def param_sharding(name, val):
             if rules is None:
@@ -311,16 +342,6 @@ class CompiledProgram:
             spec = safe_spec(mesh, rules.spec_for(name, len(shape)),
                              shape, name=name)
             return NamedSharding(mesh, spec)
-        # No explicit loss scaling needed: the program computes the GLOBAL
-        # batch mean, so XLA's SPMD partitioner inserts the psum with the
-        # right coefficient -- fluid's CoeffNumDevice scale_loss_grad op
-        # (details/scale_loss_grad_op_handle.cc) is subsumed.
-        jitted = jax.jit(step, donate_argnums=(0,))
-        # rules and mesh are fixed for this executable: memoize each
-        # name's target sharding so the steady state pays one dict hit
-        # + an is_equivalent_to check per array, not a spec_for
-        # key-scan + regex + NamedSharding build per step
-        _targets: Dict[str, NamedSharding] = {}
 
         def place(n, v):
             # A previously-placed array is kept only if its sharding
@@ -329,9 +350,9 @@ class CompiledProgram:
             # apply to state placed under the old config too (the
             # config epoch busts the executable cache, but the scope
             # arrays live on).
-            target = _targets.get(n)
+            target = targets.get(n)
             if target is None:
-                target = _targets[n] = param_sharding(n, v)
+                target = targets[n] = param_sharding(n, v)
             if _is_sharded(v):
                 eq = _sharding_matches(v, target)
                 if eq:
@@ -350,37 +371,10 @@ class CompiledProgram:
                         f"rules")
             return jax.device_put(v, target)
 
-        def run(scope, feed_arrays, return_numpy):
-            with _span("exe.state"):
-                mut = {n: scope._get(n) for n in mutated}
-                const_st = {n: scope._get(n) for n in const}
-                for n, v in list(mut.items()) + list(const_st.items()):
-                    if v is None:
-                        raise RuntimeError(
-                            f"Variable {n!r} used before "
-                            f"initialization -- run the startup "
-                            f"program first")
-                mut = {n: place(n, v) for n, v in mut.items()}
-                const_st = {n: place(n, v)
-                            for n, v in const_st.items()}
-                rng = scope._get(RNG_VAR)
-                if rng is None:
-                    rng = jax.random.PRNGKey(_global_seed[0])
-                if not _is_sharded(rng):
-                    rng = jax.device_put(rng, repl)
-            with _span("exe.feed"):
-                # feeds sharded over dp (params above: by the rules)
-                sharded_feeds = {
-                    n: jax.device_put(v, batched)
-                    for n, v in feed_arrays.items()}
-            with _span("exe.call"), mesh:
-                new_state, fetches, rng_out = jitted(
-                    mut, const_st, sharded_feeds, rng)
-            return Executor._store_and_fetch(
-                scope, new_state, rng_out, fetches, fetch_names,
-                return_numpy)
-
-        return run
+        placement = _Placement(mesh=mesh, rule=place,
+                               feeds=NamedSharding(mesh, P("dp")))
+        self._placement_kept = (token, placement)
+        return placement
 
 
 def _sharding_matches(v, target):

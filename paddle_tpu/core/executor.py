@@ -46,7 +46,7 @@ RNG_VAR = "@RNG@"
 
 _global_seed = [0]
 
-# (program uid, version, native_build) -> (reason-or-None,), the
+# (program uid, version) -> (reason-or-None,), the
 # Executor.prepare_unsupported_reason memo (wrapped in a tuple so a
 # cached None is distinguishable from a miss)
 _PREPARE_REASON_CACHE: Dict = {}
@@ -102,6 +102,9 @@ class CUDAPinnedPlace(CPUPlace):
 
 class _CompiledBlock:
     """One specialization of a block: jitted fn + binding metadata."""
+
+    # only a _CompiledScan carries any (never written to)
+    write_only_specs: Dict = {}
 
     def __init__(self, fn, feed_names, state_in, const_in, state_out,
                  fetch_names):
@@ -607,7 +610,7 @@ def _partitioned(mesh) -> bool:
 
 def _onto_mesh(v, mesh):
     """`v` unchanged unless it is committed to ONE device: then
-    replicated on `mesh` (see Executor._scope_state)."""
+    replicated on `mesh` (see _scope_state)."""
     if isinstance(v, jax.Array) and v.committed \
             and len(v.sharding.device_set) == 1 \
             and mesh.devices.size > 1:
@@ -615,6 +618,113 @@ def _onto_mesh(v, mesh):
 
         return jax.device_put(v, NamedSharding(mesh, PartitionSpec()))
     return v
+
+
+class _Placement:
+    """Where a step's arguments go: one policy, chosen once when the
+    step is bound, read by _stage_feeds, _scope_state and _scope_rng.
+
+    * `device`: a single-device program. Host feeds and host state
+      are committed to the executor's device; placed state goes back
+      to the scope, so it is moved once.
+    * `mesh` alone: a program with a bound sharding plan or under a
+      context-/expert-parallel scope. Feeds stay uncommitted (the
+      jit's shardings place them); state an earlier single-device
+      program committed to its place is replicated on the mesh, once
+      (jit refuses a committed single-device argument beside a
+      shard_map).
+    * `mesh` with `rule` and `feeds`: CompiledProgram's data-parallel
+      step. Every feed is put on the `feeds` sharding (rows split
+      over 'dp'), state goes where `rule(name, value)` says, and the
+      scope keeps what it held: the step's outputs come back placed.
+    """
+
+    __slots__ = ("device", "mesh", "rule", "feeds")
+
+    def __init__(self, device=None, mesh=None, rule=None, feeds=None):
+        self.device, self.mesh = device, mesh
+        self.rule, self.feeds = rule, feeds
+
+    @classmethod
+    def of(cls, program, place):
+        """The executor's own two policies: the program's mesh, else
+        the caller's place."""
+        mesh = _program_mesh(program)
+        return cls(device=place.device()) if mesh is None \
+            else cls(mesh=mesh)
+
+
+def _stage_feeds(feed, block, placement, check=None, np_dtypes=None):
+    """(feed arrays, their (name, shape, dtype) specs) of one call's
+    feed dict: the one `exe.feed` site of a per-call feed. Each value
+    is coerced to its variable's dtype (from `np_dtypes`, a handle's
+    bound table, else looked up in `block`), validated against the
+    declared shape, and placed. An entry point with a rule of its own
+    passes `check(name, array) -> array` in place of that validation:
+    a prepared handle holds the array to its bound spec, the
+    data-parallel path cuts the remainder rows. The specs are the
+    host arrays': what the cache keys carry."""
+    with _span("exe.feed"):
+        device, sharding = placement.device, placement.feeds
+        arrays, specs = {}, []
+        for name, val in feed.items():
+            arr = _coerce_feed(val, np_dtypes[name] if np_dtypes
+                               else _var_np_dtype(block, name))
+            if check is None:
+                _check_feed_shape(block, name, arr)
+            else:
+                arr = check(name, arr)
+            specs.append((name, tuple(arr.shape), str(arr.dtype)))
+            if sharding is not None:
+                arr = jax.device_put(arr, sharding)
+            elif device is not None and not isinstance(arr, jax.Array):
+                # one explicit transfer to the caller's place
+                arr = jax.device_put(arr, device)
+            arrays[name] = arr
+        return arrays, specs
+
+
+def _scope_state(scope, names, placement):
+    """Gather scope values for `names`, each where `placement` (see
+    _Placement) wants it."""
+    device, mesh, rule = placement.device, placement.mesh, placement.rule
+    out = {}
+    for n in names:
+        v = scope._get(n)
+        if v is None:
+            raise RuntimeError(
+                f"Variable {n!r} is used before initialization -- "
+                f"run the startup program first")
+        if rule is not None:
+            v = rule(n, v)
+        elif device is not None:
+            if not isinstance(v, jax.Array):
+                v = jax.device_put(np.asarray(v), device)
+                scope._set(n, v)
+        else:
+            placed = _onto_mesh(v, mesh)
+            if placed is not v:
+                scope._set(n, placed)
+                v = placed
+        out[n] = v
+    return out
+
+
+def _scope_rng(scope, program, placement):
+    """The step PRNG key: the scope's, else seeded from the program /
+    global seed; placed like the state (a `rule` places it under its
+    name, a mesh takes over a key held on one device)."""
+    rng = scope._get(RNG_VAR)
+    held = rng is not None
+    if not held:
+        prog_seed = getattr(program, "_seed", None)
+        rng = jax.random.PRNGKey(
+            prog_seed if prog_seed is not None else _global_seed[0])
+    if placement.rule is not None:
+        return placement.rule(RNG_VAR, rng)
+    if held and placement.mesh is not None:
+        return _onto_mesh(rng, placement.mesh)
+    return rng
 
 
 def _mesh_token(mesh):
@@ -657,6 +767,17 @@ def _var_np_dtype(block, name, default=np.float32):
     if v is None or v.dtype is None:
         return default
     return to_np_dtype(v.dtype)
+
+
+def _check_fetch_names(block, fetch_names, feed):
+    """A fetch target is a variable of the program or one of the
+    call's feeds: anything else would surface from inside the trace,
+    if at all."""
+    for name in fetch_names:
+        if not block.has_var(name) and name not in feed:
+            raise KeyError(
+                f"fetch target {name!r} does not exist in the "
+                f"program")
 
 
 def _check_feed_shape(block, name, value):
@@ -734,11 +855,6 @@ def _scan_fallback_reason(program):
     if isinstance(program, CompiledProgram):
         return ("CompiledProgram (data-parallel / inference-compiled) "
                 "programs run through their own per-step path")
-    from ..flags import FLAGS
-
-    if FLAGS.native_build:
-        return ("FLAGS_native_build executes C++-built programs one "
-                "step at a time")
     host_op = _first_host_effect_op(program.global_block)
     if host_op is not None:
         return (f"op {host_op!r} bridges to the host "
@@ -843,6 +959,71 @@ def _note_cost_model(program, fn, kind, feed_specs, compiled=None,
                                   write_only=write_only)
     obs_costmodel.note_executable(program, fn, kind,
                                   feed_specs=feed_specs, avals=avals)
+
+
+class _BoundStep:
+    """A resolved executable bound to what a dispatch needs beside
+    the scope and the staged feed: the program (its `_seed` keys a
+    scope that holds no key yet) and the placement policy. The ONE
+    thing that runs an executable: Executor.run, run_steps,
+    PreparedProgram.run and the data-parallel CompiledProgram each
+    look one up (or hold one) and call `dispatch`."""
+
+    __slots__ = ("compiled", "program", "placement")
+
+    def __init__(self, compiled, program, placement):
+        self.compiled = compiled
+        self.program = program
+        self.placement = placement
+
+    def gather(self, scope):
+        """(mutable state, constant state, key) as the executable
+        takes them, from `scope`."""
+        c = self.compiled
+        state = _scope_state(scope, c.state_in, self.placement)
+        const = _scope_state(scope, c.const_in, self.placement)
+        for n, spec in c.write_only_specs.items():
+            # a scan's write-only carry slot: step 1 overwrites the
+            # zeros, the carry just needs a step-invariant structure
+            state[n] = jnp.zeros(spec.shape, spec.dtype)
+        return state, const, _scope_rng(scope, self.program,
+                                        self.placement)
+
+    def dispatch(self, scope, feed_arrays, return_numpy):
+        """Run the executable once on staged feeds: gather
+        (`exe.state`), the asynchronous call (`exe.call`), the new
+        state and the advanced key back to the scope (`exe.store`)
+        and, with `return_numpy`, the host blocked on the device for
+        the fetches (`exe.fetch`)."""
+        from ..flags import FLAGS
+
+        c = self.compiled
+        with _span("exe.state"):
+            state, const, rng = self.gather(scope)
+        with _span("exe.call"):
+            new_state, fetches, rng_out = c.fn(state, const,
+                                               feed_arrays, rng)
+        with _span("exe.store"):
+            if FLAGS.check_nan_inf:
+                _check_nan_inf(new_state, fetches, c.fetch_names)
+            scope._set(RNG_VAR, rng_out)
+            for n, v in new_state.items():
+                scope._set(n, v)
+        if not return_numpy:
+            return list(fetches)
+        with _span("exe.fetch"):
+            return [np.asarray(v) for v in fetches]
+
+    def lower(self, scope, feed_avals):
+        """The executable's jax Lowered at `feed_avals` and the
+        scope's current state (diagnostics: Executor.compiled_text,
+        PreparedProgram.lowered_text): the one kept from an
+        ahead-of-time compile, else lowered again."""
+        aot = getattr(self.compiled, "_aot", None)
+        if aot is not None:
+            return aot[0]
+        state, const, rng = jax.tree.map(_as_aval, self.gather(scope))
+        return self.compiled.fn.lower(state, const, feed_avals, rng)
 
 
 class Executor:
@@ -1032,97 +1213,53 @@ class Executor:
         if isinstance(program, CompiledProgram):
             return program._run(self, feed, fetch_list, scope, return_numpy)
         scope = scope or global_scope()
-        with _span("exe.feed"):
-            feed = dict(feed or {})
-            fetch_names = _to_fetch_names(fetch_list)
-            block = program.global_block
-            for name in fetch_names:
-                if not block.has_var(name) and name not in feed:
-                    raise KeyError(
-                        f"fetch target {name!r} does not exist in the "
-                        f"program")
-            for name, value in feed.items():
-                _check_feed_shape(block, name, value)
-
-            mesh = _program_mesh(program)
-            device = self.place.device() if mesh is None else None
-            feed_arrays = {}
-            feed_specs = []
-            for name, val in feed.items():
-                arr = _coerce_feed(val, _var_np_dtype(block, name))
-                feed_specs.append((name, arr.shape, str(arr.dtype)))
-                # commit feeds to the caller's place (one explicit
-                # transfer); a mesh program's shardings place them
-                # instead
-                if device is not None \
-                        and not isinstance(arr, jax.Array):
-                    arr = jax.device_put(arr, device)
-                feed_arrays[name] = arr
-            if any(op.type == "go" for op in block.ops):
-                self._launch_go_ops(block, scope, feed_arrays)
-
-        from .. import amp
-        from ..flags import FLAGS
-
-        if FLAGS.native_build:
-            # the train-step XLA program is BUILT IN C++ (xla_train
-            # kernel registry) and consumed in-process via StableHLO;
-            # the traced path below stays the cross-check oracle
-            if amp.enabled():
-                raise RuntimeError(
-                    "FLAGS_native_build does not compose with AMP "
-                    "yet; the native kernel slice builds the block "
-                    "at its declared dtypes")
-            nkey = ("native", program._uid, program._version,
-                    tuple(sorted(feed_specs)), tuple(fetch_names),
-                    scope._uid)
-            step = self._cache.get(nkey) if use_program_cache \
-                else None
-            if step is None:
-                from ..native.hlo_exec import NativeBuiltStep
-
-                step = NativeBuiltStep(program, scope, feed_arrays,
-                                       fetch_names)
-                self.compile_count += 1
-                if use_program_cache:
-                    self._cache[nkey] = step
-            else:
-                self.cache_hit_count += 1
-            fetched = step.run(scope, feed_arrays)
-            out = [fetched[n] for n in fetch_names]
-            if FLAGS.check_nan_inf:
-                _check_nan_inf(
-                    {n: scope._get(n) for n in step.state_out_names},
-                    out, fetch_names)
-            if return_numpy:
-                out = [np.asarray(v) for v in out]
-            return out
-
+        feed = dict(feed or {})
+        fetch_names = _to_fetch_names(fetch_list)
+        block = program.global_block
+        _check_fetch_names(block, fetch_names, feed)
+        placement = _Placement.of(program, self.place)
+        feed_arrays, feed_specs = _stage_feeds(feed, block, placement)
+        if self.prepare_unsupported_reason(program) is not None:
+            # `go` ops are what stands in a prepared handle's way: the
+            # memo per program version answers without a walk over
+            # the ops every dispatch
+            self._launch_go_ops(block, scope, feed_arrays)
         with _span("exe.lookup"):
+            step = self._bound_step(
+                program, scope, feed_arrays, feed_specs, fetch_names,
+                placement, use_program_cache=use_program_cache)
+        return step.dispatch(scope, feed_arrays, return_numpy)
+
+    def _bound_step(self, program, scope, feed_arrays, feed_specs,
+                    fetch_names, placement, steps=None, stacked=False,
+                    use_program_cache=True) -> _BoundStep:
+        """The lookup run, run_steps and PreparedProgram._bind share:
+        the in-memory key of the block (or, with `steps`, of the
+        K-step scan), the cache, and on a miss the one call of the
+        resolver (disk rehydration, else trace + compile)."""
+        if steps is None:
             key = self._block_cache_key(program, feed_specs,
                                         fetch_names)
-            compiled = self._cache.get(key) if use_program_cache \
-                else None
-            if compiled is None:
+        else:
+            key = self._scan_cache_key(program, feed_specs,
+                                       fetch_names, steps, stacked)
+        compiled = self._cache.get(key) if use_program_cache else None
+        if compiled is not None:
+            self.cache_hit_count += 1
+        else:
+            block = program.global_block
+            specs = tuple(sorted(feed_specs))
+            if steps is None:
                 compiled = self._resolve_block(
-                    program, block, tuple(sorted(feed_specs)),
-                    fetch_names, scope, feed_arrays)
-                if use_program_cache:
-                    self._cache[key] = compiled
+                    program, block, specs, fetch_names, scope,
+                    feed_arrays)
             else:
-                self.cache_hit_count += 1
-
-        with _span("exe.state"):
-            mut = self._scope_state(scope, compiled.state_in, device,
-                                    mesh)
-            const_st = self._scope_state(scope, compiled.const_in,
-                                         device, mesh)
-            rng = self._scope_rng(scope, program, mesh)
-        with _span("exe.call"):
-            new_state, fetches, rng_out = compiled.fn(
-                mut, const_st, feed_arrays, rng)
-        return self._store_and_fetch(scope, new_state, rng_out,
-                                     fetches, fetch_names, return_numpy)
+                compiled = self._resolve_scan(
+                    program, block, specs, fetch_names, scope, steps,
+                    stacked, feed_arrays, placement)
+            if use_program_cache:
+                self._cache[key] = compiled
+        return _BoundStep(compiled, program, placement)
 
     def compiled_text(self, program, feed, fetch_list,
                       scope: Optional[Scope] = None) -> str:
@@ -1136,81 +1273,19 @@ class Executor:
         answers."""
         scope = scope or global_scope()
         block = program.global_block
-        fetch_names = _to_fetch_names(fetch_list)
         feed_specs, feed_avals = [], {}
         for name, val in dict(feed).items():
             arr = _coerce_feed(val, _var_np_dtype(block, name))
             feed_specs.append((name, arr.shape, str(arr.dtype)))
             feed_avals[name] = _as_aval(arr)
         compiled = self._cache.get(self._block_cache_key(
-            program, feed_specs, fetch_names))
+            program, feed_specs, _to_fetch_names(fetch_list)))
         if compiled is None:
             raise RuntimeError("compiled_text: this step has not run "
                                "on this executor yet")
-        mesh = _program_mesh(program)
-        device = self.place.device() if mesh is None else None
-        state = self._scope_state(scope, compiled.state_in, device, mesh)
-        const = self._scope_state(scope, compiled.const_in, device, mesh)
-        rng = self._scope_rng(scope, program, mesh)
-        state, const, rng = jax.tree.map(_as_aval, (state, const, rng))
-        return compiled.fn.lower(state, const, feed_avals,
-                                 rng).compile().as_text()
-
-    @staticmethod
-    def _store_and_fetch(scope, new_state, rng_out, fetches,
-                         fetch_names, return_numpy):
-        """The end of every dispatch: the new state and the advanced
-        key go back to the scope (`exe.store`), and with
-        `return_numpy` the host then blocks on the device for the
-        fetches (`exe.fetch`)."""
-        from ..flags import FLAGS
-
-        with _span("exe.store"):
-            if FLAGS.check_nan_inf:
-                _check_nan_inf(new_state, fetches, fetch_names)
-            scope._set(RNG_VAR, rng_out)
-            for n, v in new_state.items():
-                scope._set(n, v)
-        if not return_numpy:
-            return list(fetches)
-        with _span("exe.fetch"):
-            return [np.asarray(v) for v in fetches]
-
-    # ------------------------------------------------------------------
-    def _scope_state(self, scope, names, device, mesh=None):
-        """Gather scope values for `names`. Host arrays are committed
-        to `device` once. For a mesh program (`device` None, `mesh`
-        set) an array an earlier single-device program committed to
-        its place is re-placed replicated on the mesh -- jit refuses a
-        committed single-device argument beside a shard_map."""
-        out = {}
-        for n in names:
-            v = scope._get(n)
-            if v is None:
-                raise RuntimeError(
-                    f"Variable {n!r} is used before initialization -- "
-                    f"run the startup program first")
-            if device is not None and not isinstance(v, jax.Array):
-                v = jax.device_put(np.asarray(v), device)
-                scope._set(n, v)
-            elif mesh is not None:
-                placed = _onto_mesh(v, mesh)
-                if placed is not v:
-                    scope._set(n, placed)
-                    v = placed
-            out[n] = v
-        return out
-
-    @staticmethod
-    def _scope_rng(scope, program, mesh=None):
-        """The step PRNG key: the scope's, else seeded from the
-        program / global seed."""
-        rng = scope._get(RNG_VAR)
-        if rng is None:
-            prog_seed = getattr(program, "_seed", None)
-            return jax.random.PRNGKey(
-                prog_seed if prog_seed is not None else _global_seed[0])
-        return _onto_mesh(rng, mesh) if mesh is not None else rng
+        step = _BoundStep(compiled, program,
+                          _Placement.of(program, self.place))
+        return step.lower(scope, feed_avals).compile().as_text()
 
     # ------------------------------------------------------------------
     def run_steps(self, program: Optional[Program] = None, feed=None,
@@ -1245,7 +1320,7 @@ class Executor:
         calls with the named reason recorded on
         `self.last_run_steps_fallback` (None when the scan path ran):
         host-bridging ops (io_callback readers, py_func, go, print/
-        save/load, PS send/recv), CompiledProgram, FLAGS_native_build.
+        save/load, PS send/recv), CompiledProgram.
         The scan executable is cached under its own key (program
         _uid/_version, per-step feed specs, fetch set, K, AMP and
         parallel-scope tokens), so Pass.apply version bumps invalidate
@@ -1291,59 +1366,31 @@ class Executor:
 
         fetch_names = _to_fetch_names(fetch_list)
         block = program.global_block
-        first_feed = feeds_seq[0] if feeds_seq is not None else feed
-        for name in fetch_names:
-            if not block.has_var(name) and name not in first_feed:
-                raise KeyError(
-                    f"fetch target {name!r} does not exist in the "
-                    f"program")
-        mesh = _program_mesh(program)
-        device = self.place.device() if mesh is None else None
-
-        with _span("exe.feed"):
-            feed_arrays, feed_specs = self._stage_scan_feeds(
-                block, feed, feeds_seq, device)
-
+        stacked = feeds_seq is not None
+        _check_fetch_names(block, fetch_names,
+                           feeds_seq[0] if stacked else feed)
+        placement = _Placement.of(program, self.place)
+        if stacked:
+            feed_arrays, feed_specs = self._stage_stacked_feeds(
+                block, feeds_seq, placement.device)
+        else:
+            feed_arrays, feed_specs = _stage_feeds(feed, block,
+                                                   placement)
         with _span("exe.lookup"):
-            key = self._scan_cache_key(program, feed_specs,
-                                       fetch_names, steps,
-                                       feeds_seq is not None)
-            compiled = self._cache.get(key) if use_program_cache \
-                else None
-            if compiled is None:
-                compiled = self._resolve_scan(
-                    program, block, tuple(sorted(feed_specs)),
-                    fetch_names, scope, steps, feeds_seq is not None,
-                    feed_arrays, device)
-                if use_program_cache:
-                    self._cache[key] = compiled
-            else:
-                self.cache_hit_count += 1
-
-        with _span("exe.state"):
-            carry = self._scope_state(scope, compiled.state_in, device,
-                                      mesh)
-            const_st = self._scope_state(scope, compiled.const_in,
-                                         device, mesh)
-            for n, spec in compiled.write_only_specs.items():
-                # zeros placeholder: step 1 overwrites it; the carry
-                # just needs a step-invariant structure
-                carry[n] = jnp.zeros(spec.shape, spec.dtype)
-            rng = self._scope_rng(scope, program, mesh)
-        with _span("exe.call"):
-            fin_state, ys, rng_out = compiled.fn(
-                carry, const_st, feed_arrays, rng)
-        return self._store_and_fetch(scope, fin_state, rng_out, ys,
-                                     fetch_names, return_numpy)
+            step = self._bound_step(
+                program, scope, feed_arrays, feed_specs, fetch_names,
+                placement, steps, stacked, use_program_cache)
+        return step.dispatch(scope, feed_arrays, return_numpy)
 
     @staticmethod
-    def _stage_scan_feeds(block, feed, feeds_seq, device):
+    def _stage_stacked_feeds(block, feeds_seq, device):
         """(feed arrays, PER-STEP feed specs: what each scan body
-        sees) of a run_steps call: K batches stacked and staged in
-        one transfer, or the one shared batch."""
+        sees) of a run_steps call with K batches: stacked on the host
+        and staged in one transfer. The second `exe.feed` site, beside
+        _stage_feeds."""
         feed_arrays = {}
         feed_specs = []
-        if feeds_seq is not None:
+        with _span("exe.feed"):
             for name in sorted(feeds_seq[0]):
                 dt = _var_np_dtype(block, name)
                 cols = [_coerce_feed(f[name], dt) for f in feeds_seq]
@@ -1358,15 +1405,6 @@ class Executor:
                 feed_arrays[name] = arr
                 feed_specs.append(
                     (name, tuple(arr.shape[1:]), str(arr.dtype)))
-        else:
-            for name, val in feed.items():
-                _check_feed_shape(block, name, val)
-                arr = _coerce_feed(val, _var_np_dtype(block, name))
-                feed_specs.append(
-                    (name, tuple(arr.shape), str(arr.dtype)))
-                if device is not None and not isinstance(arr, jax.Array):
-                    arr = jax.device_put(arr, device)
-                feed_arrays[name] = arr
         return feed_arrays, feed_specs
 
     def _warn_scan_fallback(self, program, reason):
@@ -1417,26 +1455,19 @@ class Executor:
         per-REQUEST errors (bad feed shape) from a prepared handle
         propagate like Executor.run's would, instead of being
         mistaken for 'program not preparable'. Memoized per
-        (program, version, native-build flag): hot serving paths ask
-        on every request and must not re-walk the op list."""
-        from ..flags import FLAGS
-
+        (program, version): hot serving paths ask on every request
+        and must not re-walk the op list."""
         from .compiler import CompiledProgram
 
         if isinstance(program, CompiledProgram):
             return "CompiledProgram runs through its own path"
-        key = (program._uid, program._version, FLAGS.native_build)
+        key = (program._uid, program._version)
         cached = _PREPARE_REASON_CACHE.get(key)
         if cached is not None:
             return cached[0]
-        if FLAGS.native_build:
-            reason = ("FLAGS_native_build steps carry their own "
-                      "context")
-        elif any(op.type == "go"
-                 for op in program.global_block.ops):
-            reason = "`go` ops launch host threads per run"
-        else:
-            reason = None
+        reason = "`go` ops launch host threads per run" \
+            if any(op.type == "go" for op in program.global_block.ops) \
+            else None
         if len(_PREPARE_REASON_CACHE) > 512:
             _PREPARE_REASON_CACHE.clear()
         _PREPARE_REASON_CACHE[key] = (reason,)
@@ -1502,11 +1533,6 @@ class Executor:
         feed specs + fetch set + AMP/parallel-scope tokens + backend +
         device count + jax/jaxlib versions — any toolchain or program
         change is a clean miss."""
-        from ..flags import FLAGS
-
-        if FLAGS.native_build:
-            # native-built steps have their own C++ artifact path
-            return None, None
         from .compile_cache import (active_cache, canonical_digest,
                                     version_token)
 
@@ -1584,7 +1610,7 @@ class Executor:
 
     @_compile_spanned
     def _resolve_scan(self, program, block, feed_specs, fetch_names,
-                      scope, steps, stacked, feed_arrays, device):
+                      scope, steps, stacked, feed_arrays, placement):
         """run_steps analogue of _resolve_block — the K-specialized
         scan executable is the most expensive single compile in the
         repo, so it benefits most from the disk warm start."""
@@ -1613,7 +1639,7 @@ class Executor:
         compiled = self._compile_steps(
             program, block, tuple(sorted(feed_arrays)), fetch_names,
             scope, steps, stacked=stacked, feed_arrays=feed_arrays,
-            device=device, aot=dcache is not None)
+            placement=placement, aot=dcache is not None)
         self.compile_count += 1
         _record_compile_event("scan", program, "cold", t0,
                               compiled.fn)
@@ -1727,7 +1753,7 @@ class Executor:
 
     # ------------------------------------------------------------------
     def _compile_steps(self, program, block, feed_names, fetch_names,
-                       scope, steps, stacked, feed_arrays, device,
+                       scope, steps, stacked, feed_arrays, placement,
                        aot=False):
         """Lower the SAME _build_step_fn body run() compiles -- the
         step-key advance included -- into one jitted lax.scan over K
@@ -1770,8 +1796,8 @@ class Executor:
         # shapes of the write-only carry slots come from one abstract
         # eval of the single step (dtypes canonicalized the way jit
         # will see them)
-        mut_ex = self._scope_state(scope, mutated, device)
-        const_ex = self._scope_state(scope, const, device)
+        mut_ex = _scope_state(scope, mutated, placement)
+        const_ex = _scope_state(scope, const, placement)
         rng_ex = scope._get(RNG_VAR)
         if rng_ex is None:
             rng_ex = jax.random.PRNGKey(0)
@@ -1803,7 +1829,7 @@ class Executor:
             jitted = jax.jit(multi, donate_argnums=donate,
                              in_shardings=plan_sh[0],
                              out_shardings=plan_sh[1])
-        elif device is not None:
+        elif placement.device is not None:
             layouts = _pin_state_layout_formats(
                 multi, carry_ex, const_ex, feed_arrays, rng_ex,
                 self.place, carry_names, len(fetch_names))
@@ -1904,12 +1930,13 @@ class PreparedProgram:
     Executor::Prepare builds the op list once, RunPreparedContext
     replays it, framework/executor.cc:337,377).
 
-    Binds ONCE: the resolved executable (through the same in-memory /
-    on-disk caches as Executor.run, so a warmed bucket is shared), the
-    feed order + coercion dtypes, the scope-gather name lists, and the
-    device commitment. `run(feed)` then goes straight from feed dict
-    to executable call — no fetch parsing, no key hashing, no feed
-    validation, no block analysis.
+    Binds ONCE: a _BoundStep (`step`: the resolved executable, through
+    the same lookup and the same in-memory / on-disk caches as
+    Executor.run, so a warmed bucket is shared, with its scope-gather
+    name lists and its placement) and the feed coercion dtypes and
+    specs. `run(feed)` then goes straight from feed dict to the
+    dispatch every entry point shares — no fetch parsing, no key
+    hashing, no declared-shape validation, no block analysis.
 
     Staleness guards stay cheap but present: every run() compares the
     program `_version` (Pass.apply bumps it) and the AMP /
@@ -1961,13 +1988,9 @@ class PreparedProgram:
             self._feed_example = {
                 name: np.zeros(shape, _dtype_from_str(dt))
                 for name, shape, dt in self._bind_specs}
-        for name in self.fetch_names:
-            if not block.has_var(name) \
-                    and name not in self._feed_example:
-                raise KeyError(
-                    f"fetch target {name!r} does not exist in the "
-                    f"program")
+        _check_fetch_names(block, self.fetch_names, self._feed_example)
         self._fallback_reason = None
+        self.step = None
         if self._steps is not None:
             reason = _scan_fallback_reason(program)
             if reason is not None:
@@ -1975,53 +1998,17 @@ class PreparedProgram:
                 exe._warn_scan_fallback(program, reason)
                 self._snapshot_tokens()
                 return
-        mesh = self._mesh = _program_mesh(program)
-        device = self._device = exe.place.device() if mesh is None \
-            else None
-
-        feed_arrays = {}
-        feed_specs = []
-        np_dtypes = {}
-        for name, val in self._feed_example.items():
-            dt = _var_np_dtype(block, name)
-            np_dtypes[name] = dt
-            arr = _coerce_feed(val, dt)
-            _check_feed_shape(block, name, arr)
-            if device is not None and not isinstance(arr, jax.Array):
-                arr = jax.device_put(arr, device)
-            feed_arrays[name] = arr
-            feed_specs.append((name, tuple(arr.shape),
-                               str(arr.dtype)))
-        # the same in-memory keys run()/run_steps() use (one shared
-        # builder per kind), so prepared handles, plain runs, and
-        # serving clones share executables
-        if self._steps is None:
-            key = exe._block_cache_key(program, feed_specs,
-                                       self.fetch_names)
-            compiled = exe._cache.get(key)
-            if compiled is None:
-                compiled = exe._resolve_block(
-                    program, block, tuple(sorted(feed_specs)),
-                    self.fetch_names, scope, feed_arrays)
-                exe._cache[key] = compiled
-            else:
-                exe.cache_hit_count += 1
-        else:
-            key = exe._scan_cache_key(program, feed_specs,
-                                      self.fetch_names, self._steps,
-                                      False)
-            compiled = exe._cache.get(key)
-            if compiled is None:
-                compiled = exe._resolve_scan(
-                    program, block, tuple(sorted(feed_specs)),
-                    self.fetch_names, scope, self._steps, False,
-                    feed_arrays, device)
-                exe._cache[key] = compiled
-            else:
-                exe.cache_hit_count += 1
-        self._compiled = compiled
-        self._np_dtypes = {n: np_dtypes.get(n, _var_np_dtype(block, n))
-                           for n in compiled.feed_names}
+        placement = _Placement.of(program, exe.place)
+        feed_arrays, feed_specs = _stage_feeds(self._feed_example,
+                                               block, placement)
+        # the lookup run()/run_steps() use, under the same in-memory
+        # keys: prepared handles, plain runs and serving clones share
+        # executables
+        self.step = exe._bound_step(
+            program, scope, feed_arrays, feed_specs, self.fetch_names,
+            placement, self._steps)
+        self._np_dtypes = {n: _var_np_dtype(block, n)
+                           for n in self.step.compiled.feed_names}
         # spec check table: shapes strict, dtypes compared AFTER
         # canonicalization so a numpy-int64 example and a jax-int32
         # array at run time agree (jit canonicalizes both the same)
@@ -2041,22 +2028,23 @@ class PreparedProgram:
         only: kernel routing happens at trace time, so this is where
         a caller reads which Pallas (Mosaic `tpu_custom_call`)
         kernels a step really carries."""
-        c = self._compiled
-        aot = getattr(c, "_aot", None)
-        if aot is not None:
-            return aot[0].as_text()
-        exe = self.exe
-        state = exe._scope_state(self.scope, c.state_in, self._device,
-                                 self._mesh)
-        const = exe._scope_state(self.scope, c.const_in, self._device,
-                                 self._mesh)
-        rng = exe._scope_rng(self.scope, self.program, self._mesh)
-        state, const, rng = jax.tree.map(_as_aval, (state, const, rng))
-        if isinstance(c, _CompiledScan):
-            state.update(c.write_only_specs)
         feeds = {name: jax.ShapeDtypeStruct(shape, _dtype_from_str(dt))
                  for name, (shape, dt) in self._check_specs.items()}
-        return c.fn.lower(state, const, feeds, rng).as_text()
+        return self.step.lower(self.scope, feeds).as_text()
+
+    def _check_spec(self, name, arr):
+        """_stage_feeds check of a prepared handle: the bound spec,
+        strictly. The declared shape was validated once, at bind; a
+        prepared run does not validate it again."""
+        want_shape, want_dt = self._check_specs[name]
+        got_dt = str(jax.dtypes.canonicalize_dtype(arr.dtype))
+        if tuple(arr.shape) != want_shape or got_dt != want_dt:
+            raise ValueError(
+                f"prepared program was bound for feed "
+                f"{name!r} spec {want_shape}/{want_dt} but got "
+                f"{tuple(arr.shape)}/{got_dt}; prepare() again "
+                f"for new shapes (or use Executor.run)")
+        return arr
 
     def run(self, feed=None, return_numpy: bool = True):
         """The hot loop. Semantics match Executor.run (or run_steps
@@ -2085,47 +2073,20 @@ class PreparedProgram:
                 return_numpy, True)
         if self._steps is not None:
             exe.last_run_steps_fallback = None
-        c = self._compiled
-        scope, device = self.scope, self._device
-        with _span("exe.feed"):
-            feed = feed or {}
-            if set(feed) != set(c.feed_names):
-                unknown = sorted(set(feed) - set(c.feed_names))
-                missing = sorted(set(c.feed_names) - set(feed))
-                raise ValueError(
-                    f"prepared program binds feeds "
-                    f"{sorted(c.feed_names)}; got unknown={unknown} "
-                    f"missing={missing}")
-            feed_arrays = {}
-            for name in c.feed_names:
-                arr = _coerce_feed(feed[name], self._np_dtypes[name])
-                want_shape, want_dt = self._check_specs[name]
-                got_dt = str(jax.dtypes.canonicalize_dtype(arr.dtype))
-                if tuple(arr.shape) != want_shape or got_dt != want_dt:
-                    raise ValueError(
-                        f"prepared program was bound for feed "
-                        f"{name!r} spec {want_shape}/{want_dt} but got "
-                        f"{tuple(arr.shape)}/{got_dt}; prepare() again "
-                        f"for new shapes (or use Executor.run)")
-                if device is not None \
-                        and not isinstance(arr, jax.Array):
-                    arr = jax.device_put(arr, device)
-                feed_arrays[name] = arr
-
-        with _span("exe.state"):
-            mut = exe._scope_state(scope, c.state_in, device,
-                                   self._mesh)
-            const_st = exe._scope_state(scope, c.const_in, device,
-                                        self._mesh)
-            rng = exe._scope_rng(scope, self.program, self._mesh)
-            if isinstance(c, _CompiledScan):
-                for n, spec in c.write_only_specs.items():
-                    mut[n] = jnp.zeros(spec.shape, spec.dtype)
-        with _span("exe.call"):
-            new_state, out, rng_out = c.fn(mut, const_st, feed_arrays,
-                                           rng)
-        return exe._store_and_fetch(scope, new_state, rng_out, out,
-                                    c.fetch_names, return_numpy)
+        step = self.step
+        feed = feed or {}
+        feed_names = step.compiled.feed_names
+        if set(feed) != set(feed_names):
+            unknown = sorted(set(feed) - set(feed_names))
+            missing = sorted(set(feed_names) - set(feed))
+            raise ValueError(
+                f"prepared program binds feeds "
+                f"{sorted(feed_names)}; got unknown={unknown} "
+                f"missing={missing}")
+        feed_arrays, _ = _stage_feeds(
+            feed, self.program.global_block, step.placement,
+            self._check_spec, self._np_dtypes)
+        return step.dispatch(self.scope, feed_arrays, return_numpy)
 
 
 class PreparedCache:
@@ -2151,7 +2112,7 @@ class PreparedCache:
     def lookup(self, feed) -> Optional["PreparedProgram"]:
         """The PreparedProgram for this feed's spec, binding it on
         first sight, or None when the program takes the per-call
-        Executor.run path (go ops / CompiledProgram / native build —
+        Executor.run path (go ops / CompiledProgram —
         checked up front so a per-REQUEST feed error raises exactly
         like Executor.run's validation would). Normalizes non-array
         feed values in place."""

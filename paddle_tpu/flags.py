@@ -132,11 +132,6 @@ _DEFS = {
     # Python oracle on every compile; raise on divergence instead of
     # silently preferring either side
     "native_verify": (_as_bool, False, True),
-    # build the Executor's train-step XLA computation in C++ (the
-    # xla_train kernel registry) instead of tracing it in Python; the
-    # compiled program is consumed in-process via StableHLO. Raises a
-    # named error when the block uses ops outside the native slice.
-    "native_build": (_as_bool, False, True),
     # memory / allocator family (XLA buffer assignment owns this)
     "eager_delete_scope": (_as_bool, True, False),
     "eager_delete_tensor_gb": (float, -1.0, False),

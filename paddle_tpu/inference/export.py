@@ -425,8 +425,7 @@ def export_train_program(main_program, scope, example_feeds,
         arr = np.asarray(arr)
         # canonicalize like the jax runtime (int64->int32 etc. under
         # the default x64-disabled config): the manifest dtypes define
-        # the computation's PARAMETER types, and the in-process
-        # consumer (FLAGS_native_build) feeds jax-canonical buffers
+        # the computation's PARAMETER types
         arr = np.ascontiguousarray(
             arr.astype(_jax.dtypes.canonicalize_dtype(arr.dtype)))
         fname = f"data/{i:03d}.bin"

@@ -334,8 +334,7 @@ def emit_token_step(src, step_logits_v, positions, tgt_buf, finished,
     (inference/serving.py), which normalizes everything after the
     first end_id to the -1 sentinel either way. Expressed with
     reduce_sum/elementwise_min/greater_than only, all inside the
-    native xla_train kernel slice (FLAGS_native_build builds these
-    programs too)."""
+    native xla_train kernel slice."""
     tok = layers.cast(layers.argmax(step_logits_v, axis=-1), "int64")
     not_fin = layers.elementwise_sub(
         layers.fill_constant_batch_size_like(

@@ -2216,8 +2216,8 @@ int main(int argc, char** argv) {
 
   if (hlo_mode) {
     // dump the natively-built computation as a serialized
-    // HloModuleProto; the Python Executor (FLAGS_native_build)
-    // converts it to StableHLO and compiles/executes it in-process
+    // HloModuleProto, for a caller that wants to inspect it or
+    // compile it with a runtime of its own
     if (argc < 4) fail("--hlo needs an output path");
     std::string blob = comp.proto().SerializeAsString();
     std::ofstream out(argv[3], std::ios::binary);

@@ -13,9 +13,10 @@ static side:
   when the executable exposes it) per compiled executable, keyed on
   ``(Program.fingerprint(), feed specs, kind)``. Captured by the
   Executor's compile hook (core/executor.py ``_resolve_block`` /
-  ``_resolve_scan``) — compiles are rare by design, so snapshot cost
-  rides the compile budget, never a request. Feature detection
-  follows the hlo_exec.py discipline across jaxlib spellings:
+  ``_resolve_scan``, the miss side of the one lookup every entry
+  point dispatches through) — compiles are rare by design, so
+  snapshot cost rides the compile budget, never a request. The
+  analysis surface is feature-detected across jaxlib spellings:
 
   - an AOT ``Compiled`` (disk-cache paths) answers
     ``cost_analysis()``/``memory_analysis()`` directly;

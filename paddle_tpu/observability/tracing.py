@@ -22,7 +22,8 @@ request spend its 300 ms" — so this module adds:
   the runner attach to every co-batched request without threading
   trace handles through the runner protocol.
 * **Global (non-request) events** — compile events from the Executor
-  (core/executor.py _resolve_block/_resolve_scan), annotated with
+  (core/executor.py _resolve_block/_resolve_scan, both called from
+  the one lookup, Executor._bound_step), annotated with
   ``Program.fingerprint()``, the cache tier that satisfied the
   resolution (``disk`` rehydration vs ``cold`` compile; a memory hit
   never produces a compile event — the steady-state-serving tests

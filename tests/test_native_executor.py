@@ -1,8 +1,7 @@
-"""Round-5 native rungs (VERDICT r4 next #4): the C++ XLA builder
-covers a SECOND model family (the ResNet slice: conv2d/pool2d/
-batch_norm + grads), and the production Executor consumes the
-natively-built computation in-process via FLAGS_native_build — the
-trace path is the cross-check oracle at 1e-5."""
+"""Round-5 native rungs (VERDICT r4 next #4): the standalone C++ XLA
+builder covers a SECOND model family (the ResNet slice: conv2d/pool2d/
+batch_norm + grads) beside the transformer slice; the Executor's trace
+path is the cross-check oracle at 1e-5."""
 import numpy as np
 import pytest
 
@@ -148,259 +147,3 @@ class TestTransformerSliceBinaryDriver:
         nat = [row[cost.name] for row in rows]
         np.testing.assert_allclose(nat, py, rtol=2e-5, atol=2e-6)
         assert py[-1] < py[0]
-
-
-@pytest.mark.skipif(not _native_ready(),
-                    reason="no toolchain/XLA runtime for xla_train")
-class TestNativeControlFlow:
-    """Sub-block control flow in the C++ builder (closes the 'block 0
-    only, no control flow' limitation): the transformer's
-    autoregressive greedy decode — a lax.while_loop program with a
-    23-op loop body — builds as an xla::While and reproduces the
-    traced path token for token."""
-
-    def test_greedy_decode_matches_traced_tokens(self):
-        from paddle_tpu.models import transformer as T
-
-        _fresh()
-        main, startup, cost = T.build_program(
-            seq_len=8, d_model=32, n_heads=2, n_layers=1, d_inner=64,
-            vocab=32, dropout_rate=0.0, learning_rate=2.0,
-            warmup_steps=40)
-        main._seed = 5
-        r = np.random.RandomState(0)
-        src = r.randint(3, 32, (8, 8)).astype(np.int64)
-        tgt = np.concatenate(
-            [np.ones((8, 1), np.int64), src[:, :-1]], 1)
-        exe = fluid.Executor(fluid.CPUPlace())
-        sc = fluid.Scope()
-        exe.run(startup, scope=sc)
-        for _ in range(40):
-            exe.run(main, feed={"src_ids": src, "tgt_ids": tgt,
-                                "label": src},
-                    fetch_list=[cost], scope=sc)
-        dec, _, _, out_ids = T.build_greedy_decode_program(
-            seq_len=8, max_out_len=9, d_model=32, n_heads=2,
-            n_layers=1, d_inner=64, vocab=32, start_id=1, end_id=2)
-        ref, = exe.run(dec, feed={"src_ids": src},
-                       fetch_list=[out_ids], scope=sc)
-        fluid.set_flags({"FLAGS_native_build": True})
-        try:
-            nat, = exe.run(dec, feed={"src_ids": src},
-                           fetch_list=[out_ids], scope=sc)
-        finally:
-            fluid.set_flags({"FLAGS_native_build": False})
-        np.testing.assert_array_equal(np.asarray(nat),
-                                      np.asarray(ref))
-        # the KV-CACHED incremental decode (batched matmul/transpose2
-        # cache reads, greater_than freeze masks) builds natively too
-        inc, _, _, inc_out = T.build_incremental_decode_program(
-            seq_len=8, max_out_len=9, d_model=32, n_heads=2,
-            n_layers=1, d_inner=64, vocab=32, start_id=1, end_id=2)
-        iref, = exe.run(inc, feed={"src_ids": src},
-                        fetch_list=[inc_out], scope=sc)
-        fluid.set_flags({"FLAGS_native_build": True})
-        try:
-            inat, = exe.run(inc, feed={"src_ids": src},
-                            fetch_list=[inc_out], scope=sc)
-        finally:
-            fluid.set_flags({"FLAGS_native_build": False})
-        np.testing.assert_array_equal(np.asarray(inat),
-                                      np.asarray(iref))
-        np.testing.assert_array_equal(np.asarray(inat),
-                                      np.asarray(ref))
-        # and BEAM SEARCH: the third generation flavor (dense beam
-        # step + unrolled backtrack) builds natively too
-        bm, _, _, bouts = T.build_beam_decode_program(
-            seq_len=8, max_out_len=9, d_model=32, n_heads=2,
-            n_layers=1, d_inner=64, vocab=32, start_id=1, end_id=2,
-            beam_size=2)
-        bfetch = list(bouts) if isinstance(bouts, (list, tuple)) \
-            else [bouts]
-        brefs = exe.run(bm, feed={"src_ids": src[:1]},
-                        fetch_list=bfetch, scope=sc)
-        fluid.set_flags({"FLAGS_native_build": True})
-        try:
-            bnats = exe.run(bm, feed={"src_ids": src[:1]},
-                            fetch_list=bfetch, scope=sc)
-        finally:
-            fluid.set_flags({"FLAGS_native_build": False})
-        for a, b in zip(brefs, bnats):
-            a, b = np.asarray(a), np.asarray(b)
-            if np.issubdtype(a.dtype, np.floating):
-                np.testing.assert_allclose(b, a, rtol=1e-5,
-                                           atol=1e-6)
-            else:
-                np.testing.assert_array_equal(b, a)
-
-
-@pytest.mark.skipif(not _native_ready(),
-                    reason="no toolchain/XLA runtime for xla_train")
-class TestNativeBuildExecutor:
-    """FLAGS_native_build: the Executor consumes the C++-built
-    computation in-process (StableHLO), trace path as oracle."""
-
-    def _losses(self, build, feed, steps, native_build):
-        _fresh()
-        prog, startup, loss = build()
-        exe = fluid.Executor(fluid.CPUPlace())
-        sc = fluid.Scope()
-        exe.run(startup, scope=sc)
-        if native_build:
-            fluid.set_flags({"FLAGS_native_build": True})
-        try:
-            out = []
-            for _ in range(steps):
-                l, = exe.run(prog, feed=feed, fetch_list=[loss],
-                             scope=sc)
-                out.append(float(np.asarray(l).reshape(-1)[0]))
-        finally:
-            fluid.set_flags({"FLAGS_native_build": False})
-        return out
-
-    def test_conv_model_parity(self):
-        feed = _conv_data()
-        base = self._losses(_build_conv, feed, 5, False)
-        got = self._losses(_build_conv, feed, 5, True)
-        np.testing.assert_allclose(got, base, rtol=1e-5, atol=1e-6)
-        assert got[-1] < got[0]
-
-    def test_mlp_adam_parity(self):
-        def build():
-            prog, startup = fluid.Program(), fluid.Program()
-            with fluid.program_guard(prog, startup):
-                x = fluid.layers.data("x", shape=[32],
-                                      dtype="float32")
-                y = fluid.layers.data("y", shape=[1], dtype="int64")
-                h = fluid.layers.fc(x, 32, act="tanh")
-                logits = fluid.layers.fc(h, 4)
-                loss = fluid.layers.mean(
-                    fluid.layers.softmax_with_cross_entropy(logits, y))
-                fluid.optimizer.Adam(0.01).minimize(loss)
-            return prog, startup, loss
-
-        r = np.random.RandomState(2)
-        feed = {"x": r.randn(32, 32).astype(np.float32),
-                "y": r.randint(0, 4, (32, 1)).astype(np.int64)}
-        base = self._losses(build, feed, 6, False)
-        got = self._losses(build, feed, 6, True)
-        np.testing.assert_allclose(got, base, rtol=1e-5, atol=1e-6)
-
-    def test_gradient_merge_parity(self):
-        """run_block_if (the optimizer gate GradientMergeOptimizer
-        emits) builds as an xla::Conditional: the k=3 loss staircase
-        matches the traced path bit for bit."""
-        def build():
-            prog, startup = fluid.Program(), fluid.Program()
-            with fluid.program_guard(prog, startup):
-                x = fluid.layers.data("x", shape=[16],
-                                      dtype="float32")
-                y = fluid.layers.data("y", shape=[1], dtype="int64")
-                h = fluid.layers.fc(x, 32, act="relu")
-                logits = fluid.layers.fc(h, 4)
-                loss = fluid.layers.mean(
-                    fluid.layers.softmax_with_cross_entropy(logits,
-                                                            y))
-                fluid.optimizer.GradientMergeOptimizer(
-                    fluid.optimizer.SGD(0.1), k_steps=3).minimize(
-                    loss)
-            return prog, startup, loss
-
-        r = np.random.RandomState(0)
-        feed = {"x": r.randn(16, 16).astype(np.float32),
-                "y": r.randint(0, 4, (16, 1)).astype(np.int64)}
-        base = self._losses(build, feed, 9, False)
-        got = self._losses(build, feed, 9, True)
-        np.testing.assert_allclose(got, base, rtol=1e-5, atol=1e-6)
-        assert got[0] == got[1] == got[2]  # merge window
-        assert got[3] < got[0]             # k-th step applied
-
-    def test_transformer_parity(self):
-        feed = _transformer_data()
-        base = self._losses(_build_transformer, feed, 5, False)
-        got = self._losses(_build_transformer, feed, 5, True)
-        np.testing.assert_allclose(got, base, rtol=2e-5, atol=2e-6)
-
-    def test_edge_semantics_match_traced(self):
-        """Pin the decode-slice kernels' edge semantics against the
-        traced oracle: floor-mod with negatives, expand tiling,
-        gather, top_k values+indices, reduce_sum keep_dim and
-        full-reduce shapes."""
-        def both(build_fn, feeds):
-            _fresh()
-            prog, startup, fetches = build_fn()
-            exe = fluid.Executor(fluid.CPUPlace())
-            sc = fluid.Scope()
-            exe.run(startup, scope=sc)
-            ref = exe.run(prog, feed=feeds, fetch_list=fetches,
-                          scope=sc)
-            fluid.set_flags({"FLAGS_native_build": True})
-            try:
-                nat = exe.run(prog, feed=feeds, fetch_list=fetches,
-                              scope=sc)
-            finally:
-                fluid.set_flags({"FLAGS_native_build": False})
-            for i, (a, b) in enumerate(zip(ref, nat)):
-                a, b = np.asarray(a), np.asarray(b)
-                assert a.shape == b.shape, (i, a.shape, b.shape)
-                if np.issubdtype(a.dtype, np.floating):
-                    np.testing.assert_allclose(
-                        b, a, rtol=1e-5, atol=1e-6, err_msg=str(i))
-                else:
-                    np.testing.assert_array_equal(
-                        b, a, err_msg=str(i))
-
-        def b_mod():
-            prog, startup = fluid.Program(), fluid.Program()
-            with fluid.program_guard(prog, startup):
-                x = fluid.layers.data("x", shape=[6],
-                                      dtype="float32")
-                y = fluid.layers.data("y", shape=[6],
-                                      dtype="float32")
-                out = fluid.layers.elementwise_mod(x, y)
-            return prog, startup, [out]
-
-        both(b_mod,
-             {"x": np.array([[-7., 7, -7, 5, -5, 0]], np.float32),
-              "y": np.array([[3., 3, -3, -3, 5, 3]], np.float32)})
-
-        def b_misc():
-            prog, startup = fluid.Program(), fluid.Program()
-            with fluid.program_guard(prog, startup):
-                x = fluid.layers.data("x", shape=[3],
-                                      dtype="float32")
-                e = fluid.layers.expand(x, [2, 3])
-                g = fluid.layers.gather(
-                    x, fluid.layers.fill_constant([2], "int64", 1))
-                tkv, tki = fluid.layers.topk(x, k=2)
-                rs = fluid.layers.reduce_sum(x, dim=[1],
-                                             keep_dim=True)
-                rall = fluid.layers.reduce_sum(x, dim=[0, 1])
-            return prog, startup, [e, g, tkv, tki, rs, rall]
-
-        both(b_misc,
-             {"x": np.array([[3., 1, 2], [6, 5, 4]], np.float32)})
-
-    def test_unsupported_op_is_a_named_error(self):
-        def build():
-            prog, startup = fluid.Program(), fluid.Program()
-            with fluid.program_guard(prog, startup):
-                x = fluid.layers.data("x", shape=[8],
-                                      dtype="float32")
-                out = fluid.layers.atan(x)  # outside the native slice
-            return prog, startup, out
-
-        _fresh()
-        prog, startup, out = build()
-        exe = fluid.Executor(fluid.CPUPlace())
-        sc = fluid.Scope()
-        exe.run(startup, scope=sc)
-        fluid.set_flags({"FLAGS_native_build": True})
-        try:
-            with pytest.raises(RuntimeError,
-                               match="no native XLA kernel"):
-                exe.run(prog, feed={"x": np.zeros((2, 8),
-                                                  np.float32)},
-                        fetch_list=[out], scope=sc)
-        finally:
-            fluid.set_flags({"FLAGS_native_build": False})

@@ -353,10 +353,6 @@ class TestMemory:
         KV saving (r5 learning: memory_analysis is valid on the CPU
         backend for schedule/state-level comparisons) — the
         spec-derived byte claim above is not just arithmetic."""
-        import jax
-
-        from paddle_tpu.core.executor import RNG_VAR
-
         exe, scope = trained["exe"], trained["scope"]
 
         def arg_bytes(bundle):
@@ -365,16 +361,10 @@ class TestMemory:
                 else PagedContinuousGenerationServer
             s = srv(bundle, executor=exe, scope=scope, start=False)
             try:
-                c = s._serves[0]._compiled
-                mut = exe._scope_state(scope, c.state_in, None)
-                const = exe._scope_state(scope, c.const_in, None)
-                rng = scope._get(RNG_VAR)
-                if rng is None:
-                    rng = jax.random.PRNGKey(0)
                 feed = {"n_steps": np.array([1], np.int64),
                         "min_active": np.array([0], np.int64)}
-                m = c.fn.lower(mut, const, feed,
-                               rng).compile().memory_analysis()
+                m = s._serves[0].step.lower(
+                    scope, feed).compile().memory_analysis()
                 return int(m.argument_size_in_bytes)
             finally:
                 s.close()
@@ -717,7 +707,6 @@ class TestPoolAddressedAsStored:
         import jax
 
         from paddle_tpu import unique_name
-        from paddle_tpu.core.executor import RNG_VAR
         from paddle_tpu.core.scope import Scope
         from paddle_tpu.models import transformer as T
         from paddle_tpu.models.decode_engine import POOL_MARK
@@ -761,15 +750,14 @@ class TestPoolAddressedAsStored:
         srv = PagedContinuousGenerationServer(
             bundle, executor=exe, scope=scope, start=False)
         try:
-            comp = srv._serves[0]._compiled
+            step = srv._serves[0].step
+            comp = step.compiled
             assert set(pools) <= set(comp.state_in) | set(comp.const_in)
-            rng = scope._get(RNG_VAR)
+            state, const, rng = step.gather(scope)
             closed = jax.make_jaxpr(comp.fn)(
-                exe._scope_state(scope, comp.state_in, None),
-                exe._scope_state(scope, comp.const_in, None),
+                state, const,
                 {"n_steps": np.array([1], np.int64),
-                 "min_active": np.array([0], np.int64)},
-                jax.random.PRNGKey(0) if rng is None else rng)
+                 "min_active": np.array([0], np.int64)}, rng)
         finally:
             srv.close()
         names = {e.primitive.name for e in _eqns(closed.jaxpr)}

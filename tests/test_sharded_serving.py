@@ -289,7 +289,7 @@ class TestPerDeviceKV:
                 with PagedContinuousGenerationServer(
                         b, executor=trained["exe"],
                         scope=fork) as srv:
-                    fn = srv._serves[0]._compiled.fn
+                    fn = srv._serves[0].step.compiled.fn
                     ma = getattr(fn, "memory_analysis", None)
                     assert ma is not None, \
                         "AOT path did not engage (no memory_analysis)"
