@@ -440,6 +440,14 @@ def server_phase(c, meter, on_chip):
                              "radix_admissions",
                              "plain_radix_admissions", "preemptions")},
         aot_failures=exe.aot_failures)
+    # on the chip and in the rehearsal (interpret mode) the paged
+    # self-attention read is the kernel's, in every program that ran
+    paged_read = ("paged_decode_attention",
+                  (c["n_slots"] + 1, 1, c["d_model"]))
+    check({r for r in routes if r[:2] == paged_read}
+          == {paged_read + (True,)},
+          f"the paged read did not take its kernel: "
+          f"{sorted(set(routes))}")
 
     rows0, streamed0 = waves[0][0], waves[0][1]
     # every comparison is printed before any of them can end the run
@@ -658,24 +666,32 @@ def sweep_cases(tiny):
                           ffn_block.ffn_block_reference,
                           (x, w1, b1, w2, b2), (0, 1, 2, 3, 4)), 3e-2))
 
-    rws, hh, dh, nb, bs, pages = (5, 2, 64, 7, 8, 3) if tiny \
-        else (9, 8, 64, 24, 16, 3)
-    q = rnd(17, (rws, hh, dh))
-    pk, pv = rnd(18, (nb, bs, hh, dh)), rnd(19, (nb, bs, hh, dh))
+    # the serve cell's size (BENCHMARK.json, transformer-big-serve):
+    # 32 lanes and the dustbin, 16 heads of 64, 1,280 blocks of 16
+    rws, hh, dh, nb, bs, pages = (5, 2, 64, 24, 8, 4) if tiny \
+        else (33, 16, 64, 1280, 16, 16)
+    q = rnd(17, (rws, 1, hh * dh))
+    pk, pv = (rnd(i, (nb * bs, hh * dh)) for i in (18, 19))
     rs = np.random.RandomState(SEED)
-    tab = jnp.asarray(np.stack([rs.permutation(nb)[:pages]
-                                for _ in range(rws)]).astype(np.int32))
-    step = jnp.asarray(rs.randint(0, pages * bs, (rws,)).astype(np.int32))
+    # lanes own disjoint blocks; the dustbin's cleared row names block 0
+    tab = np.zeros((rws, pages), np.int32)
+    tab[:-1] = 1 + rs.permutation(nb - 1)[:(rws - 1) * pages].reshape(
+        rws - 1, pages)
+    tab = jnp.asarray(tab)
+    step = jnp.asarray(np.append(
+        rs.randint(0, pages * bs, (rws - 1,)), 0).astype(np.int32))
+    kw = dict(block_size=bs, n_heads=hh, scale=dh ** -0.5)
 
     def paged_fwd():
         got = jax.jit(lambda *a: paged_attention.paged_decode_attention(
-            *a, scale=dh ** -0.5))(q, pk, pv, tab, step)
-        want = paged_attention.paged_decode_attention_reference(
-            q, pk, pv, tab, step, scale=dh ** -0.5)
+            *a, **kw))(q, pk, pv, tab, step)
+        want = paged_attention.paged_attention_reference(
+            q, pk, pv, tab, step, **kw)
         return [("out", got, want)]
     cases.append(("paged_attention",
-                  f"q({rws},{hh},{dh}) pool({nb},{bs}) f32",
-                  paged_attention.usable(q, pk, tab), False,
+                  f"q({rws},1,{hh * dh}) pool({nb * bs},{hh * dh}) "
+                  f"table({rws},{pages}) f32",
+                  paged_attention.usable(q, pk, tab, bs), True,
                   [("fwd", paged_fwd)], 1e-3))  # inference-only kernel
     return cases
 

@@ -743,6 +743,9 @@ class PoolAccess:
     gate_var: Optional[str] = None
     gate_fact: Optional[ProvFact] = None
     axis_size: Optional[int] = None
+    # the reader neither clamps nor fills (paged_decode_attention):
+    # an index PTA190 cannot bound is an error there, not a warning
+    unchecked: bool = False
 
 
 def _producer_op(var) -> Optional[Operator]:
@@ -1586,6 +1589,27 @@ class _Interp:
                 for n in op.output_arg_names:
                     if n != EMPTY_VAR:
                         self.pool_views[n] = root
+            return
+        if op.type == "paged_decode_attention":
+            # the table addresses whole blocks of BOTH pools; whatever
+            # var is wired to a pool slot is read unchecked, marked
+            # @POOL or not, so the proof is never skipped
+            tab = _first("Table")
+            bs = op.attrs.get("block_size")
+            for slot in ("PoolK", "PoolV"):
+                x = _first(slot)
+                if x is None:
+                    continue
+                xvar = blk._find_var_recursive(x)
+                blocks = None
+                if xvar is not None and xvar.shape and \
+                        isinstance(bs, int) and bs > 0 and \
+                        xvar.shape[0] is not None and xvar.shape[0] >= 0:
+                    blocks = int(xvar.shape[0]) // bs
+                self.pool_accesses.append(PoolAccess(
+                    site, guards, "read", self.pool_views.get(x) or x,
+                    tab, self._prov_of(tab) if tab else None,
+                    axis_size=blocks, unchecked=True))
             return
         if op.type in ("gather", "gather_nd"):
             x = _first("X")

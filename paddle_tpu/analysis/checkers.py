@@ -1843,7 +1843,11 @@ def check_pool_access_provenance(program: Program):
       index fact's bound must fit it (ERROR when the bound provably
       exceeds the axis; WARNING when no bound is derivable for a
       READ — the write kernel drops out-of-range rows, reads have
-      no such net)."""
+      no such net; ERROR for an UNCHECKED read,
+      ``paged_decode_attention``, whose kernel copies the blocks its
+      table names with no clamp and no fill: this proof is the only
+      thing between a stale table entry and a read outside the
+      pool)."""
     from . import absint
 
     facts = absint.analyze(program)
@@ -1882,6 +1886,20 @@ def check_pool_access_provenance(program: Program):
                 hint="gate with the active mask "
                      "(absint.mark_pool_index_source(active, "
                      "'lane_active'); gate=cast(active,'float32'))")
+        if acc.unchecked and not fact.const and (
+                fact.bound is None or acc.axis_size is None):
+            yield _diag_at(
+                "PTA190", ERROR, acc.site,
+                f"unchecked read of pool {acc.pool!r}: in-bounds is "
+                f"unprovable (index {acc.index_var!r} "
+                f"[{_chain_of(fact)}]: bound {fact.bound}, pool extent "
+                f"{acc.axis_size} blocks) and the kernel neither "
+                f"clamps nor fills",
+                var=acc.pool,
+                hint="declare the host invariant's bound at the mint "
+                     "site (mark_pool_index_source(var, tag, "
+                     "bound=N)) and give the pool a static shape")
+            continue
         if acc.axis_size is not None:
             if fact.bound is not None and fact.bound > acc.axis_size:
                 yield _diag_at(

@@ -259,6 +259,15 @@ def _gather(op, prov_of, shape_of):
     return _all_outs(op, f.with_step(op.type, distinct=False))
 
 
+@_family("paged_read", ("paged_decode_attention",))
+def _paged_read(op, prov_of, shape_of):
+    # the output is attention context, not an index: no provenance
+    # leaves the op. Its Table input is judged where gather's Index
+    # is, at the pool-access record (absint._record_pool_access),
+    # with the bound compared against the pool's BLOCK count.
+    return {}
+
+
 @_family("split", ("split",))
 def _split(op, prov_of, shape_of):
     f = prov_of(_in(op, "X") or "")

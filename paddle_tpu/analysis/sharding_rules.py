@@ -624,6 +624,51 @@ def rule_gather(op, spec_of, shape_of, mesh):
     return _all_outs(op, ShardSpec.of(out_places), events)
 
 
+@_family("paged_read", ("paged_decode_attention",))
+def rule_paged_read(op, spec_of, shape_of, mesh):
+    """The paged self-attention read: every contraction stays inside
+    one head, so pools sharded on their ``H*Dh`` axis (whole heads a
+    shard, the tp serving layout) give context rows sharded the same
+    way on their last axis with no collective. The lane axis follows
+    Q; a table or positions that are not replicated would have to be
+    gathered first, and a query laid out against the pools' heads
+    resharded — both surfaced as events."""
+    q, out = _in(op, "Q"), _outs(op)
+    sk = spec_of(_in(op, "PoolK"))
+    sv = spec_of(_in(op, "PoolV"))
+    sq = spec_of(q) if q else REPLICATED_SPEC
+    if sk.is_top or sv.is_top or sq.is_top:
+        return _all_outs(op, TOP_SPEC)
+    events = []
+    head_axis = sk.axis_of(1)
+    if sv.axis_of(1) != head_axis or sk.axis_of(0) is not None \
+            or sv.axis_of(0) is not None:
+        events.append(CollectiveEvent(
+            "reshard", tuple(sorted(set(sk.axes()) | set(sv.axes()))),
+            out[0] if out else None,
+            f"paged read wants both pools sharded alike on H*Dh "
+            f"alone, got {sk.describe()} and {sv.describe()}"))
+    if sq.axis_of(2) not in (None, head_axis):
+        events.append(CollectiveEvent(
+            "reshard", (sq.axis_of(2),), out[0] if out else None,
+            f"query rows are laid out {sq.describe()} against pools "
+            f"sharded {sk.describe()}"))
+    for slot in ("Table", "Pos"):
+        name = _in(op, slot)
+        st = spec_of(name) if name else REPLICATED_SPEC
+        if not st.is_top and not st.is_replicated:
+            events.append(CollectiveEvent(
+                "allgather", tuple(st.axes()),
+                out[0] if out else None,
+                f"paged read's {slot} {name!r} is sharded "
+                f"{st.describe()}: every shard needs every lane's "
+                f"row"))
+    places = [(d, a) for d, a in sq.placements if d < 2]
+    if head_axis is not None:
+        places.append((2, head_axis))
+    return _all_outs(op, ShardSpec.of(places), events)
+
+
 @_family("one_hot", ("one_hot",))
 def rule_one_hot(op, spec_of, shape_of, mesh):
     x = _in(op, "X")

@@ -106,6 +106,10 @@ class _CompiledBlock:
     # only a _CompiledScan carries any (never written to)
     write_only_specs: Dict = {}
 
+    # the step body's own list of kernel routing decisions
+    # (_build_step_fn), filled when the body is traced
+    kernel_routes = ()
+
     def __init__(self, fn, feed_names, state_in, const_in, state_out,
                  fetch_names):
         self.fn = fn
@@ -441,7 +445,8 @@ def _build_step_fn(block, feed_names, mutated, const, state_out,
         # kernels route to their references while it traces -- inside
         # the body, so whoever triggers the trace (first call, AOT
         # lowering, a cost-model probe) gets the same program
-        with pallas.auto_partitioned(on_mesh):
+        with pallas.auto_partitioned(on_mesh), \
+                pallas.record_routes() as routes:
             for i, op in enumerate(block.ops):
                 if op.type in _SKIP_OP_TYPES:
                     continue
@@ -455,12 +460,17 @@ def _build_step_fn(block, feed_names, mutated, const, state_out,
                     for n in free_after[i]:
                         if n not in keep:
                             env.pop(n, None)
+        step.kernel_routes[:] = routes
         new_state = {n: env[n] for n in state_out if n in env}
         fetches = [env[n] for n in fetch_names]
         # ops derive keys functionally (fold_in(step_key, uid)); the
         # step key itself advances exactly once per step here
         return new_state, fetches, jax.random.split(rng, 1)[0]
 
+    # (kernel, shape, routed) of the newest trace of this body; empty
+    # until something traces it (jit is lazy, and an executable
+    # loaded from the disk cache never does)
+    step.kernel_routes = []
     return step
 
 
@@ -1850,6 +1860,7 @@ class Executor:
         scan = _CompiledScan(fn, feed_names, mutated, const,
                              state_out, fetch_names, write_only_specs,
                              steps, stacked)
+        scan.kernel_routes = step.kernel_routes
         if aot_art is not None:
             scan._aot = aot_art
         return scan
@@ -1915,6 +1926,7 @@ class Executor:
                     fn, aot_art = got
         blk = _CompiledBlock(fn, feed_names, mutated, const, state_out,
                              fetch_names)
+        blk.kernel_routes = step.kernel_routes
         if aot_art is not None:
             blk._aot = aot_art
         return blk
@@ -2021,6 +2033,14 @@ class PreparedProgram:
         self._feed_example = None  # large batches must not be pinned
         # for the handle's lifetime; re-binds rebuild from specs
         self._snapshot_tokens()
+
+    def kernel_routes(self) -> list:
+        """(kernel, shape, routed) for every Pallas routing decision
+        the bound executable's newest trace took
+        (ops/pallas.note_route). Empty before the first dispatch
+        traces it, and for an executable loaded from the disk cache,
+        which is never traced here."""
+        return list(self.step.compiled.kernel_routes)
 
     def lowered_text(self) -> str:
         """StableHLO of the bound executable, lowered again at the

@@ -2422,6 +2422,16 @@ class ContinuousGenerationServer:
                 # the pool stats, these explain WHY lanes vanished
                 "cancelled": self._n_cancelled,
                 "deadline_expired": self._n_deadline,
+                # which route each bound serve program's paged
+                # self-attention took when it was traced ("kernel",
+                # "reference"; a program not traced yet, or a dense
+                # one, lists none): trace-time record, no tick reads it
+                "self_attention_routes": {
+                    str(key): sorted({
+                        "kernel" if routed else "reference"
+                        for kernel, _, routed in h.kernel_routes()
+                        if kernel == "paged_decode_attention"})
+                    for key, h in self._serves.items()},
             }
             spec = self._speculative_stats_locked()
             if spec is not None:

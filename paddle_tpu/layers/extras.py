@@ -474,6 +474,33 @@ def masked_pool_write(pool, new, index, gate=None, leading_dims=1,
 __all__.append("masked_pool_write")
 
 
+def paged_decode_attention(q, pool_k, pool_v, block_tab, pos, block_size,
+                           n_heads, scale=1.0, name=None):
+    """Context rows ``[R, q, H*Dh]`` of the decode tick's queries ``q``
+    over each lane's own cache positions, read from the SHARED
+    ``[NB*BS, H*Dh]`` pools through the lane's row of ``block_tab``
+    (ops/paged_ops.py; query j of a lane attends positions
+    <= pos + j). The read surface of the `@POOL` self-attention pools
+    as ``masked_pool_write`` is their write surface: the ownership
+    prover (PTA190) must be able to chain ``block_tab`` to a marked
+    host table with a bound, because the kernel neither clamps nor
+    fills. Reference counterpart: none (the reference's decode caches
+    are dense per-request tensors,
+    tests/unittests/dist_transformer.py:1498)."""
+    helper = LayerHelper("paged_decode_attention", input=q, name=name)
+    out = helper.create_variable_for_type_inference(q.dtype, True)
+    helper.append_op(
+        "paged_decode_attention",
+        {"Q": q, "PoolK": pool_k, "PoolV": pool_v, "Table": block_tab,
+         "Pos": pos}, {"Out": out},
+        {"block_size": int(block_size), "n_heads": int(n_heads),
+         "scale": float(scale)})
+    return out
+
+
+__all__.append("paged_decode_attention")
+
+
 def filtered_softmax(logits, temperature=1.0, top_k=0, top_p=1.0,
                      name=None):
     """Temperature/top-k/top-p filtered, renormalized probabilities

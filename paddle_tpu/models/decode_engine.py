@@ -403,7 +403,27 @@ def cells_of_blocks(blocks, block_size, offs):
 # ---------------------------------------------------------------------------
 # Cache-access objects: the ONE place layout differences live.
 # ---------------------------------------------------------------------------
-class _DenseLaneCache:
+class _DenseViewAttention:
+    """Self-attention of the dense layouts: ``update`` writes this
+    tick's keys and values and hands back the ``[R, H, maxT, Dh]``
+    vars, over which the attention is the shared
+    matmul / bias / softmax / matmul."""
+
+    def attend(self, qh, kh, vh, att_bias, scale):
+        """qh, kh, vh [R,H,q,Dh] -> context rows [R,q,H*Dh]."""
+        kc, vc = self.update(kh, vh)
+        scores = layers.scale(
+            layers.matmul(qh, kc, transpose_y=True),
+            scale=scale)  # [R,H,q,maxT]
+        scores = layers.elementwise_add(scores, att_bias)
+        probs = layers.softmax(scores, axis=-1)
+        ctx = layers.matmul(probs, vc)
+        return layers.reshape(
+            layers.transpose(ctx, perm=[0, 2, 1, 3]),
+            [0, qh.shape[2], qh.shape[1] * qh.shape[3]])  # [R,q,HD]
+
+
+class _DenseLaneCache(_DenseViewAttention):
     """Per-layer dense self-KV access: in-place one-hot masked write
     into per-lane ``[R, H, maxT, Dh]`` vars, attention reads the vars
     directly (the r10 layout; write masks broadcast for either a
@@ -427,17 +447,18 @@ class _DenseLaneCache:
 
 
 class _PagedLaneCache:
-    """Per-layer paged self-KV access: writes go through the
+    """Per-layer paged self-KV access. Writes go through the
     ``masked_pool_write`` registry op (disjoint one-hot scatter into
     the SHARED ``[NB * BS, H * Dh]`` pool at each lane's block-table
     cell, gated by the active mask so idle/dustbin lanes never
-    touch the pool — the PTA110 exclusivity contract), reads gather
-    every lane's maxT cache positions back into the dense
-    ``[R, H, maxT, Dh]`` view the shared attention math expects.
-    Positions a lane has not written yet hold stale pool bytes; the
-    caller's validity bias (-1e9 past position t) masks them exactly
-    like the dense layout masks its zeros, so the softmax sees
-    identical values — token-exact parity with dense.
+    touch the pool — the PTA110 exclusivity contract). The attention
+    is the cache's own: ``paged_decode_attention`` reads each lane's
+    cells from the pools where they are stored, through the lane's
+    row of the block table, and no dense ``[R, H, maxT, Dh]`` view of
+    a pool exists. Positions a lane has not written yet hold stale
+    pool bytes; the op masks positions past ``pos`` (+ j for query j)
+    exactly like the dense layouts' -1e9 bias masks their zeros, so
+    the softmax sees identical values — token-exact parity with dense.
 
     ``q`` > 1 is the multi-position verify write: the q positions of
     every lane flatten to R*q masked_pool_write rows (distinct cells —
@@ -447,38 +468,36 @@ class _PagedLaneCache:
     per-position validity so positions past the buffer end never
     touch the pool."""
 
-    def __init__(self, pool_k, pool_v, write_idx, gate, flat_pos,
-                 rows, n_heads, head_dim, maxT, q=1):
+    def __init__(self, pool_k, pool_v, write_idx, gate, block_tab, pos,
+                 rows, block_size, q=1):
         self.pool_k, self.pool_v = pool_k, pool_v
         self.write_idx, self.gate = write_idx, gate
-        self.flat_pos = flat_pos          # [rows*maxT] int32 cell addrs
-        self.rows, self.maxT, self.q = rows, maxT, q
-        self.n_heads, self.head_dim = n_heads, head_dim
+        self.block_tab = block_tab        # [rows, NP] int32, host-owned
+        self.pos = pos                    # [rows] position of query 0
+        self.rows, self.block_size, self.q = rows, block_size, q
 
-    def _view(self, pool):
-        # the pool is gathered as it is stored, one row a cell: only
-        # the gathered rows are ever reshaped, never the pool
-        rows_kv = layers.gather(pool, self.flat_pos)
-        return layers.transpose(
-            layers.reshape(rows_kv, [self.rows, self.maxT,
-                                     self.n_heads, self.head_dim]),
-            perm=[0, 2, 1, 3])
+    def attend(self, qh, kh, vh, att_bias, scale):
+        """qh, kh, vh [R,H,q,Dh] -> context rows [R,q,H*Dh]; the
+        mask comes from ``pos``, so ``att_bias`` is not read."""
+        n_heads, head_dim = qh.shape[1], qh.shape[3]
 
-    def update(self, kh, vh):
+        def rows_of(x, lead):     # [R,H,q,Dh] -> lead + [H*Dh]
+            return layers.reshape(
+                layers.transpose(x, perm=[0, 2, 1, 3]),
+                lead + [n_heads * head_dim])
+
         for pool, new in ((self.pool_k, kh), (self.pool_v, vh)):
-            # [R,H,q,Dh] -> [R*q, H*Dh] write rows
             layers.masked_pool_write(
-                pool,
-                layers.reshape(
-                    layers.transpose(new, perm=[0, 2, 1, 3]),
-                    [self.rows * self.q,
-                     self.n_heads * self.head_dim]),
+                pool, rows_of(new, [self.rows * self.q]),
                 self.write_idx, gate=self.gate, leading_dims=1,
                 exclusive_via="block_table")
-        return self._view(self.pool_k), self._view(self.pool_v)
+        return layers.paged_decode_attention(
+            rows_of(qh, [self.rows, self.q]), self.pool_k, self.pool_v,
+            self.block_tab, self.pos, self.block_size, n_heads,
+            scale=scale)
 
 
-class _DenseSpanCache:
+class _DenseSpanCache(_DenseViewAttention):
     """Per-layer dense self-KV access for a MULTI-position write (the
     speculative verify step: q=k+1 query rows per lane land at cache
     positions t..t+k in one update). ``pos_oh`` is the [R,q,maxT]
@@ -657,12 +676,17 @@ def cached_decoder_step(x, caches, cross_kv, att_bias, d_model,
 
     ``caches``: per-layer cache-access objects (_DenseLaneCache for
     q=1, _DenseSpanCache for the speculative q=k+1 verify step,
-    _PagedLaneCache for either) owning the self-attention KV write+view.
+    _PagedLaneCache for either) owning the self-attention: the KV
+    write and ``attend``, the context rows over the lane's cache (the
+    dense objects share matmul/softmax/matmul over their vars, the
+    paged object reads its pools in place).
     ``cross_kv``: per-layer (ck, cv) [R,H,S,Dh] encoder projections
     (vars for dense, pool gathers for paged). ``att_bias`` is the
-    0/-1e9 validity bias added to the [R,H,q,maxT] attention scores —
-    for q>1 it must be per-query-position causal ([R,1,q,maxT]:
-    query j masks cache positions > t+j). Param names are the
+    0/-1e9 validity bias the dense objects add to their [R,H,q,maxT]
+    attention scores — for q>1 it must be per-query-position causal
+    ([R,1,q,maxT]: query j masks cache positions > t+j); the paged
+    object masks the same positions from its lanes' counters and
+    takes None. Param names are the
     explicit {prefix}dec{li}_* scheme shared with the training build
     (``prefix`` is how a speculative DRAFT model co-resides with the
     target in one scope without aliasing — the PTA100 contract).
@@ -707,16 +731,7 @@ def cached_decoder_step(x, caches, cross_kv, att_bias, d_model,
             qh = heads_of(qv, q, n_heads, head_dim)
             kh = heads_of(k, q, n_heads, head_dim)
             vh = heads_of(v, q, n_heads, head_dim)
-        kc, vc = cache.update(kh, vh)
-        scores = layers.scale(
-            layers.matmul(qh, kc, transpose_y=True),
-            scale=scale)  # [R,H,q,maxT]
-        scores = layers.elementwise_add(scores, att_bias)
-        probs = layers.softmax(scores, axis=-1)
-        ctx = layers.matmul(probs, vc)
-        ctx = layers.reshape(
-            layers.transpose(ctx, perm=[0, 2, 1, 3]),
-            [0, q, d_model])  # [R,q,HD]
+        ctx = cache.attend(qh, kh, vh, att_bias, scale)  # [R,q,HD]
         attn_out = layers.fc(ctx, d_model, num_flatten_dims=2,
                              bias_attr=False,
                              param_attr=f"{prefix}dec{li}_self_out.w")
@@ -2211,7 +2226,8 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
         _tel_add(sv, "tel_occupancy",
                  layers.reduce_sum(act, keep_dim=True))
         positions = layers.cast(layers.range(0, maxT, 1), "int64")
-        posf = layers.cast(positions, "float32")
+        # the dense layouts' bias alone reads it
+        posf = None if paged else layers.cast(positions, "float32")
         pos_table = layers.assign(
             T._position_encoding(max(seq_len, maxT), d_model)[:maxT])
         step2 = layers.reshape(stepv, [rows, 1])           # [R,1]
@@ -2228,15 +2244,16 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
         x = layers.scale(x, scale=d_model ** 0.5)
         pos_t = layers.matmul(t_mask, pos_table)           # [R,D]
         x = layers.elementwise_add(x, layers.unsqueeze(pos_t, [1]))
-        # per-lane attention validity (paged gathers exactly the
-        # dense maxT positions — block_size divides maxT — so the
-        # same bias masks unwritten cells in both layouts)
-        att_bias = layers.reshape(
-            layers.scale(layers.cast(layers.greater_than(
-                posf, layers.cast(step2, "float32")), "float32"),
-                scale=-1e9),
-            [rows, 1, 1, maxT])
+        att_bias = None
         if not paged:
+            # per-lane attention validity (the paged read masks the
+            # same positions from stepv: block_size divides maxT, so
+            # both layouts attend exactly the maxT cache positions)
+            att_bias = layers.reshape(
+                layers.scale(layers.cast(layers.greater_than(
+                    posf, layers.cast(step2, "float32")), "float32"),
+                    scale=-1e9),
+                [rows, 1, 1, maxT])
             write_mask = layers.reshape(t_mask, [rows, 1, maxT, 1])
             keep_mask = layers.reshape(
                 layers.elementwise_sub(
@@ -2254,13 +2271,12 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
         else:
             # cell addresses through the HOST-owned block table:
             # flat cache cell of position p = tab[lane, p//BS]*BS
-            # + p%BS, materialized for all maxT positions (gather
-            # view) and for the current write position (scatter)
-            tabf = layers.cast(sv[f"{state_prefix}block_tab"],
-                               "float32")                  # [R,NP]
+            # + p%BS. The read takes the table itself; only the
+            # current write position's cell is materialized, from
+            # its page/offset one-hots out of t_mask
+            block_tab = sv[f"{state_prefix}block_tab"]     # [R,NP]
+            tabf = layers.cast(block_tab, "float32")
             offs = layers.assign(np.arange(BS, dtype="float32"))
-            flat_pos = cells_of_blocks(tabf, BS, offs)     # [R*maxT]
-            # current position's page/offset one-hots from t_mask
             t_pages = layers.reshape(t_mask, [rows, NP, BS])
             page_oh = layers.reduce_sum(t_pages, dim=2)    # [R,NP]
             off_oh = layers.reduce_sum(t_pages, dim=1)     # [R,BS]
@@ -2279,8 +2295,8 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
             caches = [_PagedLaneCache(
                 sv[f"{state_prefix}self_k{li}{POOL_MARK}"],
                 sv[f"{state_prefix}self_v{li}{POOL_MARK}"],
-                write_idx, gate, flat_pos, rows, n_heads, head_dim,
-                maxT) for li in range(n_layers)]
+                write_idx, gate, block_tab, stepv, rows, BS)
+                for li in range(n_layers)]
             pref = sv[f"{state_prefix}prompt_ref"]
             cross_kv = []
             for li in range(n_layers):
@@ -2621,12 +2637,13 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
         # per-query causal validity: query j attends positions
         # <= step+j (positions past the buffer get all-zero one-hots
         # and never write — see the span caches)
-        bias = layers.reshape(
-            layers.scale(layers.cast(layers.greater_than(
-                posf, layers.cast(posq3, "float32")), "float32"),
-                scale=-1e9),
-            [rows, 1, Q, maxT])
+        bias = None
         if not paged:
+            bias = layers.reshape(
+                layers.scale(layers.cast(layers.greater_than(
+                    posf, layers.cast(posq3, "float32")), "float32"),
+                    scale=-1e9),
+                [rows, 1, Q, maxT])
             keep = layers.reshape(
                 layers.elementwise_sub(
                     layers.fill_constant([rows, maxT], "float32",
@@ -2641,10 +2658,9 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
                          sv[f"{state_prefix}cross_v{li}"])
                         for li in range(n_layers)]
         else:
-            tabf = layers.cast(sv[f"{state_prefix}block_tab"],
-                               "float32")                  # [R,NP]
+            block_tab = sv[f"{state_prefix}block_tab"]     # [R,NP]
+            tabf = layers.cast(block_tab, "float32")
             offs = layers.assign(np.arange(BS, dtype="float32"))
-            flat_pos = cells_of_blocks(tabf, BS, offs)     # [R*maxT]
             t_pages_q = layers.reshape(t_mask_q, [rows, Q, NP, BS])
             page_oh = layers.reduce_sum(t_pages_q, dim=3)  # [R,Q,NP]
             off_oh = layers.reduce_sum(t_pages_q, dim=2)   # [R,Q,BS]
@@ -2669,8 +2685,8 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
             caches = [_PagedLaneCache(
                 sv[f"{state_prefix}self_k{li}{POOL_MARK}"],
                 sv[f"{state_prefix}self_v{li}{POOL_MARK}"],
-                write_idx, gate, flat_pos, rows, n_heads, head_dim,
-                maxT, q=Q) for li in range(n_layers)]
+                write_idx, gate, block_tab, stepv, rows, BS, q=Q)
+                for li in range(n_layers)]
             pref = sv[f"{state_prefix}prompt_ref"]
             cross_kv = []
             for li in range(n_layers):

@@ -6,9 +6,11 @@ tests/unittests/dist_transformer.py:1498 fast_decode caches). The
 shared block pool follows vLLM's PagedAttention block tables
 (SOSP'23, PAPERS.md), re-designed for XLA static shapes: the pool is
 one persistable tensor, lanes address it through host-allocated
-int32 tables, reads are plain `gather` composition, and ALL writes
-funnel through the single op below so the lane-exclusivity contract
-is one auditable surface (analysis checker PTA110).
+int32 tables, ALL writes funnel through ``masked_pool_write`` so the
+lane-exclusivity contract is one auditable surface (analysis checker
+PTA110), and a decode tick's self-attention reads the pool where it
+is stored through ``paged_decode_attention`` (the cross-attention
+prompt table and the COW copy still read by plain `gather`).
 """
 from __future__ import annotations
 
@@ -79,3 +81,48 @@ def masked_pool_write(ctx):
     out = pool.reshape((n,) + tail).at[safe].set(
         new.reshape((rows,) + tail).astype(pool.dtype), mode="drop")
     return out.reshape(pool.shape)
+
+
+@register_op("paged_decode_attention", differentiable=False,
+             stop_gradient_slots=("Q", "PoolK", "PoolV", "Table",
+                                  "Pos"))
+def paged_decode_attention(ctx):
+    """Self-attention of the decode tick's queries over a lane's own
+    cache positions, read from the SHARED pools where they are stored.
+
+    inputs: Q [R, q, H*Dh] (this tick's query rows); PoolK, PoolV
+    [NB*BS, H*Dh] (after this tick's ``masked_pool_write``); Table
+    [R, NP] int (the lane's block table: cache position p of lane r is
+    pool row ``Table[r, p // BS] * BS + p % BS``); Pos [R] int (the
+    cache position of a lane's first query: query j attends positions
+    <= Pos + j, so stale cells past a lane's position are masked as
+    the dense step's -1e9 bias masks them). attrs: block_size,
+    n_heads, scale. Out [R, q, H*Dh], the context rows. Idle and
+    dustbin lanes read whatever blocks their table rows name (block 0
+    when cleared) and their rows are ignored downstream.
+
+    Nothing of shape ``[R, H, maxT, Dh]`` is built: the routes in
+    ops/pallas/paged_attention.py (a Pallas kernel for q = 1 on one
+    TPU, a jnp composition elsewhere) keep ``H*Dh`` on the lanes, and
+    ``note_route`` records which was taken. Reads are NOT clamped or
+    filled: Table must be proven in bounds, which the ownership prover
+    does at build time (analysis/absint.py records the read with
+    Table as its index, PTA190 wants provenance from a marked host
+    table AND a bound that fits NB, and rejects the op otherwise).
+    """
+    from .pallas import note_route
+    from .pallas import paged_attention as PA
+
+    q = ctx.input("Q")
+    pool_k, pool_v = ctx.input("PoolK"), ctx.input("PoolV")
+    tab, pos = ctx.input("Table"), ctx.input("Pos")
+    kw = dict(block_size=int(ctx.attr("block_size")),
+              n_heads=int(ctx.attr("n_heads")),
+              scale=float(ctx.attr("scale", 1.0)))
+    pos = pos.reshape(q.shape[0])
+    if note_route("paged_decode_attention", q.shape,
+                  PA.usable(q, pool_k, tab, kw["block_size"])):
+        return PA.paged_decode_attention(q, pool_k, pool_v, tab, pos,
+                                         **kw)
+    return PA.paged_attention_reference(q, pool_k, pool_v, tab, pos,
+                                        **kw)

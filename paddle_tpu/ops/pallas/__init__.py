@@ -19,8 +19,13 @@ def on_tpu() -> bool:
     GSPMD partitions over a mesh (auto_partitioned). A backend that
     fails to initialize raises here instead of routing every kernel to
     its jnp reference."""
-    return jax.default_backend() == "tpu" \
-        and not getattr(_TRACE, "auto_partitioned", False)
+    return jax.default_backend() == "tpu" and not mesh_placed()
+
+
+def mesh_placed() -> bool:
+    """True while the program being traced is one GSPMD partitions
+    over a mesh (inside ``auto_partitioned``)."""
+    return getattr(_TRACE, "auto_partitioned", False)
 
 
 # Thread-local: a serving thread traces its programs lazily while
@@ -48,28 +53,31 @@ def auto_partitioned(on: bool = True):
 
 # Trace-time record of kernel routing. `usable()` returning False is
 # otherwise indistinguishable from the kernel running, so callers that
-# must know (chip_smoke.py) install a list with record_routes() and
-# every routing site reports its decision through note_route().
-_ROUTE_LOG = [None]
+# must know (chip_smoke.py, the executor's per-program record) open a
+# list with record_routes() and every routing site reports its decision
+# through note_route(). Records nest: a decision goes to every list
+# that is open, so a caller's list still sees what a program traced
+# inside it recorded for itself.
+_ROUTE_LOGS = []
 
 
 @contextlib.contextmanager
 def record_routes():
     """Collect (kernel, shape, routed) for every routing decision
     traced inside the block."""
-    prev, log = _ROUTE_LOG[0], []
-    _ROUTE_LOG[0] = log
+    log = []
+    _ROUTE_LOGS.append(log)
     try:
         yield log
     finally:
-        _ROUTE_LOG[0] = prev
+        # by identity: two logs that hold the same entries are equal
+        _ROUTE_LOGS[:] = [l for l in _ROUTE_LOGS if l is not log]
 
 
 def note_route(kernel: str, shape, routed: bool) -> bool:
     """Report one routing decision; returns `routed` so call sites can
     wrap their usable() test."""
-    log = _ROUTE_LOG[0]
-    if log is not None:
+    for log in tuple(_ROUTE_LOGS):  # another thread may open or close one
         log.append((kernel, tuple(int(d) for d in shape), bool(routed)))
     return routed
 

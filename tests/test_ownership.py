@@ -222,6 +222,74 @@ class TestPTA190:
             assert not _diags(main, code), code
 
 
+def _paged_read_fixture(mark, bound):
+    """``paged_decode_attention`` over two @POOL vars of 8 blocks of 4
+    cells, its table marked (or not) the way a builder would."""
+    main, startup, g = _guarded()
+    with g:
+        blk = main.global_block
+        pk = _mk_pool(blk, "@own/self_k0@POOL", (32, 16))
+        pv = _mk_pool(blk, "@own/self_v0@POOL", (32, 16))
+        tab = _mk_state(blk, "@own/block_tab", (3, 2))
+        pos = _mk_state(blk, "@own/step", (3,), "int64")
+        if mark:
+            absint.mark_pool_index_source(tab, mark, bound=bound)
+        q = layers.data("q", shape=[3, 1, 16], dtype="float32",
+                        append_batch_size=False)
+        layers.paged_decode_attention(q, pk, pv, tab, pos,
+                                      block_size=4, n_heads=2)
+    return main
+
+
+class TestPTA190UncheckedRead:
+    """The paged attention read copies the blocks its table names with
+    no clamp and no fill: PTA190's proof is what licenses that, so
+    every way of not having the proof is an error at build time."""
+
+    @pytest.mark.parametrize("mark,bound,needle", [
+        (None, None, "UNKNOWN provenance"),
+        ("block_table", None, "unprovable"),
+        ("block_table", 9, "exceeds"),
+    ], ids=["unknown_provenance", "no_bound", "bound_past_the_pool"])
+    def test_unproven_table_is_error(self, mark, bound, needle):
+        ds = [d for d in _diags(_paged_read_fixture(mark, bound),
+                                "PTA190") if needle in d.message]
+        # one finding a pool: the table addresses both
+        assert len(ds) == 2 and {d.severity for d in ds} == {ERROR}
+        assert {d.var for d in ds} == {"@own/self_k0@POOL",
+                                       "@own/self_v0@POOL"}
+
+    def test_proven_table_is_clean_and_counted(self):
+        main = _paged_read_fixture("block_table", 8)
+        for code in ("PTA190", "PTA191", "PTA192"):
+            assert not _diags(main, code), code
+        reads = [a for a in absint.analyze(main).pool_accesses
+                 if a.kind == "read"]
+        assert [(a.index_var, a.axis_size, a.unchecked)
+                for a in reads] == [("@own/block_tab", 8, True)] * 2
+        ledger = absint.analyze(main).ownership_ledger()
+        assert ledger["proven_reads"] == 2 and ledger["unproven"] == 0
+
+    def test_pool_slot_is_judged_whatever_the_var_is_called(self):
+        """A var without the @POOL mark wired to a pool slot is still
+        read unchecked, so the proof is still owed."""
+        main, startup, g = _guarded()
+        with g:
+            blk = main.global_block
+            pk = _mk_state(blk, "plain_k", (32, 16), "float32")
+            pv = _mk_state(blk, "plain_v", (32, 16), "float32")
+            tab = layers.data("tab", shape=[3, 2], dtype="int32",
+                              append_batch_size=False)
+            pos = layers.data("pos", shape=[3], dtype="int64",
+                              append_batch_size=False)
+            q = layers.data("q", shape=[3, 1, 16], dtype="float32",
+                            append_batch_size=False)
+            layers.paged_decode_attention(q, pk, pv, tab, pos,
+                                          block_size=4, n_heads=2)
+        ds = _diags(main, "PTA190")
+        assert len(ds) == 2 and all(d.severity == ERROR for d in ds)
+
+
 class TestProvenanceSoundness:
     """Regression pins for the review-found holes in the bound/
     one-hot algebra: each was a way to certify a LYING bound (a

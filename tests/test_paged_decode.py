@@ -622,6 +622,20 @@ class TestMaskedPoolWriteOp:
         t.check_output(atol=0, rtol=0)
 
 
+class _OpCtx:
+    """What a registry kernel reads of its op: inputs by slot, attrs
+    by name."""
+
+    def __init__(self, inputs, attrs):
+        self._inputs, self._attrs = inputs, attrs
+
+    def input(self, slot):
+        return self._inputs.get(slot)
+
+    def attr(self, name, default=None):
+        return self._attrs.get(name, default)
+
+
 def _eqns(jaxpr):
     """Every equation of a jaxpr and of the jaxprs its equations hold
     (the jitted call, the decode While's body, branches)."""
@@ -670,22 +684,12 @@ class TestPoolAddressedAsStored:
 
         kernel = get_op_info("masked_pool_write").kernel
 
-        class Ctx:
-            def __init__(self, inputs, attrs):
-                self._inputs, self._attrs = inputs, attrs
-
-            def input(self, slot):
-                return self._inputs.get(slot)
-
-            def attr(self, name, default=None):
-                return self._attrs.get(name, default)
-
         for lead, shape in ((1, (7, 2, 8, 64)), (1, (256, 128)),
                             (2, (32, 8, 2, 64))):
             def lowered(pool, new, idx, gate):
-                return kernel(Ctx({"Pool": pool, "New": new,
-                                   "Index": idx, "Gate": gate},
-                                  {"leading_dims": lead}))
+                return kernel(_OpCtx({"Pool": pool, "New": new,
+                                      "Index": idx, "Gate": gate},
+                                     {"leading_dims": lead}))
 
             closed = jax.make_jaxpr(lowered)(
                 np.zeros(shape, np.float32),
@@ -807,39 +811,270 @@ class TestCowProgram:
             bundle.init_slot_state(scope)
 
 
-class TestPagedAttentionKernel:
-    """Interpret-mode validation of the Pallas paged-attention kernel
-    (ops/pallas/paged_attention.py) against its jnp oracle — the
-    kernel is NOT routed into the decode programs (CLAUDE.md: A/B on
-    the chip first, ROADMAP S7), but its code path must stay
-    correct."""
+def _paged_attention_oracle(q, pool_k, pool_v, tab, pos, block_size,
+                            n_heads, scale):
+    """Plain einsum attention over the cells a table names, on the
+    stored ``[cells, H*Dh]`` pools: query j of lane r attends cache
+    positions <= pos[r] + j."""
+    r, nq, hd = q.shape
+    d = hd // n_heads
+    t = tab.shape[1] * block_size
+    cells = (tab[:, :, None] * block_size
+             + np.arange(block_size)[None, None, :]).reshape(r, t)
+    k = pool_k[cells].reshape(r, t, n_heads, d).astype(np.float64)
+    v = pool_v[cells].reshape(r, t, n_heads, d).astype(np.float64)
+    s = np.einsum("rqhd,rthd->rhqt",
+                  q.reshape(r, nq, n_heads, d).astype(np.float64),
+                  k) * scale
+    seen = (np.arange(t)[None, None, :]
+            <= (pos[:, None] + np.arange(nq)[None, :])[:, :, None])
+    s = np.where(seen[:, None], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return np.einsum("rhqt,rthd->rqhd", p, v).reshape(r, nq, hd)
 
-    def test_interpret_mode_matches_reference(self):
+
+class TestPagedAttentionRead:
+    """``paged_decode_attention`` (ops/paged_ops.py) on both routes of
+    ops/pallas/paged_attention.py, the Pallas kernel in interpret mode
+    and the jnp reference, against a plain einsum oracle on the stored
+    ``[cells, H*Dh]`` pools."""
+
+    BSk, NBk, NP, Hh, Dh = 8, 12, 4, 2, 64
+    # lane: (table row, position). Block 0 is what a cleared row of
+    # an idle or dustbin lane names; lanes 3 and 4 share their two
+    # leading blocks (a radix or prefix hit).
+    LANES = {
+        "position_0": ([3, 0, 0, 0], 0),
+        "last_position": ([5, 1, 7, 2], 31),
+        "idle_row_block_0": ([0, 0, 0, 0], 0),
+        "shared_prefix_a": ([4, 6, 8, 0], 20),
+        "shared_prefix_b": ([4, 6, 9, 10], 27),
+        "mid_block": ([11, 2, 0, 0], 11),
+    }
+
+    def _inputs(self, nq):
+        rng = np.random.RandomState(5)
+        hd = self.Hh * self.Dh
+        tab = np.array([row for row, _ in self.LANES.values()],
+                       np.int32)
+        pos = np.array([p for _, p in self.LANES.values()], np.int32)
+        q = rng.randn(len(pos), nq, hd).astype(np.float32)
+        pk = rng.randn(self.NBk * self.BSk, hd).astype(np.float32)
+        pv = rng.randn(self.NBk * self.BSk, hd).astype(np.float32)
+        return q, pk, pv, tab, pos
+
+    def _run_op(self, q, pk, pv, tab, pos, interpret):
+        """Through the registry op, as a program runs it; returns the
+        context rows and the routing record."""
+        import jax
+
+        from paddle_tpu.core.registry import get_op_info
+        from paddle_tpu.ops import pallas
+        from paddle_tpu.ops.pallas import attention as base
+
+        kernel = get_op_info("paged_decode_attention").kernel
+
+        slots = ("Q", "PoolK", "PoolV", "Table", "Pos")
+        attrs = {"block_size": self.BSk, "n_heads": self.Hh,
+                 "scale": 0.125}
+        base.force_interpret(interpret)
+        try:
+            with pallas.record_routes() as routes:
+                out = jax.jit(lambda *a: kernel(_OpCtx(
+                    dict(zip(slots, a)), attrs)))(q, pk, pv, tab, pos)
+        finally:
+            base.force_interpret(False)
+        return np.asarray(out), routes
+
+    @pytest.mark.parametrize("lane", list(LANES))
+    @pytest.mark.parametrize("route,nq", [
+        ("kernel", 1), ("reference", 1), ("reference", 3)])
+    def test_matches_einsum_oracle(self, route, nq, lane):
+        q, pk, pv, tab, pos = self._inputs(nq)
+        got, routes = self._run_op(q, pk, pv, tab, pos,
+                                   interpret=(route == "kernel"))
+        assert routes == [("paged_decode_attention", q.shape,
+                           route == "kernel")]
+        want = _paged_attention_oracle(q, pk, pv, tab, pos, self.BSk,
+                                       self.Hh, 0.125)
+        i = list(self.LANES).index(lane)
+        np.testing.assert_allclose(got[i], want[i], rtol=2e-5,
+                                   atol=2e-5)
+
+    def test_kernel_refuses_what_it_cannot_take(self):
+        """``usable`` decides on shapes alone: several queries a lane
+        (the speculative verify step), a width off the 128 lanes, and
+        the CPU without interpret mode all take the reference."""
         import jax.numpy as jnp
 
         from paddle_tpu.ops.pallas import attention as base
         from paddle_tpu.ops.pallas import paged_attention as pa
 
-        rng = np.random.RandomState(5)
-        R, Hh, Dh, NBk, BSk, NP = 5, 2, 64, 7, 8, 3
-        q = rng.randn(R, Hh, Dh).astype(np.float32)
-        pk = rng.randn(NBk, BSk, Hh, Dh).astype(np.float32)
-        pv = rng.randn(NBk, BSk, Hh, Dh).astype(np.float32)
-        # distinct blocks per lane (the allocator invariant)
-        tab = np.stack([rng.permutation(NBk)[:NP]
-                        for _ in range(R)]).astype(np.int32)
-        step = rng.randint(0, NP * BSk, (R,)).astype(np.int32)
-        assert pa.usable(jnp.asarray(q), jnp.asarray(pk), tab) \
-            is False  # CPU without interpret mode: gated off
+        q, pk, pv, tab, pos = self._inputs(1)
+        q3 = self._inputs(3)[0]
+        assert not pa.usable(jnp.asarray(q), jnp.asarray(pk), tab,
+                             self.BSk)
         base.force_interpret(True)
         try:
-            assert pa.usable(jnp.asarray(q), jnp.asarray(pk), tab)
-            got = np.asarray(pa.paged_decode_attention(
-                jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv),
-                jnp.asarray(tab), jnp.asarray(step), scale=0.125))
+            assert pa.usable(jnp.asarray(q), jnp.asarray(pk), tab,
+                             self.BSk)
+            assert not pa.usable(jnp.asarray(q3), jnp.asarray(pk), tab,
+                                 self.BSk)
+            assert not pa.usable(jnp.asarray(q[:, :, :96]),
+                                 jnp.asarray(pk[:, :96]), tab, self.BSk)
+            assert not pa.usable(jnp.asarray(q), jnp.asarray(pk), tab,
+                                 4)
         finally:
             base.force_interpret(False)
-        want = np.asarray(pa.paged_decode_attention_reference(
-            jnp.asarray(q), jnp.asarray(pk), jnp.asarray(pv),
-            jnp.asarray(tab), jnp.asarray(step), scale=0.125))
-        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+    def test_no_environment_switch_is_read(self, monkeypatch):
+        """The route is the shapes' and the backend's alone."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.ops.pallas import attention as base
+        from paddle_tpu.ops.pallas import paged_attention as pa
+
+        q, pk, _, tab, _ = self._inputs(1)
+        monkeypatch.setenv("PADDLE_TPU_DISABLE_PAGED_ATTN", "1")
+        base.force_interpret(True)
+        try:
+            assert pa.usable(jnp.asarray(q), jnp.asarray(pk), tab,
+                             self.BSk)
+        finally:
+            base.force_interpret(False)
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """Untrained weights at a width the kernel takes (H*Dh = 128):
+    one scope under a dense and a paged bundle."""
+    from paddle_tpu import unique_name
+    from paddle_tpu.core.scope import Scope
+    from paddle_tpu.models import transformer as T
+
+    fluid.seed(3)
+    model = dict(seq_len=S, d_model=128, n_heads=2, n_layers=2,
+                 d_inner=64, vocab=V)
+    scope = Scope()
+    exe = fluid.Executor(fluid.TPUPlace(0))
+    with unique_name.guard():
+        _, startup, _ = T.build_program(
+            with_optimizer=False, dropout_rate=0.0, **model)
+    exe.run(startup, scope=scope)
+    kwargs = dict(max_out_len=MAXT, start_id=2, end_id=END_ID,
+                  **model)
+    with unique_name.guard():
+        dense = T.build_decode_step_program(n_slots=N_SLOTS, **kwargs)
+    with unique_name.guard():
+        paged = T.build_decode_step_program(
+            n_slots=N_SLOTS, state_prefix="@pgw/",
+            cache=CacheConfig(layout="paged", block_size=BS,
+                              n_blocks=NB, n_prompt_entries=8),
+            **kwargs)
+    return {"exe": exe, "scope": scope, "dense": dense,
+            "paged": paged}
+
+
+class TestKernelIsWhatAServerDispatches:
+    def test_served_tokens_equal_the_dense_servers(self, wide):
+        """One served generation a prompt, end to end, with the
+        kernel (interpret mode) in every paged serve program: token
+        for token the dense server's, the second wave through the
+        prefix-hit programs; ``stats()`` and the routing record name
+        the route each program took."""
+        from paddle_tpu.ops import pallas
+        from paddle_tpu.ops.pallas import attention as base
+
+        srcs = np.random.RandomState(23).randint(
+            3, V, (6, S)).astype(np.int64)
+        with ContinuousGenerationServer(
+                wide["dense"], executor=wide["exe"],
+                scope=wide["scope"]) as srv:
+            want = np.stack([r.result(timeout=120.0) for r in
+                             [srv.submit(s) for s in srcs]])
+            assert set(map(tuple, srv.stats()[
+                "self_attention_routes"].values())) == {()}
+        base.force_interpret(True)
+        try:
+            with pallas.record_routes() as routes, \
+                    PagedContinuousGenerationServer(
+                        wide["paged"], executor=wide["exe"],
+                        scope=wide["scope"],
+                        radix_reuse=False) as srv:
+                got = [np.stack([r.result(timeout=300.0) for r in
+                                 [srv.submit(s) for s in srcs]])
+                       for _ in range(2)]
+                st = srv.stats()
+        finally:
+            base.force_interpret(False)
+        np.testing.assert_array_equal(got[0], want)
+        np.testing.assert_array_equal(got[1], want)
+        assert st["block_pool"]["prefix_hits"] >= len(srcs)
+        taken = [v for v in st["self_attention_routes"].values() if v]
+        assert len(taken) >= 2 and all(v == ["kernel"] for v in taken)
+        mine = {(shape, routed) for k, shape, routed in routes
+                if k == "paged_decode_attention"}
+        assert mine == {((N_SLOTS + 1, 1, 128), True)}
+
+
+class TestNoDenseViewOfAPool:
+    """Fails on the parent of ISSUE 31: the lowered paged tick at the
+    serve cell's rehearsal size builds nothing of a dense view's
+    shape, ``[R, H, maxT, Dh]`` or ``[R*maxT, H*Dh]``, and fills no
+    gathered pool rows."""
+
+    def test_lowered_tick_at_rehearsal_size(self, trained):
+        import json
+        import os
+        import re
+
+        from paddle_tpu import unique_name
+        from paddle_tpu.core.scope import Scope
+        from paddle_tpu.models import transformer as T
+
+        with open(os.path.join(
+                os.path.dirname(__file__), "..", "benchmark", "chip",
+                "configs", "transformer-big-serve.json")) as f:
+            cfg = json.load(f)
+        c = {**cfg["sizes"], **cfg["rehearsal"]}
+        model = dict(seq_len=c["seq_len"], d_model=c["d_model"],
+                     n_heads=c["n_heads"], n_layers=c["n_layers"],
+                     d_inner=c["d_inner"], vocab=c["vocab"])
+        scope, exe = Scope(), trained["exe"]
+        with unique_name.guard():
+            _, startup, _ = T.build_program(
+                with_optimizer=False, dropout_rate=0.0, **model)
+        exe.run(startup, scope=scope)
+        with unique_name.guard():
+            bundle = T.build_decode_step_program(
+                n_slots=c["n_slots"], state_prefix="@noview/",
+                cache=CacheConfig(
+                    layout="paged", block_size=c["block_size"],
+                    n_blocks=c["n_blocks"],
+                    n_prompt_entries=c["n_prompt_entries"]),
+                max_out_len=c["max_out_len"], start_id=2, end_id=1,
+                **model)
+        srv = PagedContinuousGenerationServer(
+            bundle, executor=exe, scope=scope, start=False)
+        try:
+            text = srv._serves[0].lowered_text()
+        finally:
+            srv.close()
+        rows, maxT = c["n_slots"] + 1, c["max_out_len"]
+        heads, hd = c["n_heads"], c["d_model"]
+        view = f"tensor<{rows}x{heads}x{maxT}x{hd // heads}xf32>"
+        flat = f"tensor<{rows * maxT}x{hd}xf32>"
+        assert view not in text and flat not in text
+        # the self pools are gathered by whole blocks under the
+        # in-bounds promise: no select fills rows for an index out of
+        # range (jnp.take's fill mode did, one pass a pool a tick)
+        gathers = re.findall(r'"stablehlo.gather"\(.*', text)
+        block = f"tensor<{rows}x{maxT // c['block_size']}x" \
+                f"{c['block_size']}x{hd}xf32>"
+        assert sum(block in g for g in gathers) == 2 * c["n_layers"]
+        assert not re.search(
+            r"stablehlo.select.*" + re.escape(block), text)
+        assert not re.search(
+            r"stablehlo.select.*" + re.escape(
+                f"tensor<{rows}x{maxT}x{hd}xf32>"), text)

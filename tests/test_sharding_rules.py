@@ -720,6 +720,93 @@ class TestPagedSpecOpRules:
             [(None, None, "tp", None), (), (), ()], 4)
         assert got == _spec_to_pspec(facts.spec("@rulepool"), 4)
 
+    def test_paged_decode_attention_keeps_head_shard_no_collective(
+            self):
+        """Pools sharded on H*Dh under tp: the context rows come out
+        sharded the same way and the tick pays no reshard, no gather
+        and no psum (every contraction is inside one head); GSPMD
+        picks the same layout for the reference route."""
+        NB, BS, H, Dh, R, NP = 8, 4, 4, 8, 5, 2
+        main, startup, g = _guarded()
+        with g:
+            pools = []
+            for tag in "kv":
+                pool = main.global_block.create_var(
+                    name=f"@rule/self_{tag}0@POOL",
+                    shape=(NB * BS, H * Dh), dtype="float32",
+                    persistable=True, stop_gradient=True)
+                absint.mark_sharded(pool, {1: "tp"})
+                pools.append(pool)
+            q = _data("q", (R, 1, H * Dh), {2: "tp"})
+            tab = _data("tab", (R, NP), dtype="int32")
+            absint.mark_pool_index_source(tab, "block_table", bound=NB)
+            pos = _data("pos", (R,), dtype="int64")
+            out = layers.paged_decode_attention(
+                q, pools[0], pools[1], tab, pos, block_size=BS,
+                n_heads=H, scale=0.5)
+        absint.set_mesh(main, MESH)
+        facts = self._facts(main)
+        assert facts.converged
+        assert facts.spec(out.name) == ShardSpec.of({2: "tp"})
+        assert not [es for es in facts.collective_events
+                    if es.site.op.type == "paged_decode_attention"]
+        assert not _diags(main, "PTA190")
+
+        from paddle_tpu.ops.pallas.paged_attention import \
+            paged_attention_reference
+
+        import jax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+        from paddle_tpu.ops import pallas
+
+        def fn(q, pk, pv, tab, pos):
+            # as the executor traces a program a mesh places
+            with pallas.auto_partitioned():
+                return paged_attention_reference(
+                    q, pk, pv, tab, pos, block_size=BS, n_heads=H,
+                    scale=0.5)
+
+        arrays = [np.zeros((R, 1, H * Dh), np.float32),
+                  np.zeros((NB * BS, H * Dh), np.float32),
+                  np.zeros((NB * BS, H * Dh), np.float32),
+                  np.zeros((R, NP), np.int32), np.zeros((R,), np.int32)]
+        pspecs = [(None, None, "tp"), (None, "tp"), (None, "tp"), (),
+                  ()]
+        got = _jax_out_pspec(fn, arrays, pspecs, 3)
+        assert got == _spec_to_pspec(facts.spec(out.name), 3)
+        # and GSPMD's program for it holds no collective at all
+        mesh = Mesh(np.array(jax.devices()[:8]).reshape(4, 2),
+                    ("dp", "tp"))
+        hlo = jax.jit(fn).lower(*[
+            jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=NamedSharding(
+                mesh, PartitionSpec(*p)))
+            for a, p in zip(arrays, pspecs)]).compile().as_text()
+        for collective in ("all-reduce", "all-gather", "all-to-all",
+                           "collective-permute"):
+            assert collective not in hlo, collective
+
+    def test_paged_decode_attention_sharded_table_is_an_event(self):
+        NB, BS, H, Dh, R, NP = 8, 4, 4, 8, 8, 2
+        main, startup, g = _guarded()
+        with g:
+            pools = [main.global_block.create_var(
+                name=f"@rule2/self_{tag}0@POOL",
+                shape=(NB * BS, H * Dh), dtype="float32",
+                persistable=True, stop_gradient=True)
+                for tag in "kv"]
+            q = _data("q", (R, 1, H * Dh))
+            tab = _data("tab", (R, NP), {0: "dp"}, dtype="int32")
+            pos = _data("pos", (R,), dtype="int64")
+            layers.paged_decode_attention(
+                q, pools[0], pools[1], tab, pos, block_size=BS,
+                n_heads=H)
+        absint.set_mesh(main, MESH)
+        kinds = [es.event.kind for es in
+                 self._facts(main).collective_events
+                 if es.site.op.type == "paged_decode_attention"]
+        assert kinds == ["allgather"]
+
     def test_span_scatter_keeps_buffer_layout(self):
         R, T, W = 8, 16, 4
         main, startup, g = _guarded()

@@ -87,3 +87,37 @@ def test_grouped_products_of_the_expert_layer(one_chip, rows,
     names = _kernel_names(compiled)
     assert any("tgmm" in n for n in names), names
     assert any("gmm" in n and "tgmm" not in n for n in names), names
+
+
+def test_paged_decode_attention_at_the_serve_cells_size(one_chip):
+    """transformer-big-serve: 33 lanes, 16 heads of 64, 1,280 blocks
+    of 16 cells, 6 layers. The pools go in as stored and the layers
+    share one lowering of the kernel (a cell binds up to 19 serve
+    programs at set-up)."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    rows, heads, dim, bs, pages, blocks, layers = 33, 16, 64, 16, 16, \
+        1280, 6
+
+    def tick(q, pools, tab, pos):
+        for li in range(layers):
+            q = q + pa.paged_decode_attention(
+                q, pools[2 * li], pools[2 * li + 1], tab, pos,
+                block_size=bs, n_heads=heads, scale=dim ** -0.5)
+        return q
+    lowered = jax.jit(tick).lower(
+        _spec(one_chip, (rows, 1, heads * dim), jnp.float32),
+        [_spec(one_chip, (blocks * bs, heads * dim), jnp.float32)
+         for _ in range(2 * layers)],
+        _spec(one_chip, (rows, pages), jnp.int32),
+        _spec(one_chip, (rows,), jnp.int32))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    compiled = lowered.compile()
+    assert _kernel_names(compiled) == {"paged_decode_attention"}
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == layers
+    # nothing of a dense view's size, and no pool staged or copied
+    for shape in ("f32[33,16,256,64]", "f32[8448,1024]",
+                  "f32[33,256,1024]"):
+        assert shape not in text, shape
+    assert "copy-start" not in text
