@@ -1314,7 +1314,8 @@ class ContinuousGenerationServer:
         st = bundle.state
         self._fetches = [st["tok_buf"], st["step"], st["active"],
                          st["finished"]] + self._spec_names \
-            + self._lane_names + self._devtel.fetch_names
+            + self._lane_names + self._devtel.fetch_names \
+            + self._extra_fetch_names()
         self._serves = {}
         for key, prog in sorted(bundle.serves.items(),
                                 key=lambda kv: str(kv[0])):
@@ -1887,6 +1888,11 @@ class ContinuousGenerationServer:
                 [req.seed for _, req in admits]
                 + [0] * (A - len(admits)), np.int64)
         return A, feed
+
+    def _extra_fetch_names(self) -> List[str]:
+        """Hook: state a scheduler wants back from every dispatch
+        behind the base fetches (they end `outs`)."""
+        return []
 
     def _pre_dispatch(self):
         """Hook: publish host-owned state (paged block tables) just
@@ -2557,6 +2563,17 @@ class PagedContinuousGenerationServer(ContinuousGenerationServer):
     FIFO admission only: ``admit_select`` hooks are rejected (tier
     grouping owns the admission order).
     """
+
+    def __new__(cls, bundle=None, *args, **kwargs):
+        # a decoder-only bundle (no encoder, prompts of their own
+        # length) is planned by inference/decoder_only.py; the cycle,
+        # the pools and the radix tree are this class's
+        if cls is PagedContinuousGenerationServer \
+                and getattr(bundle, "decoder_only", False):
+            from .decoder_only import DecoderOnlyPagedServer
+
+            cls = DecoderOnlyPagedServer
+        return super().__new__(cls)
 
     def __init__(self, bundle, radix_reuse=True, chunked_prefill=None,
                  prefill_worker=None, **kwargs):

@@ -581,3 +581,88 @@ def spec_accept(proposals, draft_probs, target_probs, seed, pos, k,
 
 __all__.extend(["filtered_softmax", "sample_categorical",
                 "span_scatter", "spec_accept"])
+
+
+# ---------------------------------------------------------------------------
+# the paged side of latent attention with a learned selection
+# (ops/paged_ops.py): rows come in G groups, each one lane with its row
+# of the block table
+# ---------------------------------------------------------------------------
+def paged_cell_index(block_tab, pos, block_size, name=None):
+    """Pool rows [N] int32 of positions pos [N] through block_tab [G,
+    NP] (N = G * n, group-major)."""
+    helper = LayerHelper("paged_cell_index", input=pos, name=name)
+    out = helper.create_variable_for_type_inference("int32", True)
+    helper.append_op("paged_cell_index",
+                     {"Table": block_tab, "Pos": pos}, {"Out": out},
+                     {"block_size": int(block_size)})
+    return out
+
+
+def dsa_indexer_scores(q_i, w, pool, block_tab, pos, block_size,
+                       name=None):
+    """Indexer scores [N, NP*BS] float32 of every cached position of
+    each row's lane (-inf past the row's own position), read from the
+    indexer-key pool through block_tab [G, NP]."""
+    helper = LayerHelper("dsa_indexer_scores", input=q_i, name=name)
+    out = helper.create_variable_for_type_inference("float32", True)
+    helper.append_op(
+        "dsa_indexer_scores",
+        {"QI": q_i, "W": w, "Pool": pool, "Table": block_tab,
+         "Pos": pos}, {"Out": out}, {"block_size": int(block_size)})
+    return out
+
+
+def dsa_select(scores, k, mode="indices", name=None):
+    """Each row's k largest scores: mode "indices" their positions [N,
+    k] int32 (-1 where it has fewer live positions), mode "threshold"
+    the k-th largest score [N] float32 (ops/paged_ops.py)."""
+    helper = LayerHelper("dsa_select", input=scores, name=name)
+    out = helper.create_variable_for_type_inference(
+        "int32" if mode == "indices" else "float32", True)
+    helper.append_op("dsa_select", {"Scores": scores}, {"Out": out},
+                     {"k": int(k), "mode": mode})
+    return out
+
+
+def sparse_latent_attention(q_lat, pool, block_tab, sel, block_size,
+                            latent_dim, scale=1.0, k=0, cells=None,
+                            name=None):
+    """[N, H, latent_dim] float32: attention of the absorbed queries
+    q_lat [N, H, rkv+dr] over the rows of the latent pool that the
+    selection names, addressed through block_tab [G, NP]. `sel`: [N,
+    K] positions of the row's lane (-1 for none), or the pair (scores
+    [N, T], threshold [N]) of `dsa_select(mode="threshold")` with the
+    selection's size `k`. The read surface of the latent pools."""
+    helper = LayerHelper("sparse_latent_attention", input=q_lat,
+                         name=name)
+    out = helper.create_variable_for_type_inference("float32", True)
+    chosen = {"Scores": sel[0], "Thr": sel[1]} \
+        if isinstance(sel, tuple) else {"Sel": sel}
+    if cells is not None:       # paged_cell_index of sel, made once
+        chosen["Cells"] = cells
+    helper.append_op(
+        "sparse_latent_attention",
+        {"Q": q_lat, "Pool": pool, "Table": block_tab, **chosen},
+        {"Out": out},
+        {"block_size": int(block_size), "latent_dim": int(latent_dim),
+         "scale": float(scale), "k": int(k)})
+    return out
+
+
+__all__.extend(["paged_cell_index", "dsa_indexer_scores", "dsa_select",
+                "sparse_latent_attention"])
+
+
+def lane_probe_write(hist, new, gate, step=None, name=None):
+    """hist [R, K] (or [R, T, K] with step [R]) takes new [R, K] in the
+    rows (at the steps) of the lanes whose gate is 1, in place."""
+    helper = LayerHelper("lane_probe_write", input=hist, name=name)
+    inputs = {"Hist": hist, "New": new, "Gate": gate}
+    if step is not None:
+        inputs["Step"] = step
+    helper.append_op("lane_probe_write", inputs, {"Out": hist}, {})
+    return hist
+
+
+__all__.append("lane_probe_write")

@@ -1775,3 +1775,121 @@ def moe_dropless(input, num_experts, d_inner, top_k, experts_held=None,
 
 __all__.extend(["rms_norm", "rotary_embedding", "swiglu", "short_conv",
                 "moe_dropless"])
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA) and the sparse-attention indexer (ops/lm_ops.py)
+# ---------------------------------------------------------------------------
+def _matrix(helper, name, shape, dtype):
+    from ..param_attr import ParamAttr
+
+    return helper.create_parameter(
+        ParamAttr(name=name), list(shape), dtype,
+        default_initializer=NormalInitializer(0.0, shape[-2] ** -0.5))
+
+
+def _ones(helper, name, size, dtype, value=1.0):
+    from ..param_attr import ParamAttr
+
+    return helper.create_parameter(
+        ParamAttr(name=name), [size], dtype,
+        default_initializer=ConstantInitializer(value))
+
+
+def mla_project(x, pos, n_heads, q_lora_rank, kv_lora_rank,
+                qk_nope_head_dim, qk_rope_head_dim, v_head_dim,
+                rope_theta=10000.0, epsilon=1e-5, row_width=0, name=None):
+    """Latent attention's projections of rows x [N, D] at positions
+    pos [N]: returns (q_lat [N, H, W], the queries with the key
+    up-projection absorbed; c_q [N, rq]; latent [N, W], the cache
+    rows; kv_b, the up-projection parameter `mla_output` takes); W is
+    `row_width` (default rkv + dr; zeros past rkv + dr).
+    Parameters `<name>_q_a.w`, `_q_a_norm.w`, `_q_b.w`, `_kv_a.w`,
+    `_kv_a_norm.w`, `_kv_b.w`."""
+    helper = LayerHelper("mla_project", input=x, name=name)
+    p = name or helper.name
+    d, dt = x.shape[-1], x.dtype
+    h, rq, rkv = n_heads, q_lora_rank, kv_lora_rank
+    dn, dr, dv = qk_nope_head_dim, qk_rope_head_dim, v_head_dim
+    qa = _matrix(helper, f"{p}_q_a.w", (d, rq), dt)
+    qan = _ones(helper, f"{p}_q_a_norm.w", rq, dt)
+    qb = _matrix(helper, f"{p}_q_b.w", (rq, h * (dn + dr)), dt)
+    kva = _matrix(helper, f"{p}_kv_a.w", (d, rkv + dr), dt)
+    kvan = _ones(helper, f"{p}_kv_a_norm.w", rkv, dt)
+    kvb = _matrix(helper, f"{p}_kv_b.w", (rkv, h * (dn + dv)), dt)
+    outs = {s: helper.create_variable_for_type_inference(dt, True)
+            for s in ("QLat", "CQ", "Latent")}
+    helper.append_op(
+        "mla_project",
+        {"X": x, "Pos": pos, "QA": qa, "QANorm": qan, "QB": qb,
+         "KVA": kva, "KVANorm": kvan, "KVB": kvb}, outs,
+        {"n_heads": int(h), "qk_nope_head_dim": int(dn),
+         "qk_rope_head_dim": int(dr), "theta": float(rope_theta),
+         "epsilon": float(epsilon), "row_width": int(row_width)})
+    return outs["QLat"], outs["CQ"], outs["Latent"], kvb
+
+
+def mla_output(ctx, kv_b, qk_nope_head_dim, name=None):
+    """ctx [N, H, rkv] (attention over latent rows) through the value
+    up-projection in kv_b -> [N, H*dv]."""
+    helper = LayerHelper("mla_output", input=ctx, name=name)
+    out = helper.create_variable_for_type_inference(kv_b.dtype, True)
+    helper.append_op("mla_output", {"Ctx": ctx, "KVB": kv_b},
+                     {"Out": out},
+                     {"qk_nope_head_dim": int(qk_nope_head_dim)})
+    return out
+
+
+def dsa_indexer_project(x, c_q, pos, n_heads, head_dim, rope_dim,
+                        rope_theta=10000.0, name=None):
+    """The sparse-attention indexer's projections of rows x [N, D]
+    (c_q [N, rq] from mla_project): (q_i [N, hi, di], k_i [N, di] the
+    indexer's cache rows, w [N, hi] float32). Parameters
+    `<name>_idx_q.w`, `_idx_k.w`, `_idx_k_norm.w`, `_idx_k_norm.b`,
+    `_idx_w.w`."""
+    helper = LayerHelper("dsa_indexer_project", input=x, name=name)
+    p = name or helper.name
+    d, dt, rq = x.shape[-1], x.dtype, c_q.shape[-1]
+    iq = _matrix(helper, f"{p}_idx_q.w", (rq, n_heads * head_dim), dt)
+    ik = _matrix(helper, f"{p}_idx_k.w", (d, head_dim), dt)
+    ikw = _ones(helper, f"{p}_idx_k_norm.w", head_dim, dt)
+    ikb = _ones(helper, f"{p}_idx_k_norm.b", head_dim, dt, 0.0)
+    iw = _matrix(helper, f"{p}_idx_w.w", (d, n_heads), dt)
+    qi = helper.create_variable_for_type_inference(dt, True)
+    ki = helper.create_variable_for_type_inference(dt, True)
+    w = helper.create_variable_for_type_inference("float32", True)
+    helper.append_op(
+        "dsa_indexer_project",
+        {"X": x, "CQ": c_q, "Pos": pos, "IQ": iq, "IK": ik,
+         "IKNormW": ikw, "IKNormB": ikb, "IW": iw},
+        {"QI": qi, "KI": ki, "W": w},
+        {"n_heads": int(n_heads), "rope_dim": int(rope_dim),
+         "theta": float(rope_theta)})
+    return qi, ki, w
+
+
+def lm_head(x, vocab, param_attr, name=None):
+    """float32 logits of rows x [N, D] through an untied head [D, V]
+    kept in x's dtype."""
+    helper = LayerHelper("lm_head", input=x, name=name)
+    w = _matrix(helper, param_attr, (x.shape[-1], vocab), x.dtype)
+    out = helper.create_variable_for_type_inference("float32", True)
+    helper.append_op("lm_head", {"X": x, "W": w}, {"Out": out}, {})
+    return out
+
+
+def moe_tick_stats(chosen, active, first_held, n_held, name=None):
+    """(pairs [1], hit [1], load [n_held]) that the rows with active 1
+    sent to the held experts (chosen [N, k] from moe_dropless)."""
+    helper = LayerHelper("moe_tick_stats", input=chosen, name=name)
+    outs = {s: helper.create_variable_for_type_inference("int64", True)
+            for s in ("Pairs", "Hit", "Load")}
+    helper.append_op("moe_tick_stats",
+                     {"Chosen": chosen, "Active": active}, outs,
+                     {"first_held": int(first_held),
+                      "n_held": int(n_held)})
+    return outs["Pairs"], outs["Hit"], outs["Load"]
+
+
+__all__.extend(["mla_project", "mla_output", "dsa_indexer_project",
+                "lm_head", "moe_tick_stats"])

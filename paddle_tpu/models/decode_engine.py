@@ -1224,6 +1224,117 @@ class DecodeStepBundle:
                 scope._set(name, np.zeros(shape, dt))
 
 
+class DecoderOnlyStepBundle:
+    """Program set of a DECODER-ONLY model on the slot pool (the
+    builder in models/glm_moe_dsa.py): no encoder, no cross-attention
+    prompt table. A request's prompt goes into its lane's OWN paged
+    cache in chunks; the radix tree over prompt tokens is the only
+    prefix cache. What the servers read of a DecodeStepBundle is here
+    under the same names (`serves`, `state`, `n_slots`, `dustbin`,
+    `max_out_len`, `end_id`, `cache`, `init_slot_state`,
+    `serve_feed_spec`); `decoder_only` tells
+    PagedContinuousGenerationServer to plan by prompt length.
+
+    * ``serves[0]`` — the tick-only program: a While of decode ticks,
+      each advancing every live lane one token.
+    * ``serves[PREFILL]`` — prompt chunks, one after another, largest
+      size first: for each of ``chunk_sizes`` up to ``max_chunks``
+      chunks of at most that many tokens (each a lane, a start position
+      and a length: the chunk's latent and indexer-key rows land in the
+      lane's blocks, its queries see the cached prefix and the chunk
+      under the causal mask); then the admission of up to
+      ``max_chunks`` lanes whose prompt is now cached but for its last
+      token (token buffer, base position, limit, active flag); then
+      the same While of ticks.
+
+    Position 0 of a lane's row of ``tok_buf`` holds the prompt's last
+    token, at cache position ``base``; tick s reads position s of the
+    row, writes cache position base + s, and emits position s + 1. A
+    lane ends after ``limit`` tokens (its request's max_new_tokens) or
+    at the end token. ``max_out_len`` is the row's length: the most
+    new tokens a request may ask for, plus one. ``context`` is the
+    most positions (prompt and reply) a lane's table can address."""
+
+    decoder_only = True
+    PREFILL = ("prefill", 0)    # the serve key of the prefill program
+    seq_len = None              # prompts come in their own length
+    spec_k = 0
+    spec_k_options = ()
+    tokens_per_tick = 1
+    needs_seeds = False
+    sharding = None
+    sharding_plan = None
+
+    def __init__(self, serves, startup, state, state_specs, n_slots,
+                 max_out_len, context, end_id, cache, chunk_sizes,
+                 max_chunks, probes=None, selection_size=0):
+        self.serves = dict(serves)
+        self.startup = startup
+        self.state = dict(state)
+        self._state_specs = dict(state_specs)
+        self.n_slots = n_slots
+        self.dustbin = n_slots
+        self.max_out_len = max_out_len
+        self.context = context
+        self.end_id = end_id
+        self.start_id = None
+        self.cache = cache
+        self.chunk_sizes = tuple(sorted(chunk_sizes))
+        self.max_chunks = max_chunks
+        # {"selected": {layer: var}, "chosen": {layer: var}}: what the
+        # last tick of a lane selected and how its ticks were routed
+        self.probes = probes or {}
+        # positions a query attends at most (0: all it has cached)
+        self.selection_size = selection_size
+
+    def programs(self):
+        return [p for _k, p in sorted(self.serves.items(),
+                                      key=lambda kv: str(kv[0]))]
+
+    def cache_token(self) -> tuple:
+        return self.cache.token() + ("decoder_only", self.context,
+                                     self.chunk_sizes, self.max_chunks)
+
+    def serve_feed_spec(self, key) -> List[tuple]:
+        feed = [("n_steps", (1,), "int64"),
+                ("min_active", (1,), "int64")]
+        if key == 0:
+            return feed
+        a = self.max_chunks
+        chunks = [spec for c in self.chunk_sizes for spec in (
+            (f"chunk_toks_{c}", (a, c), "int64"),
+            (f"chunk_lane_{c}", (a,), "int64"),
+            (f"chunk_pos_{c}", (a,), "int64"),
+            (f"chunk_len_{c}", (a,), "int64"),
+            (f"n_chunks_{c}", (1,), "int64"))]
+        return chunks + [("admit_slots", (a,), "int64"),
+                         ("admit_tok", (a,), "int64"),
+                         ("admit_base", (a,), "int64"),
+                         ("admit_limit", (a,), "int64")] + feed
+
+    def kv_state_bytes(self) -> int:
+        import jax.numpy as jnp
+
+        return sum(int(np.prod(shape)) * jnp.dtype(dt).itemsize
+                   for name, (shape, dt) in self._state_specs.items()
+                   if name.endswith(POOL_MARK)
+                   or name == self.state["block_tab"])
+
+    def init_slot_state(self, scope):
+        """Seed the pool state in `scope`: idle lanes finished and not
+        active, everything else zero. The pools are made on the device
+        (the latent pool of a deployment is gigabytes)."""
+        import jax.numpy as jnp
+
+        for name, (shape, dt) in self._state_specs.items():
+            if name.endswith(POOL_MARK):
+                scope._set(name, jnp.zeros(shape, dt))
+            elif name == self.state["finished"]:
+                scope._set(name, np.ones(shape, dt))
+            else:
+                scope._set(name, np.zeros(shape, dt))
+
+
 def _slot_state_specs(prefix, rows, maxT, seq_len, n_heads,
                       head_dim, n_layers, cache, sampling=None,
                       draft=None, vocab=None):
@@ -1750,6 +1861,187 @@ def _pair_lint_draft_target(draft, *, seq_len, max_out_len, d_model,
             + format_diagnostics(diags))
 
 
+def tel_add(sv, state_prefix, logical, delta):
+    """Device-telemetry increment: var = var + delta on a bundle
+    counter (observability/devtel.py registry); silently skipped for
+    counters the bundle does not carry (tel_admit_hit on dense
+    bundles). Shared by every slot-pool builder."""
+    var = sv.get(f"{state_prefix}{logical}{devtel.TEL_MARK}")
+    if var is None:
+        return
+    layers.assign(layers.elementwise_add(var, delta), output=var)
+
+
+def lane_onehots(slots, A, rows):
+    """One-hot masks over the fed slot ids of an admission of A rows:
+    (oh [A, rows] float32, any_f, any_i, keep_f, keep_i [rows]). Padded
+    rows all point at the dustbin, whose scatter-sum is garbage by
+    design; min() clamps its multiplicity in the masks."""
+    lane_range = layers.cast(layers.range(0, rows, 1), "int64")
+    oh = layers.cast(
+        layers.equal(lane_range,
+                     layers.reshape(slots, [A, 1])),
+        "float32")
+    any_f = layers.elementwise_min(
+        layers.reduce_sum(oh, dim=0),
+        layers.fill_constant([rows], "float32", 1.0))
+    any_i = layers.cast(any_f, "int64")
+    keep_f = layers.elementwise_sub(
+        layers.fill_constant([rows], "float32", 1.0), any_f)
+    keep_i = layers.elementwise_sub(
+        layers.fill_constant([rows], "int64", 1.0), any_i)
+    return oh, any_f, any_i, keep_f, keep_i
+
+
+def emit_lane_tokens(tok, tok_buf, stepv, fin, act, rows, maxT, end_id,
+                     emit_flag=None, room_limit=None):
+    """The per-lane emit tail of a slot-pool tick (emit_token_step
+    vectorised over lane counters; the same freeze and write): `tok`
+    [rows] int64 is what each lane's step chose. Finished lanes keep
+    emitting end_id; the token lands at position step + 1 of the
+    lane's row of `tok_buf` (not where `emit_flag` is 0: a lane that
+    replays its history); the EOS latch counts only lanes that
+    advanced (`act`); a lane deactivates on EOS or when its step
+    reaches `room_limit` ([1] or [rows] int64; default maxT - 1, the
+    end of the buffer). Mutates tok_buf / stepv / fin / act in place;
+    every slot-pool builder's step body ends here, so their emission
+    cannot diverge."""
+    ones_n = layers.fill_constant([rows], "int64", 1.0)
+    if emit_flag is None:
+        emit_flag = ones_n
+    if room_limit is None:
+        room_limit = layers.fill_constant([1], "int64", float(maxT - 1))
+    positions = layers.cast(layers.range(0, maxT, 1), "int64")
+    not_fin = layers.elementwise_sub(ones_n, fin)
+    tok = layers.elementwise_add(
+        layers.elementwise_mul(tok, not_fin),
+        layers.cast(layers.scale(fin, scale=float(end_id)),
+                    "int64"))
+    # the EOS latch only counts lanes that actually ADVANCED this
+    # tick (act gate): a host-paused paged lane (no KV block for
+    # its next write) decodes a garbage token — its tok_buf write
+    # is re-done correctly on resume, but an un-gated fin latch
+    # would freeze the lane on garbage-EOS permanently
+    new_fin = layers.elementwise_max(
+        fin, layers.elementwise_mul(
+            layers.elementwise_mul(act, emit_flag),
+            layers.cast(layers.equal(
+                tok, layers.fill_constant(
+                    [1], "int64", float(end_id))), "int64")))
+    next2 = layers.reshape(
+        layers.elementwise_add(stepv, ones_n), [rows, 1])
+    next_mask = layers.cast(layers.equal(positions, next2),
+                            "int64")                   # [R,maxT]
+    next_mask = layers.elementwise_mul(
+        next_mask, layers.reshape(emit_flag, [rows, 1]))
+    keep_tok = layers.elementwise_sub(
+        layers.fill_constant([rows, maxT], "int64", 1.0),
+        next_mask)
+    new_step = layers.elementwise_add(stepv, act)  # gate by lane
+    layers.assign(layers.elementwise_add(
+        layers.elementwise_mul(tok_buf, keep_tok),
+        layers.elementwise_mul(next_mask,
+                               layers.reshape(tok, [rows, 1]))),
+        output=tok_buf)
+    layers.assign(new_step, output=stepv)
+    # lanes auto-deactivate on EOS or buffer exhaustion — the
+    # host retires a lane the moment its active flag drops
+    room = layers.cast(layers.less_than(new_step, room_limit),
+                       "int64")                        # [N]
+    new_act = layers.elementwise_mul(
+        layers.elementwise_mul(
+            act, layers.elementwise_sub(ones_n, new_fin)),
+        room)
+    layers.assign(new_act, output=act)
+    layers.assign(new_fin, output=fin)
+
+
+def build_serve_program(specs, state_prefix, pre_body, step_body,
+                        mark=None):
+    """One fused scheduler-cycle program of a slot-pool bundle: the
+    slot state declared, `pre_body(sv)` (an admission, a prefill chunk,
+    or nothing), then a While that runs `step_body(sv)` until `n_steps`
+    ticks ran or the live-lane count drops to `min_active` (both fed as
+    [1] int64), then the burst's exit reason counted once. `mark(sv)`
+    annotates the declared state (ownership sources). Every serve
+    program of every bundle has this shape, so the servers drive them
+    alike."""
+    import paddle_tpu as fluid
+
+    prog = fluid.Program()
+    with fluid.program_guard(prog, fluid.Program()):
+        sv = _declare_slot_state(prog.global_block, specs)
+        if mark is not None:
+            sv = mark(sv)
+        pre_body(sv)
+        n_steps = layers.data("n_steps", shape=[1], dtype="int64",
+                              append_batch_size=False)
+        min_active = layers.data("min_active", shape=[1],
+                                 dtype="int64",
+                                 append_batch_size=False)
+        act = sv[f"{state_prefix}active"]
+        k = layers.fill_constant([1], "int64", 0)
+
+        def _serve_cond(cond=None):
+            # ticks remain AND live lanes exceed the exit
+            # threshold: min(a, b) > 0
+            out = layers.greater_than(
+                layers.elementwise_min(
+                    layers.elementwise_sub(n_steps, k),
+                    layers.elementwise_sub(
+                        layers.reduce_sum(act, keep_dim=True),
+                        min_active)),
+                layers.fill_constant([1], "int64", 0.0),
+                cond=cond)
+            # divergence-source annotation (analysis/absint.py
+            # seed table): this predicate derives from the
+            # per-lane active mask — the moment a lowering shards
+            # LANES across a mesh axis it differs per device,
+            # and the burst While becomes divergent control
+            # flow. The prover (PTA130/131) uses the mark to
+            # REJECT collectives/sharded values inside the burst
+            # with a proof instead of a pattern guess. axes=
+            # names the lane-sharding axis: on a tp-only mesh
+            # (heads sharded, lanes replicated) the mark is
+            # provably inert and the guard classifies from its
+            # actual inputs — which is what lets the tp-sharded
+            # serve programs carry their vocab-psum INSIDE the
+            # burst legally (GSPMD-uniform control flow), while
+            # any future lanes-sharding mesh flips this back to
+            # proven-divergent automatically.
+            absint.mark_divergence_source(out, "lane_active_mask",
+                                          axes=(LANE_AXIS,))
+            return out
+
+        cond = _serve_cond()
+        w = layers.While(cond)
+        with w.block():
+            step_body(sv)
+            layers.increment(k, 1)
+            _serve_cond(cond=cond)
+        # devtel: classify THIS burst's exit exactly once, after
+        # the While (k and act read their final loop values).
+        # Precedence: ran all n_steps ticks > every lane idle >
+        # live dropped to min_active — int arithmetic only, no
+        # logical ops (the emit_token_step conjunction idiom)
+        ran_out = layers.cast(layers.equal(k, n_steps), "int64")
+        live = layers.reduce_sum(act, keep_dim=True)
+        idle = layers.cast(
+            layers.equal(live,
+                         layers.fill_constant([1], "int64", 0.0)),
+            "int64")
+        one = layers.fill_constant([1], "int64", 1.0)
+        not_ran = layers.elementwise_sub(one, ran_out)
+        tel_add(sv, state_prefix, "tel_exit_n_steps", ran_out)
+        tel_add(sv, state_prefix, "tel_exit_all_idle",
+                layers.elementwise_mul(not_ran, idle))
+        tel_add(sv, state_prefix, "tel_exit_min_active",
+                layers.elementwise_mul(
+                    not_ran,
+                    layers.elementwise_sub(one, idle)))
+    return prog
+
+
 def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
                               n_heads=4, n_layers=2, d_inner=128,
                               vocab=1000, start_id=0, end_id=1,
@@ -1838,10 +2130,7 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
     # counters this layout does not carry, e.g. tel_admit_hit on
     # dense bundles) ------------------------------------------------
     def _tel_add(sv, logical, delta):
-        var = sv.get(f"{state_prefix}{logical}{devtel.TEL_MARK}")
-        if var is None:
-            return
-        layers.assign(layers.elementwise_add(var, delta), output=var)
+        tel_add(sv, state_prefix, logical, delta)
 
     # --- ownership mint-site annotations (analysis/absint.py seed
     # table): every paged program declares the SAME host-owned index
@@ -1867,23 +2156,7 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
     # masks over the fed slot ids, then token-buffer/counter/flag
     # resets for exactly the admitted lanes --------------------------
     def _lane_onehots(slots, A):
-        lane_range = layers.cast(layers.range(0, rows, 1), "int64")
-        # [A, rows] one-hot per admitted prompt; padded rows all
-        # point at the dustbin, whose scatter-sum is garbage by
-        # design — min() clamps its multiplicity in the masks
-        oh = layers.cast(
-            layers.equal(lane_range,
-                         layers.reshape(slots, [A, 1])),
-            "float32")
-        any_f = layers.elementwise_min(
-            layers.reduce_sum(oh, dim=0),
-            layers.fill_constant([rows], "float32", 1.0))
-        any_i = layers.cast(any_f, "int64")
-        keep_f = layers.elementwise_sub(
-            layers.fill_constant([rows], "float32", 1.0), any_f)
-        keep_i = layers.elementwise_sub(
-            layers.fill_constant([rows], "int64", 1.0), any_i)
-        return oh, any_f, any_i, keep_f, keep_i
+        return lane_onehots(slots, A, rows)
 
     def _reset_lane_state(sv, any_i, keep_i, oh=None, seeds=None,
                           tier="miss"):
@@ -2337,11 +2610,6 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
         else:
             tok = layers.cast(layers.argmax(logits_v, axis=-1),
                               "int64")                     # [R]
-        not_fin = layers.elementwise_sub(ones_n, fin)
-        tok = layers.elementwise_add(
-            layers.elementwise_mul(tok, not_fin),
-            layers.cast(layers.scale(fin, scale=float(end_id)),
-                        "int64"))
         # teacher forcing (radix tail prefill / beam probe): while
         # step+1 < prefill_until the lane is REPLAYING its history —
         # the decoder ran and its KV write landed (that is the whole
@@ -2350,52 +2618,15 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
         # must not latch fin. prefill_until defaults to 0 everywhere,
         # so non-radix lanes take emit_flag == act identically to the
         # pre-forcing lowering.
-        emit_flag = ones_n
+        emit_flag = None
         if paged:
             forcing = layers.elementwise_mul(
                 act, layers.cast(layers.less_than(
                     layers.elementwise_add(stepv, ones_n),
                     sv[f"{state_prefix}prefill_until"]), "int64"))
             emit_flag = layers.elementwise_sub(ones_n, forcing)
-        # the EOS latch only counts lanes that actually ADVANCED this
-        # tick (act gate): a host-paused paged lane (no KV block for
-        # its next write) decodes a garbage token — its tok_buf write
-        # is re-done correctly on resume, but an un-gated fin latch
-        # would freeze the lane on garbage-EOS permanently
-        new_fin = layers.elementwise_max(
-            fin, layers.elementwise_mul(
-                layers.elementwise_mul(act, emit_flag),
-                layers.cast(layers.equal(
-                    tok, layers.fill_constant(
-                        [1], "int64", float(end_id))), "int64")))
-        next2 = layers.reshape(
-            layers.elementwise_add(stepv, ones_n), [rows, 1])
-        next_mask = layers.cast(layers.equal(positions, next2),
-                                "int64")                   # [R,maxT]
-        next_mask = layers.elementwise_mul(
-            next_mask, layers.reshape(emit_flag, [rows, 1]))
-        keep_tok = layers.elementwise_sub(
-            layers.fill_constant([rows, maxT], "int64", 1.0),
-            next_mask)
-        new_step = layers.elementwise_add(stepv, act)  # gate by lane
-        layers.assign(layers.elementwise_add(
-            layers.elementwise_mul(tok_buf, keep_tok),
-            layers.elementwise_mul(next_mask,
-                                   layers.reshape(tok, [rows, 1]))),
-            output=tok_buf)
-        layers.assign(new_step, output=stepv)
-        # lanes auto-deactivate on EOS or buffer exhaustion — the
-        # host retires a lane the moment its active flag drops
-        room = layers.cast(layers.less_than(
-            new_step, layers.fill_constant([1], "int64",
-                                           float(maxT - 1))),
-            "int64")                                       # [N]
-        new_act = layers.elementwise_mul(
-            layers.elementwise_mul(
-                act, layers.elementwise_sub(ones_n, new_fin)),
-            room)
-        layers.assign(new_act, output=act)
-        layers.assign(new_fin, output=fin)
+        emit_lane_tokens(tok, tok_buf, stepv, fin, act, rows, maxT,
+                         end_id, emit_flag=emit_flag)
 
     # --- the speculative (draft-and-verify) step body: k unrolled
     # cached DRAFT steps propose tokens per lane, ONE batched
@@ -2856,78 +3087,10 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
         # _k0_body while sharing the SAME slot-state specs, so
         # controller re-bucketing is pure program selection (all
         # executables built up front, zero steady-state compiles)
-        step_body = body if step_body is None else step_body
-        prog = fluid.Program()
-        with fluid.program_guard(prog, fluid.Program()):
-            sv = _mark_ownership(
-                _declare_slot_state(prog.global_block, specs))
-            pre_body(sv)
-            n_steps = layers.data("n_steps", shape=[1], dtype="int64",
-                                  append_batch_size=False)
-            min_active = layers.data("min_active", shape=[1],
-                                     dtype="int64",
-                                     append_batch_size=False)
-            act = sv[f"{state_prefix}active"]
-            k = layers.fill_constant([1], "int64", 0)
-
-            def _serve_cond(cond=None):
-                # ticks remain AND live lanes exceed the exit
-                # threshold: min(a, b) > 0
-                out = layers.greater_than(
-                    layers.elementwise_min(
-                        layers.elementwise_sub(n_steps, k),
-                        layers.elementwise_sub(
-                            layers.reduce_sum(act, keep_dim=True),
-                            min_active)),
-                    layers.fill_constant([1], "int64", 0.0),
-                    cond=cond)
-                # divergence-source annotation (analysis/absint.py
-                # seed table): this predicate derives from the
-                # per-lane active mask — the moment a lowering shards
-                # LANES across a mesh axis it differs per device,
-                # and the burst While becomes divergent control
-                # flow. The prover (PTA130/131) uses the mark to
-                # REJECT collectives/sharded values inside the burst
-                # with a proof instead of a pattern guess. axes=
-                # names the lane-sharding axis: on a tp-only mesh
-                # (heads sharded, lanes replicated) the mark is
-                # provably inert and the guard classifies from its
-                # actual inputs — which is what lets the tp-sharded
-                # serve programs carry their vocab-psum INSIDE the
-                # burst legally (GSPMD-uniform control flow), while
-                # any future lanes-sharding mesh flips this back to
-                # proven-divergent automatically.
-                absint.mark_divergence_source(out, "lane_active_mask",
-                                              axes=(LANE_AXIS,))
-                return out
-
-            cond = _serve_cond()
-            w = layers.While(cond)
-            with w.block():
-                step_body(sv)
-                layers.increment(k, 1)
-                _serve_cond(cond=cond)
-            # devtel: classify THIS burst's exit exactly once, after
-            # the While (k and act read their final loop values).
-            # Precedence: ran all n_steps ticks > every lane idle >
-            # live dropped to min_active — int arithmetic only, no
-            # logical ops (the emit_token_step conjunction idiom)
-            ran_out = layers.cast(layers.equal(k, n_steps), "int64")
-            live = layers.reduce_sum(act, keep_dim=True)
-            idle = layers.cast(
-                layers.equal(live,
-                             layers.fill_constant([1], "int64", 0.0)),
-                "int64")
-            one = layers.fill_constant([1], "int64", 1.0)
-            not_ran = layers.elementwise_sub(one, ran_out)
-            _tel_add(sv, "tel_exit_n_steps", ran_out)
-            _tel_add(sv, "tel_exit_all_idle",
-                     layers.elementwise_mul(not_ran, idle))
-            _tel_add(sv, "tel_exit_min_active",
-                     layers.elementwise_mul(
-                         not_ran,
-                         layers.elementwise_sub(one, idle)))
-        return prog
+        return build_serve_program(
+            specs, state_prefix, pre_body,
+            body if step_body is None else step_body,
+            mark=_mark_ownership)
 
     # --- chunked-prefill phase bodies (cache.chunk_tokens > 0): the
     # miss admission's encoder, re-cut into resumable C-token ticks.
@@ -3736,6 +3899,14 @@ class PromptPrefixCache:
         return sum(1 for r in self._refs.values() if r > 0)
 
 
+class BlockKeys(list):
+    """A token sequence already cut into the radix tree's keys, one
+    hashable a whole block (`bytes` of the block's ids, say): what a
+    caller with prompts of tens of thousands of tokens hands
+    `RadixBlockTree` in place of the tokens, so that no call makes a
+    tuple of every id."""
+
+
 class _RadixNode:
     __slots__ = ("chunk", "block", "children", "parent", "order",
                  "queued")
@@ -3819,6 +3990,8 @@ class RadixBlockTree:
                            (-len(node.order), node.order, node))
 
     def _chunks(self, tokens):
+        if isinstance(tokens, BlockKeys):
+            return list(tokens)
         toks = tuple(int(t) for t in tokens)
         bs = self.block_size
         return [toks[i:i + bs] for i in
@@ -3957,16 +4130,18 @@ class RadixBlockTree:
 
 
 __all__ = ["CacheConfig", "SamplingConfig", "DraftConfig",
-           "ShardingConfig", "DecodeStepBundle", "DECODE_STEPS_VAR",
+           "ShardingConfig", "DecodeStepBundle",
+           "DecoderOnlyStepBundle", "DECODE_STEPS_VAR",
            "POOL_MARK", "LANE_AXIS",
            "tp_param_placements", "annotate_sharded_program",
            "place_sharded_bundle", "place_sharded_program",
            "ServingUnavailable", "BlockPoolExhausted",
            "BlockLifetimeError", "AdmissionInfeasible",
-           "HostBlockPool", "RadixBlockTree",
+           "HostBlockPool", "RadixBlockTree", "BlockKeys",
            "PromptPrefixCache", "build_greedy_decode_program",
            "build_incremental_decode_program",
            "build_decode_step_program", "build_beam_decode_program",
            "cached_decoder_step",
            "step_logits", "init_token_buffer", "emit_token_step",
-           "heads_of"]
+           "emit_lane_tokens", "lane_onehots", "tel_add",
+           "build_serve_program", "heads_of"]

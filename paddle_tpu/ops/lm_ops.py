@@ -114,3 +114,158 @@ def moe_dropless(ctx):
         scope=ctx.attr("scope", "moe"))
     return {"Out": out.reshape(shape), "Chosen": idx, "Load": load,
             "PairsHere": pairs}
+
+
+# ---------------------------------------------------------------------
+# Latent attention (MLA) and the sparse-attention indexer (DSA) of the
+# glm_moe_dsa family (models/glm_moe_dsa.py). The pool side (scores over
+# a lane's cached indexer keys, the selection, attention over the
+# selected rows of the latent pool) is in ops/paged_ops.py.
+# ---------------------------------------------------------------------
+def rope_interleaved(x, pos, theta):
+    """Rotary positions on the pairs (2i, 2i+1) of x's last axis
+    (`rope_interleave`): pair i of row n turns by pos[n] *
+    theta^(-2i/dim). x [N, ..., dim]; pos [N]. float32 in, float32
+    out."""
+    dim = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    angle = pos.astype(jnp.float32)[:, None] * inv
+    angle = angle.reshape(angle.shape[:1] + (1,) * (x.ndim - 2)
+                          + angle.shape[1:])
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    a, b = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([a * cos - b * sin, a * sin + b * cos],
+                     -1).reshape(x.shape)
+
+
+def _rms(x, g, eps):
+    xf = x.astype(jnp.float32)
+    return xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True)
+                              + eps) * g.astype(jnp.float32)
+
+
+def _dot(a, b):
+    """a [..., k] x b [k, n] in the operands' dtype with float32
+    accumulation, float32 out."""
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
+@register_op("mla_project", differentiable=False)
+def mla_project(ctx):
+    """The down- and up-projections of latent attention for N rows.
+
+    X [N, D] (after the layer's RMS norm); Pos [N] int (cache position
+    of each row); QA [D, rq], QANorm [rq], QB [rq, H*(dn+dr)]; KVA [D,
+    rkv+dr], KVANorm [rkv], KVB [rkv, H*(dn+dv)]. Outputs: CQ [N, rq]
+    (the query latent, which the indexer reads too); Latent [N, rkv+dr]
+    = [RMSNorm(c_KV) | rotated k_r], the row the cache holds; QLat [N,
+    H, rkv+dr] = [W_UK,h^T q_nope,h | rotated q_rope,h], the query with
+    the key up-projection absorbed, so that its product with a cache
+    row is the head's score (decode and prefill alike: no key is ever
+    expanded a head). attr row_width (default rkv + dr): Latent and
+    QLat are that wide, zeros past rkv + dr. Norms and angles float32;
+    products in X's dtype with float32 accumulation."""
+    x, pos = ctx.input("X"), ctx.input("Pos").reshape(-1)
+    h = int(ctx.attr("n_heads"))
+    dn, dr = int(ctx.attr("qk_nope_head_dim")), \
+        int(ctx.attr("qk_rope_head_dim"))
+    eps, theta = float(ctx.attr("epsilon", 1e-5)), \
+        float(ctx.attr("theta", 10000.0))
+    dt = x.dtype
+    kvb = ctx.input("KVB")
+    rkv = kvb.shape[0]
+    with jax.named_scope("glm.mla_proj"):
+        cq = _rms(_dot(x, ctx.input("QA")), ctx.input("QANorm"),
+                  eps).astype(dt)
+        q = _dot(cq, ctx.input("QB")).reshape(-1, h, dn + dr)
+        q_rope = rope_interleaved(q[..., dn:], pos, theta)
+        ckv = _dot(x, ctx.input("KVA"))
+        c = _rms(ckv[:, :rkv], ctx.input("KVANorm"), eps)
+        kr = rope_interleaved(ckv[:, rkv:], pos, theta)
+        w_uk = kvb.reshape(rkv, h, -1)[:, :, :dn]
+        q_lat = jnp.einsum("nhd,rhd->nhr", q[..., :dn].astype(dt), w_uk,
+                           preferred_element_type=jnp.float32)
+    # a row of the pool is `row_width` numbers wide (the latent row
+    # rounded up to whole lane tiles); what lies past rkv + dr is zero
+    # in rows and queries alike and adds nothing to a score
+    pad = max(0, int(ctx.attr("row_width", 0)) - (rkv + dr))
+    return {"CQ": cq,
+            "Latent": jnp.concatenate(
+                [c, kr, jnp.zeros((x.shape[0], pad), jnp.float32)],
+                -1).astype(dt),
+            "QLat": jnp.concatenate(
+                [q_lat, q_rope,
+                 jnp.zeros((x.shape[0], h, pad), jnp.float32)],
+                -1).astype(dt)}
+
+
+@register_op("mla_output", differentiable=False)
+def mla_output(ctx):
+    """The value up-projection after attention over latent rows: Ctx
+    [N, H, rkv] (softmax-weighted sums of cache rows' latent part) x
+    W_UV,h (the value columns of KVB [rkv, H*(dn+dv)]) -> [N, H*dv],
+    what the output projection takes."""
+    c, kvb = ctx.input("Ctx"), ctx.input("KVB")
+    h = c.shape[1]
+    dn = int(ctx.attr("qk_nope_head_dim"))
+    with jax.named_scope("glm.mla_proj"):
+        w_uv = kvb.reshape(kvb.shape[0], h, -1)[:, :, dn:]
+        out = jnp.einsum("nhr,rhv->nhv", c.astype(kvb.dtype), w_uv,
+                         preferred_element_type=jnp.float32)
+    return {"Out": out.reshape(c.shape[0], -1).astype(kvb.dtype)}
+
+
+@register_op("dsa_indexer_project", differentiable=False)
+def dsa_indexer_project(ctx):
+    """The indexer's projections for N rows. X [N, D] (after the
+    layer's RMS norm); CQ [N, rq]; Pos [N]; IQ [rq, hi*di]; IK [D, di];
+    IKNormW, IKNormB [di] (LayerNorm); IW [D, hi]. Outputs QI [N, hi,
+    di] and KI [N, di] (the row the indexer's cache holds), both with
+    rotary positions on their first `rope_dim` numbers, and W [N, hi]
+    float32, scaled by hi^-0.5 * di^-0.5."""
+    x, cq = ctx.input("X"), ctx.input("CQ")
+    pos = ctx.input("Pos").reshape(-1)
+    hi, ri = int(ctx.attr("n_heads")), int(ctx.attr("rope_dim"))
+    theta = float(ctx.attr("theta", 10000.0))
+    with jax.named_scope("glm.indexer"):
+        qi = _dot(cq, ctx.input("IQ")).reshape(cq.shape[0], hi, -1)
+        di = qi.shape[-1]
+        qi = jnp.concatenate(
+            [rope_interleaved(qi[..., :ri], pos, theta), qi[..., ri:]],
+            -1)
+        k = _dot(x, ctx.input("IK"))
+        mean = k.mean(-1, keepdims=True)
+        var = jnp.mean(jnp.square(k - mean), -1, keepdims=True)
+        k = (k - mean) * jax.lax.rsqrt(var + 1e-6) \
+            * ctx.input("IKNormW").astype(jnp.float32) \
+            + ctx.input("IKNormB").astype(jnp.float32)
+        k = jnp.concatenate(
+            [rope_interleaved(k[..., :ri], pos, theta), k[..., ri:]], -1)
+        w = _dot(x, ctx.input("IW")) * (hi ** -0.5 * di ** -0.5)
+    return {"QI": qi.astype(x.dtype), "KI": k.astype(x.dtype), "W": w}
+
+
+@register_op("lm_head", differentiable=False)
+def lm_head(ctx):
+    """Logits in float32: X [N, D] x W [D, V], operands in their own
+    dtype, float32 accumulation and result."""
+    return {"Out": _dot(ctx.input("X"), ctx.input("W"))}
+
+
+@register_op("moe_tick_stats", differentiable=False)
+def moe_tick_stats(ctx):
+    """What a decode tick's live lanes sent to the experts held here:
+    Chosen [N, k] int32 (a row's chosen experts), Active [N] 0/1.
+    Outputs Pairs [1] int64 (pairs on held experts), Hit [1] int64
+    (held experts with at least one pair), Load [n_held] int64."""
+    chosen = ctx.input("Chosen")
+    act = ctx.input("Active").reshape(-1, 1) > 0
+    first, n = int(ctx.attr("first_held")), int(ctx.attr("n_held"))
+    local = chosen - first
+    held = (local >= 0) & (local < n) & act
+    load = jnp.sum(
+        (local[..., None] == jnp.arange(n)) & held[..., None],
+        axis=(0, 1)).astype(jnp.int32)
+    return {"Pairs": load.sum().reshape(1),
+            "Hit": jnp.sum(load > 0).astype(jnp.int32).reshape(1),
+            "Load": load}

@@ -121,3 +121,68 @@ def test_paged_decode_attention_at_the_serve_cells_size(one_chip):
                   "f32[33,256,1024]"):
         assert shape not in text, shape
     assert "copy-start" not in text
+
+
+# ---------------------------------------------------------------------
+# the GLM-5.2 serve cell's paged routes (ops/paged_ops.py) at the
+# cell's widths: 33 lanes, a table of 576 pages of 64 positions over
+# 8,192 blocks, a latent row of 576 and an indexer key of 128 numbers,
+# 2,048 selected of 36,864 positions
+# ---------------------------------------------------------------------
+GLM = dict(rows=33, pages=576, bs=64, blocks=8192, latent=576, rkv=512,
+           heads=64, hi=32, di=128, topk=2048)
+
+
+def _temp_gb(compiled):
+    return compiled.memory_analysis().temp_size_in_bytes / 1e9
+
+
+def test_glm_decode_tick_indexer_selection_and_sparse_attention(one_chip):
+    from paddle_tpu.ops import paged_ops as P
+
+    g = GLM
+    cells = g["blocks"] * g["bs"]
+
+    def tick(qi, w, ipool, q, pool, tab, pos):
+        s = P.indexer_scores(qi, w, ipool, tab, pos, g["bs"])
+        val, idx = jax.lax.top_k(s, g["topk"])
+        sel = jnp.where(val > -jnp.inf, idx, -1).astype(jnp.int32)
+        return P.sparse_latent_attention_reference(
+            q, pool, tab, sel, g["bs"], g["rkv"], 0.0625)
+    compiled = jax.jit(tick).lower(
+        _spec(one_chip, (g["rows"], g["hi"], g["di"])),
+        _spec(one_chip, (g["rows"], g["hi"]), jnp.float32),
+        _spec(one_chip, (cells, g["di"])),
+        _spec(one_chip, (g["rows"], g["heads"], g["latent"])),
+        _spec(one_chip, (cells, g["latent"])),
+        _spec(one_chip, (g["rows"], g["pages"]), jnp.int32),
+        _spec(one_chip, (g["rows"],), jnp.int32)).compile()
+    # a lane's keys and scores and its selected rows (and, compiled
+    # for a chip that is not there, one relayout of the pool from the
+    # layout the compiler would like its argument in)
+    assert _temp_gb(compiled) < 1.5
+
+
+@pytest.mark.parametrize("chunk", [64, 1024])
+def test_glm_prefill_chunk_threshold_and_dense_attention(one_chip, chunk):
+    from paddle_tpu.ops import paged_ops as P
+
+    g = GLM
+    cells = g["blocks"] * g["bs"]
+
+    def chunk_fn(qi, w, ipool, q, pool, tab, pos):
+        s = P.indexer_scores(qi, w, ipool, tab, pos, g["bs"])
+        thr = P.kth_largest(s, g["topk"])
+        return P.dense_masked_latent_attention(
+            q, pool, tab, s, thr, g["topk"], g["bs"], g["rkv"], 0.0625)
+    compiled = jax.jit(chunk_fn).lower(
+        _spec(one_chip, (chunk, g["hi"], g["di"])),
+        _spec(one_chip, (chunk, g["hi"]), jnp.float32),
+        _spec(one_chip, (cells, g["di"])),
+        _spec(one_chip, (chunk, g["heads"], g["latent"])),
+        _spec(one_chip, (cells, g["latent"])),
+        _spec(one_chip, (1, g["pages"]), jnp.int32),
+        _spec(one_chip, (chunk,), jnp.int32)).compile()
+    # a block of queries at a time: scores of 128 queries by 32 heads,
+    # then of 16 queries by 64 heads, over 36,864 positions
+    assert _temp_gb(compiled) < 2.0
