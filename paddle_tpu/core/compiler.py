@@ -207,8 +207,8 @@ class CompiledProgram:
             return arr
 
         placement = self._placement(mesh)
-        feed_arrays, feed_specs = _stage_feeds(feed, block, placement,
-                                               whole_shards)
+        feed_arrays, feed_specs = _stage_feeds(
+            feed, block, placement, executor._transfers, whole_shards)
         from .. import amp
         from .executor import _parallel_scope_token
 
@@ -224,7 +224,8 @@ class CompiledProgram:
                         block, tuple(sorted(feed_arrays)), fetch_names,
                         mesh, placement)
                 self._cache[key] = step
-        return step.dispatch(scope, feed_arrays, return_numpy)
+        return step.dispatch(scope, feed_arrays, return_numpy,
+                             executor._transfers)
 
     def _run_pipeline(self, feed, fetch_names, scope, mesh,
                       return_numpy):

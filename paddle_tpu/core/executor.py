@@ -568,8 +568,9 @@ def _pin_state_layout_formats(fn, state_ex, const_ex, feeds_ex, rng_ex,
         if f is not None and f.layout is not None:
             # jax array: keep the layout it already has
             return Format(f.layout, on_dev)
-        # host value (a numpy feed, a host-written block table): it is
-        # device_put per call and arrives in the device's DEFAULT
+        # host value (a numpy feed, which the executable places
+        # itself; a host-written block table, device_put every
+        # dispatch): it arrives in the device's DEFAULT
         # layout for its shape -- on the TPU not row-major for small
         # minor dims (an int32[9,3] table is (1,0)-major, tiled), so
         # no layout may be forced on it
@@ -634,9 +635,10 @@ class _Placement:
     """Where a step's arguments go: one policy, chosen once when the
     step is bound, read by _stage_feeds, _scope_state and _scope_rng.
 
-    * `device`: a single-device program. Host feeds and host state
-      are committed to the executor's device; placed state goes back
-      to the scope, so it is moved once.
+    * `device`: a single-device program. Host state is committed to
+      the executor's device and goes back to the scope placed, so it
+      is moved once; host feeds stay host arrays, and the executable,
+      whose entries are pinned to that device, takes them up itself.
     * `mesh` alone: a program with a bound sharding plan or under a
       context-/expert-parallel scope. Feeds stay uncommitted (the
       jit's shardings place them); state an earlier single-device
@@ -664,18 +666,74 @@ class _Placement:
             else cls(mesh=mesh)
 
 
-def _stage_feeds(feed, block, placement, check=None, np_dtypes=None):
+class _Transfers:
+    """The transfers at a dispatch's boundary, made together, and
+    their count; an Executor owns one (its `_metrics_samples` exposes
+    the counts). What a dispatch feeds and fetches is a handful of
+    arrays of a few bytes to a few KB, so a transfer costs its call
+    and its round trip, not its bytes: what has to be placed before
+    the call (a scope's host-written variables; the data-parallel
+    step's feeds) goes up in one `jax.device_put` a dispatch each,
+    and every fetch's copy to the host is queued behind the
+    computation when the call returns, so the host waits for the
+    device once and not once a fetch."""
+
+    __slots__ = ("dispatches", "placed_arrays", "placements",
+                 "fetched_arrays")
+
+    def __init__(self):
+        self.dispatches = 0
+        self.placed_arrays = 0
+        self.placements = 0
+        self.fetched_arrays = 0
+
+    def put(self, values, target):
+        """The list `values` on `target` (a device or a sharding)."""
+        if not values:
+            return values
+        self.placements += 1
+        self.placed_arrays += len(values)
+        return jax.device_put(values, target)
+
+    def note_puts(self, sp, arrays0, puts0):
+        """`arrays=` and `puts=` of the span `sp`: what was put since
+        the counts read `arrays0` and `puts0`."""
+        if sp.recording:
+            sp.attrs["arrays"] = self.placed_arrays - arrays0
+            sp.attrs["puts"] = self.placements - puts0
+
+    @staticmethod
+    def start_fetch(fetches):
+        """Queue every array's copy to the host; returns at once."""
+        for v in jax.tree_util.tree_leaves(fetches):
+            v.copy_to_host_async()
+
+    def fetched(self, fetches):
+        """`fetches` as numpy arrays, in order: waits for the copies
+        `start_fetch` queued."""
+        self.fetched_arrays += len(fetches)
+        return [np.asarray(v) for v in fetches]
+
+
+def _stage_feeds(feed, block, placement, transfers, check=None,
+                 np_dtypes=None):
     """(feed arrays, their (name, shape, dtype) specs) of one call's
     feed dict: the one `exe.feed` site of a per-call feed. Each value
     is coerced to its variable's dtype (from `np_dtypes`, a handle's
     bound table, else looked up in `block`), validated against the
-    declared shape, and placed. An entry point with a rule of its own
+    declared shape, and staged: a single-device program's host feeds
+    stay host arrays, because its executable is compiled with every
+    entry pinned to the caller's place (_pin_state_layout_formats)
+    and puts them there itself inside the call, for less than a
+    `device_put` from Python costs; where the placement gives a
+    sharding for the feeds (the data-parallel step) all of a call's
+    go onto it in one transfer. An entry point with a rule of its own
     passes `check(name, array) -> array` in place of that validation:
     a prepared handle holds the array to its bound spec, the
     data-parallel path cuts the remainder rows. The specs are the
     host arrays': what the cache keys carry."""
-    with _span("exe.feed"):
-        device, sharding = placement.device, placement.feeds
+    with _span("exe.feed") as sp:
+        sharding = placement.feeds
         arrays, specs = {}, []
         for name, val in feed.items():
             arr = _coerce_feed(val, np_dtypes[name] if np_dtypes
@@ -685,39 +743,50 @@ def _stage_feeds(feed, block, placement, check=None, np_dtypes=None):
             else:
                 arr = check(name, arr)
             specs.append((name, tuple(arr.shape), str(arr.dtype)))
-            if sharding is not None:
-                arr = jax.device_put(arr, sharding)
-            elif device is not None and not isinstance(arr, jax.Array):
-                # one explicit transfer to the caller's place
-                arr = jax.device_put(arr, device)
             arrays[name] = arr
+        arrays0, puts0 = transfers.placed_arrays, transfers.placements
+        if sharding is not None:
+            arrays = dict(zip(arrays, transfers.put(
+                list(arrays.values()), sharding)))
+        transfers.note_puts(sp, arrays0, puts0)
         return arrays, specs
 
 
-def _scope_state(scope, names, placement):
-    """Gather scope values for `names`, each where `placement` (see
-    _Placement) wants it."""
+def _scope_state(scope, groups, placement, transfers):
+    """Gather scope values: one dict for each list of names in
+    `groups`, each value where `placement` (see _Placement) wants it.
+    What a single-device program's scope holds as host arrays (a
+    server's scheduler writes its tables so before every dispatch)
+    goes to the device in one transfer for all groups, and back into
+    the scope placed."""
     device, mesh, rule = placement.device, placement.mesh, placement.rule
-    out = {}
-    for n in names:
-        v = scope._get(n)
-        if v is None:
-            raise RuntimeError(
-                f"Variable {n!r} is used before initialization -- "
-                f"run the startup program first")
-        if rule is not None:
-            v = rule(n, v)
-        elif device is not None:
-            if not isinstance(v, jax.Array):
-                v = jax.device_put(np.asarray(v), device)
-                scope._set(n, v)
-        else:
-            placed = _onto_mesh(v, mesh)
-            if placed is not v:
-                scope._set(n, placed)
-                v = placed
+    outs, going = [], []
+    for names in groups:
+        out = {}
+        outs.append(out)
+        for n in names:
+            v = scope._get(n)
+            if v is None:
+                raise RuntimeError(
+                    f"Variable {n!r} is used before initialization -- "
+                    f"run the startup program first")
+            if rule is not None:
+                v = rule(n, v)
+            elif device is not None:
+                if not isinstance(v, jax.Array):
+                    going.append((out, n, np.asarray(v)))
+                    continue
+            else:
+                placed = _onto_mesh(v, mesh)
+                if placed is not v:
+                    scope._set(n, placed)
+                    v = placed
+            out[n] = v
+    placed = transfers.put([v for _, _, v in going], device)
+    for (out, n, _), v in zip(going, placed):
         out[n] = v
-    return out
+        scope._set(n, v)
+    return outs
 
 
 def _scope_rng(scope, program, placement):
@@ -986,12 +1055,14 @@ class _BoundStep:
         self.program = program
         self.placement = placement
 
-    def gather(self, scope):
+    def gather(self, scope, transfers=None):
         """(mutable state, constant state, key) as the executable
-        takes them, from `scope`."""
+        takes them, from `scope`; `transfers` counts what had to be
+        placed (a diagnostic leaves it out)."""
         c = self.compiled
-        state = _scope_state(scope, c.state_in, self.placement)
-        const = _scope_state(scope, c.const_in, self.placement)
+        state, const = _scope_state(
+            scope, (c.state_in, c.const_in), self.placement,
+            transfers or _Transfers())
         for n, spec in c.write_only_specs.items():
             # a scan's write-only carry slot: step 1 overwrites the
             # zeros, the carry just needs a step-invariant structure
@@ -999,20 +1070,28 @@ class _BoundStep:
         return state, const, _scope_rng(scope, self.program,
                                         self.placement)
 
-    def dispatch(self, scope, feed_arrays, return_numpy):
+    def dispatch(self, scope, feed_arrays, return_numpy, transfers):
         """Run the executable once on staged feeds: gather
-        (`exe.state`), the asynchronous call (`exe.call`), the new
-        state and the advanced key back to the scope (`exe.store`)
-        and, with `return_numpy`, the host blocked on the device for
-        the fetches (`exe.fetch`)."""
+        (`exe.state`), the asynchronous call, after which every
+        fetch's copy to the host is queued behind the computation
+        (`exe.call`), the new state and the advanced key back to the
+        scope (`exe.store`) and, with `return_numpy`, the host blocked
+        on the device once for all the fetches (`exe.fetch`).
+        `transfers` is the calling executor's."""
         from ..flags import FLAGS
 
         c = self.compiled
-        with _span("exe.state"):
-            state, const, rng = self.gather(scope)
+        transfers.dispatches += 1
+        with _span("exe.state") as sp:
+            arrays0, puts0 = (transfers.placed_arrays,
+                              transfers.placements)
+            state, const, rng = self.gather(scope, transfers)
+            transfers.note_puts(sp, arrays0, puts0)
         with _span("exe.call"):
             new_state, fetches, rng_out = c.fn(state, const,
                                                feed_arrays, rng)
+            if return_numpy:
+                transfers.start_fetch(fetches)
         with _span("exe.store"):
             if FLAGS.check_nan_inf:
                 _check_nan_inf(new_state, fetches, c.fetch_names)
@@ -1021,8 +1100,10 @@ class _BoundStep:
                 scope._set(n, v)
         if not return_numpy:
             return list(fetches)
-        with _span("exe.fetch"):
-            return [np.asarray(v) for v in fetches]
+        with _span("exe.fetch") as sp:
+            if sp.recording:
+                sp.attrs["arrays"] = len(fetches)
+            return transfers.fetched(fetches)
 
     def lower(self, scope, feed_avals):
         """The executable's jax Lowered at `feed_avals` and the
@@ -1069,6 +1150,9 @@ class Executor:
         # run_steps: named reason the last call used the per-step
         # fallback (None = the K-step scan path ran)
         self.last_run_steps_fallback: Optional[str] = None
+        # what crossed between host and device at the dispatches'
+        # boundaries, and in how many transfers
+        self._transfers = _Transfers()
         # observability: the counters above are pulled at expose()
         # time (weakref provider; see _metrics_samples)
         self._obs_id = f"executor-{next(Executor._obs_seq)}"
@@ -1079,8 +1163,12 @@ class Executor:
     def _metrics_samples(self):
         """Pull-provider for observability.metrics.expose(): the
         compile/hit/disk-load/evict counters serving stats already
-        read, re-registered into the central registry."""
+        read, re-registered into the central registry, and the
+        transfers at the dispatches' boundaries (placed arrays over
+        placements is arrays a transfer, fetched arrays over
+        dispatches fetches a dispatch)."""
         lab = {"executor": self._obs_id}
+        tr = self._transfers
         return [
             ("paddle_tpu_executor_compiles_total", lab,
              self.compile_count),
@@ -1090,6 +1178,14 @@ class Executor:
              self.disk_load_count),
             ("paddle_tpu_executor_cache_evictions_total", lab,
              self.cache_evict_count),
+            ("paddle_tpu_executor_dispatches_total", lab,
+             tr.dispatches),
+            ("paddle_tpu_executor_placed_arrays_total", lab,
+             tr.placed_arrays),
+            ("paddle_tpu_executor_placements_total", lab,
+             tr.placements),
+            ("paddle_tpu_executor_fetched_arrays_total", lab,
+             tr.fetched_arrays),
         ]
 
     @property
@@ -1228,7 +1324,8 @@ class Executor:
         block = program.global_block
         _check_fetch_names(block, fetch_names, feed)
         placement = _Placement.of(program, self.place)
-        feed_arrays, feed_specs = _stage_feeds(feed, block, placement)
+        feed_arrays, feed_specs = _stage_feeds(feed, block, placement,
+                                               self._transfers)
         if self.prepare_unsupported_reason(program) is not None:
             # `go` ops are what stands in a prepared handle's way: the
             # memo per program version answers without a walk over
@@ -1238,7 +1335,8 @@ class Executor:
             step = self._bound_step(
                 program, scope, feed_arrays, feed_specs, fetch_names,
                 placement, use_program_cache=use_program_cache)
-        return step.dispatch(scope, feed_arrays, return_numpy)
+        return step.dispatch(scope, feed_arrays, return_numpy,
+                             self._transfers)
 
     def _bound_step(self, program, scope, feed_arrays, feed_specs,
                     fetch_names, placement, steps=None, stacked=False,
@@ -1382,25 +1480,27 @@ class Executor:
         placement = _Placement.of(program, self.place)
         if stacked:
             feed_arrays, feed_specs = self._stage_stacked_feeds(
-                block, feeds_seq, placement.device)
+                block, feeds_seq)
         else:
-            feed_arrays, feed_specs = _stage_feeds(feed, block,
-                                                   placement)
+            feed_arrays, feed_specs = _stage_feeds(
+                feed, block, placement, self._transfers)
         with _span("exe.lookup"):
             step = self._bound_step(
                 program, scope, feed_arrays, feed_specs, fetch_names,
                 placement, steps, stacked, use_program_cache)
-        return step.dispatch(scope, feed_arrays, return_numpy)
+        return step.dispatch(scope, feed_arrays, return_numpy,
+                             self._transfers)
 
     @staticmethod
-    def _stage_stacked_feeds(block, feeds_seq, device):
+    def _stage_stacked_feeds(block, feeds_seq):
         """(feed arrays, PER-STEP feed specs: what each scan body
-        sees) of a run_steps call with K batches: stacked on the host
-        and staged in one transfer. The second `exe.feed` site, beside
+        sees) of a run_steps call with K batches: stacked on the
+        host, one array a feed for all K, which the call takes up as
+        _stage_feeds' host feeds. The second `exe.feed` site, beside
         _stage_feeds."""
         feed_arrays = {}
         feed_specs = []
-        with _span("exe.feed"):
+        with _span("exe.feed") as sp:
             for name in sorted(feeds_seq[0]):
                 dt = _var_np_dtype(block, name)
                 cols = [_coerce_feed(f[name], dt) for f in feeds_seq]
@@ -1409,12 +1509,11 @@ class Executor:
                     arr = jnp.stack(cols)  # already device-resident
                 else:
                     arr = np.stack([np.asarray(c) for c in cols])
-                    if device is not None:
-                        # ONE staging transfer for all K batches
-                        arr = jax.device_put(arr, device)
                 feed_arrays[name] = arr
                 feed_specs.append(
                     (name, tuple(arr.shape[1:]), str(arr.dtype)))
+            if sp.recording:
+                sp.attrs.update(arrays=0, puts=0)
         return feed_arrays, feed_specs
 
     def _warn_scan_fallback(self, program, reason):
@@ -1447,11 +1546,13 @@ class Executor:
                 program, feed=f, fetch_list=fetch_list, scope=scope,
                 return_numpy=False, use_program_cache=use_program_cache))
         n_fetch = len(per_step[0]) if per_step else 0
+        if return_numpy:
+            self._transfers.start_fetch(per_step)
         out = []
         for i in range(n_fetch):
             vals = [r[i] for r in per_step]
             if return_numpy:
-                out.append(np.stack([np.asarray(v) for v in vals]))
+                out.append(np.stack(self._transfers.fetched(vals)))
             else:
                 out.append(jnp.stack(vals))
         return out
@@ -1806,8 +1907,8 @@ class Executor:
         # shapes of the write-only carry slots come from one abstract
         # eval of the single step (dtypes canonicalized the way jit
         # will see them)
-        mut_ex = _scope_state(scope, mutated, placement)
-        const_ex = _scope_state(scope, const, placement)
+        mut_ex, const_ex = _scope_state(scope, (mutated, const),
+                                        placement, self._transfers)
         rng_ex = scope._get(RNG_VAR)
         if rng_ex is None:
             rng_ex = jax.random.PRNGKey(0)
@@ -1815,7 +1916,9 @@ class Executor:
         if write_only:
             if stacked:
                 feeds_ex = {
-                    n: jax.ShapeDtypeStruct(tuple(a.shape[1:]), a.dtype)
+                    n: jax.ShapeDtypeStruct(
+                        tuple(a.shape[1:]),
+                        jax.dtypes.canonicalize_dtype(a.dtype))
                     for n, a in feed_arrays.items()}
             else:
                 feeds_ex = {
@@ -2011,8 +2114,8 @@ class PreparedProgram:
                 self._snapshot_tokens()
                 return
         placement = _Placement.of(program, exe.place)
-        feed_arrays, feed_specs = _stage_feeds(self._feed_example,
-                                               block, placement)
+        feed_arrays, feed_specs = _stage_feeds(
+            self._feed_example, block, placement, exe._transfers)
         # the lookup run()/run_steps() use, under the same in-memory
         # keys: prepared handles, plain runs and serving clones share
         # executables
@@ -2105,8 +2208,9 @@ class PreparedProgram:
                 f"missing={missing}")
         feed_arrays, _ = _stage_feeds(
             feed, self.program.global_block, step.placement,
-            self._check_spec, self._np_dtypes)
-        return step.dispatch(self.scope, feed_arrays, return_numpy)
+            exe._transfers, self._check_spec, self._np_dtypes)
+        return step.dispatch(self.scope, feed_arrays, return_numpy,
+                             exe._transfers)
 
 
 class PreparedCache:

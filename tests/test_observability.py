@@ -599,6 +599,10 @@ class TestSchemaStability:
         "paddle_tpu_executor_cache_hits_total",
         "paddle_tpu_executor_disk_loads_total",
         "paddle_tpu_executor_cache_evictions_total",
+        "paddle_tpu_executor_dispatches_total",
+        "paddle_tpu_executor_placed_arrays_total",
+        "paddle_tpu_executor_placements_total",
+        "paddle_tpu_executor_fetched_arrays_total",
         "paddle_tpu_executable_cache_size",
         "paddle_tpu_executable_cache_capacity",
         "paddle_tpu_executable_cache_inserts_total",

@@ -406,6 +406,44 @@ class TestChurnAndCompiles:
             + bp["cow_copies"] == 100
 
 
+class TestTransfersOfACycle:
+    def test_one_readback_and_two_placements_a_cycle(self, trained):
+        """A scheduler cycle is one executor dispatch: what it fetches
+        comes back together, its feeds go up with the call and the
+        tables `_pre_dispatch` wrote into the scope in one transfer
+        (core/executor.py `_Transfers`), and the tokens are still the
+        whole-loop decode's."""
+        srcs = _mixed_len_prompts(np.random.RandomState(37), 24)
+        want = _oracle(trained, srcs)
+        exe = trained["exe"]
+
+        def counts():
+            return {name: value
+                    for name, _labels, value in exe._metrics_samples()}
+
+        srv = _paged_server(trained)
+        try:
+            before = counts()           # bound and idle: no cycle yet
+            replies = [srv.submit(s) for s in srcs]
+            got = np.stack([r.result(timeout=120.0) for r in replies])
+        finally:
+            srv.close()
+        cycles = srv.stats()["ticks"]
+        grown = {k.replace("paddle_tpu_executor_", ""): v - before[k]
+                 for k, v in counts().items()}
+        np.testing.assert_array_equal(got, want)
+        assert cycles >= 24 // N_SLOTS
+        assert grown["dispatches_total"] == cycles
+        assert grown["fetched_arrays_total"] \
+            == cycles * len(srv._fetches)
+        assert len(srv._fetches) >= 4
+        assert cycles == grown["placements_total"] <= 2 * cycles
+        # the block table, the prompt references and the active mask
+        # every cycle, and what an admission writes besides
+        assert grown["placed_arrays_total"] >= 3 * cycles
+        assert grown["compiles_total"] == 0
+
+
 class TestExhaustion:
     def test_block_exhaustion_named_retryable_error_not_hang(
             self, trained):
