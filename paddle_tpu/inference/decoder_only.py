@@ -19,7 +19,15 @@ deliver; one prepared dispatch a cycle); what differs is the planning:
 * when a request ends, the whole blocks of its prompt (of the first
   `cache_tokens` tokens, where the caller said how much of the prompt
   others will send again) are adopted by the tree. A lane writes only
-  blocks it alone holds: a shared block is never written through.
+  blocks it alone holds: a shared block is never written through;
+* a bundle whose lanes carry state of their own beside the paged cache
+  (`bundle.lane_state`: a state-space layer's scan state and
+  convolution tail) takes no hit from the tree and leaves nothing to
+  it: the cached blocks of a prefix could be mapped, but the lane state
+  at the prefix's end exists nowhere, and blocks that nobody can reuse
+  only cost evictions. Every admission is counted
+  (`prefix_reuse_skipped`); its prefill starts at position 0, where the
+  programs start the lane's state from zero (`state_resets`).
 """
 from __future__ import annotations
 
@@ -74,7 +82,10 @@ class DecoderOnlyPagedServer(PagedContinuousGenerationServer):
         self._dec = dict.fromkeys(
             ("prompt_tokens", "cached_prompt_tokens", "prefill_tokens",
              "prefill_chunks", "lane_ticks", "context_sum",
-             "selected_keys_sum"), 0)
+             "selected_keys_sum", "state_resets", "prefix_reuse_skipped"),
+            0)
+        # per-lane state beside the paged cache: no prefix reuse
+        self._lane_state = bundle.lane_state
         kwargs.pop("radix_reuse", None)
         kwargs.pop("chunked_prefill", None)
         super().__init__(bundle, radix_reuse=True, chunked_prefill=False,
@@ -184,8 +195,9 @@ class DecoderOnlyPagedServer(PagedContinuousGenerationServer):
             cap = (n - 1) // bs
             pages = self._pages(req)
             # the cached chain first: it pins its blocks, so that
-            # making room below cannot take them
-            shared = self._radix.acquire(
+            # making room below cannot take them. None for a bundle with
+            # lane state: the state at a hit's boundary exists nowhere
+            shared = [] if self._lane_state else self._radix.acquire(
                 _ROOT, _chunks(req.prompt[:cap * bs], bs))
             short = pages - len(shared) - self._blocks.free_count
             if short > 0 and self._radix.evict(short) < short:
@@ -209,6 +221,9 @@ class DecoderOnlyPagedServer(PagedContinuousGenerationServer):
             self._filling[slot] = req.cached
             self._dec["prompt_tokens"] += n
             self._dec["cached_prompt_tokens"] += req.cached
+            if self._lane_state:
+                self._dec["prefix_reuse_skipped"] += 1
+                self._dec["state_resets"] += 1
             if shared:
                 self._radix_admits += 1
                 self._hit_depth.observe(float(len(shared)))
@@ -418,7 +433,8 @@ class DecoderOnlyPagedServer(PagedContinuousGenerationServer):
         the probes hold of its lane."""
         self._filling.pop(slot, None)
         if req.harvest and self._harvest_ok:
-            keep = min(req.cache_tokens, len(req.prompt)) // self._bs
+            keep = 0 if self._lane_state else \
+                min(req.cache_tokens, len(req.prompt)) // self._bs
             if keep:
                 self._radix.insert(
                     _ROOT, _chunks(req.prompt[:keep * self._bs],
@@ -437,19 +453,21 @@ class DecoderOnlyPagedServer(PagedContinuousGenerationServer):
         # the state is the scope's own
         names = [name for kind in ("selected", "chosen")
                  for name in probes[kind].values()]
-        if "logits" in probes:
-            names.append(probes["logits"])
+        per_tick = [kind for kind in ("logits", "top_logit")
+                    if kind in probes]
+        names += [probes[kind] for kind in per_tick]
         rows = dict(zip(names, jax.device_get(_take_rows(
             tuple(self.scope._get(name) for name in names),
             np.int32(slot)))))
         probe = req.reply.probe = req.probe = {
+            "lane": int(slot),
             "position": int(self._lane_base[slot]) + n - 1,
             "selected": {li: rows[name]
                          for li, name in probes["selected"].items()},
             "chosen": {li: rows[name][:n]
                        for li, name in probes["chosen"].items()}}
-        if "logits" in probes:
-            probe["logits"] = rows[probes["logits"]][:n]
+        for kind in per_tick:
+            probe[kind] = rows[probes[kind]][:n]
         if req.stream is not None:
             req.stream.probe = probe
 
@@ -459,6 +477,7 @@ class DecoderOnlyPagedServer(PagedContinuousGenerationServer):
         d = self._dec
         st.update(d)
         st["filling_lanes"] = len(self._filling)
+        st["state_lanes"], st["state_bytes"] = self._state_size()
         st["mean_context"] = d["context_sum"] / d["lane_ticks"] \
             if d["lane_ticks"] else None
         st["selected_keys_per_query"] = \
@@ -470,6 +489,30 @@ class DecoderOnlyPagedServer(PagedContinuousGenerationServer):
             k: np.asarray(v).tolist() for k, v in self._moe_read.items()
             if k.startswith("moe_load")}
         return st
+
+
+    def _metrics_samples(self):
+        """The base server's series and, under the same
+        `paddle_tpu_blockpool_*` prefix, the lanes' state: how many
+        lanes carry it, its bytes, the admissions that started it from
+        zero and those that took no prefix hit because of it."""
+        lab = {"server": self._obs_id}
+        lanes, size = self._state_size()
+        return super()._metrics_samples() + [
+            ("paddle_tpu_blockpool_state_lanes", lab, lanes),
+            ("paddle_tpu_blockpool_state_bytes", lab, size),
+            ("paddle_tpu_blockpool_state_resets_total", lab,
+             self._dec["state_resets"]),
+            ("paddle_tpu_blockpool_prefix_reuse_skipped_total", lab,
+             self._dec["prefix_reuse_skipped"])]
+
+    def _state_size(self):
+        """(lanes that carry state of their own, its bytes over all
+        rows); zeros for a bundle without."""
+        if not self._lane_state:
+            return 0, 0
+        return self.n_slots, \
+            (self.n_slots + 1) * self._lane_state["bytes_per_lane"]
 
 
 @jax.jit

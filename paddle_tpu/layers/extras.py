@@ -475,7 +475,7 @@ __all__.append("masked_pool_write")
 
 
 def paged_decode_attention(q, pool_k, pool_v, block_tab, pos, block_size,
-                           n_heads, scale=1.0, name=None):
+                           n_heads, scale=1.0, name=None, n_kv_heads=None):
     """Context rows ``[R, q, H*Dh]`` of the decode tick's queries ``q``
     over each lane's own cache positions, read from the SHARED
     ``[NB*BS, H*Dh]`` pools through the lane's row of ``block_tab``
@@ -486,19 +486,41 @@ def paged_decode_attention(q, pool_k, pool_v, block_tab, pos, block_size,
     host table with a bound, because the kernel neither clamps nor
     fills. Reference counterpart: none (the reference's decode caches
     are dense per-request tensors,
-    tests/unittests/dist_transformer.py:1498)."""
+    tests/unittests/dist_transformer.py:1498). `n_kv_heads` fewer than
+    `n_heads`: grouped queries over pools ``[NB*BS, Hkv*Dh]``."""
     helper = LayerHelper("paged_decode_attention", input=q, name=name)
     out = helper.create_variable_for_type_inference(q.dtype, True)
+    attrs = {"block_size": int(block_size), "n_heads": int(n_heads),
+             "scale": float(scale)}
+    if n_kv_heads and n_kv_heads != n_heads:
+        attrs["n_kv_heads"] = int(n_kv_heads)
     helper.append_op(
         "paged_decode_attention",
         {"Q": q, "PoolK": pool_k, "PoolV": pool_v, "Table": block_tab,
-         "Pos": pos}, {"Out": out},
-        {"block_size": int(block_size), "n_heads": int(n_heads),
-         "scale": float(scale)})
+         "Pos": pos}, {"Out": out}, attrs)
     return out
 
 
-__all__.append("paged_decode_attention")
+def paged_prefill_attention(q, pool_k, pool_v, block_tab, pos, block_size,
+                            n_heads, n_kv_heads=None, scale=1.0,
+                            name=None):
+    """Context rows ``[N, H*Dh]`` of a prefill chunk's queries ``q``
+    over the lane's paged prefix and the chunk itself under the causal
+    mask (ops/paged_ops.py): row i sees the positions <= pos[i] of its
+    group's row of ``block_tab`` ``[G, NP]``. The pools hold the chunk's
+    own keys and values already."""
+    helper = LayerHelper("paged_prefill_attention", input=q, name=name)
+    out = helper.create_variable_for_type_inference(q.dtype, True)
+    helper.append_op(
+        "paged_prefill_attention",
+        {"Q": q, "PoolK": pool_k, "PoolV": pool_v, "Table": block_tab,
+         "Pos": pos}, {"Out": out},
+        {"block_size": int(block_size), "n_heads": int(n_heads),
+         "n_kv_heads": int(n_kv_heads or n_heads), "scale": float(scale)})
+    return out
+
+
+__all__.extend(["paged_decode_attention", "paged_prefill_attention"])
 
 
 def filtered_softmax(logits, temperature=1.0, top_k=0, top_p=1.0,

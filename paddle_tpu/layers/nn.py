@@ -1718,7 +1718,7 @@ def short_conv(x, taps=3, param_attr=None, name=None):
 
 def moe_dropless(input, num_experts, d_inner, top_k, experts_held=None,
                  norm_topk=True, scaling=1.0, name=None,
-                 scope="moe"):
+                 scope="moe", activation="swiglu", expert_input=None):
     """Routed expert layer that drops NO token (parallel/moe.py
     `moe_dropless`; `switch_moe` above is the capacity routing, which
     drops what does not fit). A sigmoid router over all `num_experts`
@@ -1729,6 +1729,11 @@ def moe_dropless(input, num_experts, d_inner, top_k, experts_held=None,
     blocks W2(silu(W1 x) * W3 x) of width `d_inner`; parameters
     `<name>_gate.w` [D, E], `<name>_w13` [held, D, 2*d_inner] (W1 and
     W3 side by side), `<name>_w2` [held, d_inner, D].
+    `activation` "relu2": experts that are not gated, W2 relu(W1 x)^2,
+    with `<name>_w13` [held, D, d_inner] the one up matrix.
+    `expert_input`: what the experts read where it is not what the
+    router reads (a latent projection of `input`, [N, D_e]); the
+    experts' matrices and the output are then D_e wide.
     Returns (out, chosen [N, top_k] int32, load [held] int32: pairs
     each held expert received, pairs_here [1] int32); the last three
     cost nothing unless fetched."""
@@ -1748,11 +1753,15 @@ def moe_dropless(input, num_experts, d_inner, top_k, experts_held=None,
         ParamAttr(name=f"{prefix}_bias", trainable=False),
         [num_experts], input.dtype,
         default_initializer=ConstantInitializer(0.0))
+    if activation not in ("swiglu", "relu2"):
+        raise ValueError(f"activation {activation!r}: swiglu or relu2")
+    d_e = d if expert_input is None else expert_input.shape[-1]
+    up = d_inner * (2 if activation == "swiglu" else 1)
     w13 = helper.create_parameter(
-        ParamAttr(name=f"{prefix}_w13"), [held, d, 2 * d_inner],
-        input.dtype, default_initializer=NormalInitializer(0.0, d ** -0.5))
+        ParamAttr(name=f"{prefix}_w13"), [held, d_e, up],
+        input.dtype, default_initializer=NormalInitializer(0.0, d_e ** -0.5))
     w2 = helper.create_parameter(
-        ParamAttr(name=f"{prefix}_w2"), [held, d_inner, d], input.dtype,
+        ParamAttr(name=f"{prefix}_w2"), [held, d_inner, d_e], input.dtype,
         default_initializer=NormalInitializer(0.0, d_inner ** -0.5))
     out = helper.create_variable_for_type_inference(input.dtype)
     extras = {}
@@ -1762,14 +1771,17 @@ def moe_dropless(input, num_experts, d_inner, top_k, experts_held=None,
                                      dtype="int32", persistable=False)
         var.stop_gradient = True
         extras[slot] = var
-    helper.append_op(
-        "moe_dropless",
-        {"X": input, "GateW": wg, "ExpertBias": bias, "W13": w13,
-         "W2": w2},
-        {"Out": out, **extras},
-        {"first_held": int(first), "top_k": int(top_k),
-         "norm_topk": bool(norm_topk), "scaling": float(scaling),
-         "scope": scope})
+    inputs = {"X": input, "GateW": wg, "ExpertBias": bias, "W13": w13,
+              "W2": w2}
+    attrs = {"first_held": int(first), "top_k": int(top_k),
+             "norm_topk": bool(norm_topk), "scaling": float(scaling),
+             "scope": scope}
+    if expert_input is not None:
+        inputs["ExpertX"] = expert_input
+    if activation != "swiglu":
+        attrs["activation"] = activation
+    helper.append_op("moe_dropless", inputs, {"Out": out, **extras},
+                     attrs)
     return out, extras["Chosen"], extras["Load"], extras["PairsHere"]
 
 
@@ -1893,3 +1905,89 @@ def moe_tick_stats(chosen, active, first_held, n_held, name=None):
 
 __all__.extend(["mla_project", "mla_output", "dsa_indexer_project",
                 "lm_head", "moe_tick_stats"])
+
+
+# ---------------------------------------------------------------------------
+# state-space (Mamba-2) token mixing with per-lane state (ops/ssm_ops.py)
+# and the activation of experts that are not gated
+# ---------------------------------------------------------------------------
+def relu2(x, name=None):
+    """relu(x)^2, the activation of a feed-forward that is not gated
+    (`mlp_hidden_act` "relu2"); float32 inside, x's dtype out."""
+    helper = LayerHelper("relu2", input=x, name=name)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op("relu2", {"X": x}, {"Out": out}, {})
+    return out
+
+
+def _chunk_or_tick(chunk, gate, pos):
+    """The rows' place: a chunk of one lane ({"lane", "len", "pos"},
+    each [1]; `gate` and `pos` are then not read) or one row a lane
+    (gate [R], pos [R])."""
+    if chunk is not None:
+        return {"Lane": chunk["lane"], "Len": chunk["len"],
+                "Pos": chunk["pos"]}
+    return {"Gate": gate, "Pos": pos}
+
+
+def causal_conv_tail(x, tail, kernel, name, chunk=None, gate=None,
+                     pos=None):
+    """silu(causal depthwise convolution of x [N, W] with `<name>.w`
+    [W, kernel] + `<name>.b`), the `kernel - 1` inputs before row 0
+    read from and left in `tail` [R, kernel-1, W] (per-lane state, in
+    place). `chunk`: the rows are one lane's consecutive positions;
+    else row r is lane r's next input (ops/ssm_ops.py)."""
+    helper = LayerHelper("causal_conv_tail", input=x, name=name)
+    width = x.shape[-1]
+    w = _matrix(helper, f"{name}.w", (width, kernel), x.dtype)
+    b = _ones(helper, f"{name}.b", width, x.dtype, 0.0)
+    out = helper.create_variable_for_type_inference(x.dtype, True)
+    helper.append_op(
+        "causal_conv_tail",
+        {"X": x, "Tail": tail, "Filter": w, "Bias": b,
+         **_chunk_or_tick(chunk, gate, pos)},
+        {"Out": out, "TailOut": tail}, {})
+    return out
+
+
+def mamba2_scan(xbc, dt, state, n_groups, name, block=128, chunk=None,
+                gate=None, pos=None):
+    """y [N, H*P] float32 of the Mamba-2 recurrence on rows xbc [N, H*P
+    + 2*G*N] (after the convolution) and dt [N, H], from and into the
+    per-lane `state` [R, H, P, N] float32 (in place). `chunk`: one
+    lane's consecutive positions, by the chunked form in blocks of
+    `block` (op mamba2_chunk_scan); else one step of every lane (op
+    mamba2_step). Parameters `<name>_dt_bias`, `<name>_A_log`,
+    `<name>_D` [H] float32."""
+    helper = LayerHelper("mamba2_scan", input=xbc, name=name)
+    heads = state.shape[1]
+    dt_bias, a_log, d_skip = (
+        _ones(helper, f"{name}_{leaf}", heads, "float32", value)
+        for leaf, value in (("dt_bias", 0.0), ("A_log", 0.0), ("D", 1.0)))
+    y = helper.create_variable_for_type_inference("float32", True)
+    attrs = {"n_groups": int(n_groups)}
+    if chunk is not None:
+        attrs["block"] = int(block)
+    helper.append_op(
+        "mamba2_step" if chunk is None else "mamba2_chunk_scan",
+        {"XBC": xbc, "Dt": dt, "DtBias": dt_bias, "ALog": a_log,
+         "D": d_skip, "State": state, **_chunk_or_tick(chunk, gate, pos)},
+        {"Y": y, "StateOut": state}, attrs)
+    return y
+
+
+def gated_group_rms_norm(x, z, groups, epsilon=1e-5, param_attr=None,
+                         name=None):
+    """group_rms_norm(x * silu(z)) * w in `groups` groups of the last
+    axis; x [N, D] float32, z [N, D]; z's dtype out."""
+    helper = LayerHelper("gated_group_rms_norm", input=z, name=name)
+    scale = _ones(helper, param_attr, x.shape[-1], z.dtype)
+    out = helper.create_variable_for_type_inference(z.dtype, True)
+    helper.append_op("gated_group_rms_norm",
+                     {"X": x, "Z": z, "Scale": scale}, {"Out": out},
+                     {"groups": int(groups), "epsilon": float(epsilon)})
+    return out
+
+
+__all__.extend(["relu2", "causal_conv_tail", "mamba2_scan",
+                "gated_group_rms_norm"])

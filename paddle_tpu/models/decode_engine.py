@@ -52,6 +52,7 @@ import numpy as np
 
 from .. import layers
 from ..analysis import absint
+from ..core.program import device_scope
 from ..observability import devtel
 from ..observability.devtel import DECODE_STEPS_VAR  # noqa: F401
 from ..param_attr import ParamAttr
@@ -1267,7 +1268,8 @@ class DecoderOnlyStepBundle:
 
     def __init__(self, serves, startup, state, state_specs, n_slots,
                  max_out_len, context, end_id, cache, chunk_sizes,
-                 max_chunks, probes=None, selection_size=0):
+                 max_chunks, probes=None, selection_size=0,
+                 lane_state=()):
         self.serves = dict(serves)
         self.startup = startup
         self.state = dict(state)
@@ -1286,6 +1288,26 @@ class DecoderOnlyStepBundle:
         self.probes = probes or {}
         # positions a query attends at most (0: all it has cached)
         self.selection_size = selection_size
+        # state of the second kind: fixed-size, indexed by lane and not
+        # by block table (a state-space layer's scan state and
+        # convolution tail). None where the bundle has none; a server
+        # then may map cached prefix blocks into a lane, which it may
+        # not where the state at the prefix's end exists nowhere
+        self.lane_state = self._lane_state_of(lane_state)
+
+    def _lane_state_of(self, names):
+        """{"names", "shapes" (a lane's), "bytes_per_lane"} of the
+        state vars `names` ([rows, ...] each), or None."""
+        import jax.numpy as jnp
+
+        if not names:
+            return None
+        shapes = {n: tuple(self._state_specs[n][0][1:]) for n in names}
+        per_lane = sum(
+            int(np.prod(shapes[n]))
+            * jnp.dtype(self._state_specs[n][1]).itemsize for n in names)
+        return {"names": tuple(names), "shapes": shapes,
+                "bytes_per_lane": per_lane}
 
     def programs(self):
         return [p for _k, p in sorted(self.serves.items(),
@@ -1322,12 +1344,15 @@ class DecoderOnlyStepBundle:
 
     def init_slot_state(self, scope):
         """Seed the pool state in `scope`: idle lanes finished and not
-        active, everything else zero. The pools are made on the device
-        (the latent pool of a deployment is gigabytes)."""
+        active, everything else zero. The pools and the lanes' state
+        are made on the device (the latent pool of a deployment is
+        gigabytes, and so is the scan state of its lanes)."""
         import jax.numpy as jnp
 
+        on_device = set(self.lane_state["names"]) if self.lane_state \
+            else ()
         for name, (shape, dt) in self._state_specs.items():
-            if name.endswith(POOL_MARK):
+            if name.endswith(POOL_MARK) or name in on_device:
                 scope._set(name, jnp.zeros(shape, dt))
             elif name == self.state["finished"]:
                 scope._set(name, np.ones(shape, dt))
@@ -2040,6 +2065,239 @@ def build_serve_program(specs, state_prefix, pre_body, step_body,
                     not_ran,
                     layers.elementwise_sub(one, idle)))
     return prog
+
+
+def build_decoder_only_bundle(stack, layer_specs, *, state_prefix, vocab,
+                              d_model, dtype, norm_eps, top_names,
+                              moe_layers, first_held, experts_held, top_k,
+                              n_slots, block_size, n_blocks, context,
+                              max_new_tokens, chunk_sizes, max_chunks,
+                              end_id, probe_logits, chunk_scope,
+                              selected_probes=None, selection_size=0,
+                              lane_state=(), probe_top_logit=False):
+    """The serve programs of a decoder-only stack on the slot pool, as
+    a DecoderOnlyStepBundle: what every such builder shares
+    (models/glm_moe_dsa.py, models/nemotron_h.py). The builder brings
+    the layers; this makes the slot state, the tick (a lane's current
+    token embedded, the layers, the final norm, the head, argmax, the
+    probes, the experts' counters, the emit tail), the prefill program
+    (a While of fed chunks a chunk size, largest first, then the
+    admission of the lanes whose prompt is cached) and the tick-only
+    program, both ending in the While of ticks (build_serve_program).
+
+    `stack(sv, x, pos, cell, gate, tab, chunk)`: the layers on rows x
+    [N, D] at cache positions pos [N], whose pool rows are cell [N]
+    (written where gate [N] is 1) under the block-table rows tab [G,
+    NP]. `chunk` is None in a tick (row r is lane r; gate is the lanes'
+    active flag) and {"lane", "len", "pos"} (each [1]: the lane, the
+    real rows, the position of row 0) in a prefill chunk, whose rows
+    are one lane's. Returns (x, {layer: selection [N, K]} for the
+    layers in `selected_probes`, {expert layer: chosen [N, top_k]});
+    a chunk's probes are dropped.
+    `layer_specs`: a dict of state specs a layer (its pools, its lane
+    state, what it probes), full names. `top_names`: the parameters
+    (embedding, final norm, head). `moe_layers`: the layers that route,
+    whose counters ride the state. `lane_state`: names of the state
+    that is indexed by lane and made on the device (per-lane recurrent
+    state; the bundle's `lane_state` says what a lane of it costs).
+    `probe_top_logit` keeps the logit of every token a lane emitted
+    (rows x tokens floats; the probe "top_logit"): what a comparison
+    with a reference can hold to a number, where tokens only agree or
+    do not."""
+    import paddle_tpu as fluid
+
+    chunk_sizes = tuple(sorted(set(int(c) for c in chunk_sizes)))
+    if context % block_size:
+        raise ValueError(f"block_size={block_size} must divide "
+                         f"context={context}")
+    cache = CacheConfig(layout="paged", block_size=block_size,
+                        n_blocks=n_blocks, n_prompt_entries=1)
+    rows, maxT = n_slots + 1, max_new_tokens + 1
+    p = state_prefix
+    emb_name, norm_name, head_name = top_names
+    selected_probes = dict(selected_probes or {})
+    specs = {
+        f"{p}tok_buf": ((rows, maxT), "int64"),
+        f"{p}step": ((rows,), "int64"),
+        f"{p}finished": ((rows,), "int64"),
+        f"{p}active": ((rows,), "int64"),
+        # cache position of position 0 of a lane's token row, and how
+        # many tokens the lane's request asked for
+        f"{p}base": ((rows,), "int64"),
+        f"{p}limit": ((rows,), "int64"),
+        f"{p}block_tab": ((rows, context // block_size), "int32"),
+        # what the live lanes of the ticks sent to the experts held
+        # here: pairs, held experts with a pair, pairs an expert
+        f"{p}moe_pairs": ((1,), "int64"),
+        f"{p}moe_hit": ((1,), "int64"),
+    }
+    if probe_logits:
+        specs[f"{p}logits_hist"] = ((rows, maxT, vocab), "float32")
+    if probe_top_logit:
+        specs[f"{p}top_logit_hist"] = ((rows, maxT, 1), "float32")
+    specs.update(devtel.counter_specs(p, True, chunked=True))
+    for li, layer in enumerate(layer_specs):
+        specs.update(layer)
+        if li in moe_layers:
+            specs[f"{p}moe_load{li}"] = ((experts_held,), "int64")
+            specs[f"{p}chosen_hist{li}"] = ((rows, maxT, top_k), "int32")
+
+    def mark(sv):
+        absint.mark_pool_index_source(sv[f"{p}block_tab"], "block_table",
+                                      bound=n_blocks)
+        absint.mark_pool_index_source(sv[f"{p}active"], "lane_active")
+        return sv
+
+    def embed(toks):
+        return layers.embedding(toks, size=[vocab, d_model], dtype=dtype,
+                                param_attr=ParamAttr(name=emb_name))
+
+    def add_to(var, delta):
+        layers.assign(layers.elementwise_add(var, delta), output=var)
+
+    def tick_body(sv):
+        tok_buf, stepv = sv[f"{p}tok_buf"], sv[f"{p}step"]
+        fin, act = sv[f"{p}finished"], sv[f"{p}active"]
+        tel_add(sv, p, "tel_ticks",
+                layers.fill_constant([1], "int64", 1.0))
+        tel_add(sv, p, "tel_occupancy",
+                layers.reduce_sum(act, keep_dim=True))
+        positions = layers.cast(layers.range(0, maxT, 1), "int64")
+        t_mask = layers.cast(
+            layers.equal(positions, layers.reshape(stepv, [rows, 1])),
+            "int64")
+        cur_tok = layers.reduce_sum(
+            layers.elementwise_mul(tok_buf, t_mask), dim=1,
+            keep_dim=True)                                  # [R,1]
+        pos = layers.elementwise_add(sv[f"{p}base"], stepv)
+        tab = sv[f"{p}block_tab"]
+        cell = layers.paged_cell_index(tab, pos, block_size)
+        # idle, dustbin and prefilling lanes (act = 0) write nothing
+        gate = layers.cast(act, "float32")
+        x, selected, chosen = stack(sv, embed(cur_tok), pos, cell, gate,
+                                    tab, None)
+        logits = layers.lm_head(
+            layers.rms_norm(x, norm_eps, param_attr=norm_name),
+            vocab, head_name)
+        tok = layers.cast(layers.argmax(logits, axis=-1), "int64")
+        if probe_logits:
+            layers.lane_probe_write(sv[f"{p}logits_hist"], logits, act,
+                                    step=stepv)
+        if probe_top_logit:
+            layers.lane_probe_write(
+                sv[f"{p}top_logit_hist"],
+                layers.reduce_max(logits, dim=-1, keep_dim=True), act,
+                step=stepv)
+        for li, sel in selected.items():
+            layers.lane_probe_write(sv[selected_probes[li]], sel, act)
+        for li, idx in chosen.items():
+            layers.lane_probe_write(sv[f"{p}chosen_hist{li}"], idx, act,
+                                    step=stepv)
+            pairs, hit, load = layers.moe_tick_stats(
+                idx, act, first_held, experts_held)
+            add_to(sv[f"{p}moe_pairs"], pairs)
+            add_to(sv[f"{p}moe_hit"], hit)
+            add_to(sv[f"{p}moe_load{li}"], load)
+        emit_lane_tokens(tok, tok_buf, stepv, fin, act, rows, maxT,
+                         end_id, room_limit=sv[f"{p}limit"])
+
+    def chunk_loop(sv, C, chunk_toks, chunk_lane, chunk_pos, chunk_len,
+                   n_chunks):
+        """The fed chunks of at most C tokens, one after another."""
+        j = layers.fill_constant([1], "int64", 0)
+        offs = layers.cast(layers.range(0, C, 1), "int64")
+        cond = layers.less_than(j, n_chunks)
+        loop = layers.While(cond)
+        with loop.block(), device_scope(chunk_scope):
+            toks = layers.reshape(layers.gather(chunk_toks, j), [C, 1])
+            n = layers.gather(chunk_len, j)
+            start = layers.gather(chunk_pos, j)
+            pos = layers.elementwise_add(offs, start)
+            # rows past the chunk's length are padding: they write
+            # nothing, and what they compute is dropped
+            gate = layers.cast(layers.less_than(offs, n), "float32")
+            lane = layers.gather(chunk_lane, j)
+            tab = layers.gather(sv[f"{p}block_tab"], lane)  # [1,NP]
+            cell = layers.paged_cell_index(tab, pos, block_size)
+            stack(sv, embed(toks), pos, cell, gate, tab,
+                  {"lane": lane, "len": n, "pos": start})
+            tel_add(sv, p, "tel_chunks",
+                    layers.fill_constant([1], "int64", 1.0))
+            layers.increment(j, 1)
+            layers.less_than(j, n_chunks, cond=cond)
+
+    def prefill_body(sv):
+        A = max_chunks
+
+        def fed(name, shape):
+            return layers.data(name, shape=shape, dtype="int64",
+                               append_batch_size=False)
+
+        # the largest chunks first: a lane's prompt is cut into whole
+        # chunks of the largest size and one smaller rest, which has
+        # to find them cached
+        for C in sorted(chunk_sizes, reverse=True):
+            chunk_loop(sv, C, fed(f"chunk_toks_{C}", [A, C]),
+                       fed(f"chunk_lane_{C}", [A]),
+                       fed(f"chunk_pos_{C}", [A]),
+                       fed(f"chunk_len_{C}", [A]),
+                       fed(f"n_chunks_{C}", [1]))
+        slots, a_tok = fed("admit_slots", [A]), fed("admit_tok", [A])
+        a_base, a_limit = fed("admit_base", [A]), fed("admit_limit", [A])
+        # admission: the lanes whose prompt is cached now but for its
+        # last token, which is position 0 of their token row
+        oh, _, any_i, _, keep_i = lane_onehots(slots, A, rows)
+        oh_i = layers.cast(oh, "int64")
+
+        def scattered(v):       # [A] -> [rows]; the dustbin's is junk
+            return layers.reduce_sum(layers.elementwise_mul(
+                oh_i, layers.reshape(v, [A, 1])), dim=0)
+
+        start_col = layers.assign(
+            (np.arange(maxT) == 0).astype("int64"))
+        keep_col = layers.reshape(keep_i, [rows, 1])
+        tok_buf = sv[f"{p}tok_buf"]
+        layers.assign(layers.elementwise_add(
+            layers.elementwise_mul(tok_buf, keep_col),
+            layers.elementwise_mul(
+                layers.reshape(scattered(a_tok), [rows, 1]), start_col)),
+            output=tok_buf)
+        for name, new in (("step", None), ("finished", None),
+                          ("base", a_base), ("limit", a_limit)):
+            var = sv[f"{p}{name}"]
+            kept = layers.elementwise_mul(var, keep_i)
+            layers.assign(kept if new is None else
+                          layers.elementwise_add(kept, scattered(new)),
+                          output=var)
+        valid = layers.assign(
+            (np.arange(rows) < n_slots).astype("int64"))
+        admitted = layers.elementwise_mul(any_i, valid)
+        act = sv[f"{p}active"]
+        layers.assign(layers.elementwise_add(
+            layers.elementwise_mul(act, keep_i), admitted), output=act)
+        tel_add(sv, p, "tel_admit_miss",
+                layers.reduce_sum(admitted, keep_dim=True))
+
+    serves = {0: build_serve_program(specs, p, lambda sv: None, tick_body,
+                                     mark=mark)}
+    serves[DecoderOnlyStepBundle.PREFILL] = build_serve_program(
+        specs, p, prefill_body, tick_body, mark=mark)
+    state = {k: f"{p}{k}" for k in
+             ("tok_buf", "step", "finished", "active", "base", "limit",
+              "block_tab", "moe_pairs", "moe_hit")}
+    state.update(devtel.state_entries(p, True, chunked=True))
+    state.update({f"moe_load{li}": f"{p}moe_load{li}"
+                  for li in moe_layers})
+    probes = {"selected": selected_probes,
+              "chosen": {li: f"{p}chosen_hist{li}" for li in moe_layers}}
+    if probe_logits:
+        probes["logits"] = f"{p}logits_hist"
+    if probe_top_logit:
+        probes["top_logit"] = f"{p}top_logit_hist"
+    return DecoderOnlyStepBundle(
+        serves, fluid.Program(), state, specs, n_slots, maxT, context,
+        end_id, cache, chunk_sizes, max_chunks, probes=probes,
+        selection_size=selection_size, lane_state=lane_state)
 
 
 def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
@@ -4131,7 +4389,8 @@ class RadixBlockTree:
 
 __all__ = ["CacheConfig", "SamplingConfig", "DraftConfig",
            "ShardingConfig", "DecodeStepBundle",
-           "DecoderOnlyStepBundle", "DECODE_STEPS_VAR",
+           "DecoderOnlyStepBundle", "build_decoder_only_bundle",
+           "DECODE_STEPS_VAR",
            "POOL_MARK", "LANE_AXIS",
            "tp_param_placements", "annotate_sharded_program",
            "place_sharded_bundle", "place_sharded_program",

@@ -4,9 +4,10 @@ serve engine: a decoder-only stack with latent attention (MLA) over a
 paged latent cache, a learned sparse-attention indexer whose selection
 the layers that follow share (DSA with IndexShare), and routed experts
 beside a shared expert. No reference counterpart: Fluid 1.x has no such
-model; the bundle has the serve-program shape of
-`decode_engine.build_decode_step_program` and shares its While, its
-emit tail and its slot state with it.
+model; the layers are here, and the serve programs around them (slot
+state, tick, prefill chunks, admission, the While of ticks) are
+`decode_engine.build_decoder_only_bundle`'s, which every decoder-only
+builder shares.
 
 Every layer is `h = x + Attn(RMSNorm(x)); y = h + FFN(RMSNorm(h))`, no
 biases, one more RMSNorm before an untied head.
@@ -38,16 +39,11 @@ runs is under `glm.prefill_chunk`.
 """
 from __future__ import annotations
 
-import numpy as np
-
 from .. import layers
-from ..analysis import absint
 from ..core.program import device_scope
-from ..observability import devtel
 from ..param_attr import ParamAttr
-from .decode_engine import (POOL_MARK, CacheConfig, DecoderOnlyStepBundle,
-                            build_serve_program, emit_lane_tokens,
-                            lane_onehots, tel_add)
+from .decode_engine import (POOL_MARK, DecoderOnlyStepBundle,
+                            build_decoder_only_bundle)
 
 DEFAULT_CHUNKS = (64, 256, 1024)
 PREFILL = DecoderOnlyStepBundle.PREFILL
@@ -149,44 +145,21 @@ def glm_stack(x, pos, cell, gate, tab, pools, m, chunk=False):
     return x, selected, chosen
 
 
-def _state_specs(prefix, rows, maxT, m, cache, context, probe_logits):
+def _layer_specs(prefix, rows, cells, m, context):
+    """What each layer keeps in the slot state: its latent pool, its
+    indexer-key pool where it owns an indexer, and what the lane's last
+    tick attended in it (its own selection, or the one it shares)."""
     dt = m["dtype"]
-    cells = cache.n_blocks * cache.block_size
-    specs = {
-        f"{prefix}tok_buf": ((rows, maxT), "int64"),
-        f"{prefix}step": ((rows,), "int64"),
-        f"{prefix}finished": ((rows,), "int64"),
-        f"{prefix}active": ((rows,), "int64"),
-        # cache position of position 0 of a lane's token row, and how
-        # many tokens the lane's request asked for
-        f"{prefix}base": ((rows,), "int64"),
-        f"{prefix}limit": ((rows,), "int64"),
-        f"{prefix}block_tab": ((rows, context // cache.block_size),
-                               "int32"),
-        # what the live lanes of the ticks sent to the experts held
-        # here: pairs, held experts with a pair, pairs an expert
-        f"{prefix}moe_pairs": ((1,), "int64"),
-        f"{prefix}moe_hit": ((1,), "int64"),
-    }
-    if probe_logits:
-        specs[f"{prefix}logits_hist"] = ((rows, maxT, m["vocab"]),
-                                         "float32")
-    specs.update(devtel.counter_specs(prefix, True, chunked=True))
+    out = []
     for li, kind in enumerate(m["indexer_types"]):
-        specs[f"{prefix}lat{li}{POOL_MARK}"] = ((cells, row_width(m)), dt)
+        layer = {f"{prefix}lat{li}{POOL_MARK}": ((cells, row_width(m)), dt)}
         if kind == "full":
-            specs[f"{prefix}idx{li}{POOL_MARK}"] = (
+            layer[f"{prefix}idx{li}{POOL_MARK}"] = (
                 (cells, m["index_head_dim"]), dt)
-        # what the lane's last tick attended in this layer (its own
-        # selection, or the one it shares)
-        specs[f"{prefix}sel_last{li}"] = (
+        layer[f"{prefix}sel_last{li}"] = (
             (rows, min(m["index_topk"], context)), "int32")
-        if li >= m["n_dense_layers"]:
-            specs[f"{prefix}moe_load{li}"] = ((m["experts_held"],),
-                                              "int64")
-            specs[f"{prefix}chosen_hist{li}"] = (
-                (rows, maxT, m["top_k"]), "int32")
-    return specs
+        out.append(layer)
+    return out
 
 
 def build_glm_serve_bundle(vocab, d_model, n_heads, q_lora_rank,
@@ -212,19 +185,13 @@ def build_glm_serve_bundle(vocab, d_model, n_heads, q_lora_rank,
     a dispatch;
     `probe_logits` keeps every tick's logits of every lane in the
     state (a test's probe: rows x tokens x vocabulary floats)."""
-    import paddle_tpu as fluid
-
     indexer_types = list(indexer_types)
-    chunk_sizes = tuple(sorted(set(int(c) for c in chunk_sizes)))
     n_layers = len(indexer_types) if n_layers is None else n_layers
     if len(indexer_types) != n_layers or indexer_types[0] != "full":
         raise ValueError(
             f"indexer_types {indexer_types} for {n_layers} layers; the "
             f"first layer has to own an indexer")
     context = context or block_size * 8
-    if context % block_size:
-        raise ValueError(f"block_size={block_size} must divide "
-                         f"context={context}")
     m = dict(vocab=vocab, d_model=d_model, n_heads=n_heads,
              q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
              qk_nope_head_dim=qk_nope_head_dim,
@@ -239,167 +206,29 @@ def build_glm_serve_bundle(vocab, d_model, n_heads, q_lora_rank,
              first_held=first_held, norm_topk=norm_topk,
              routed_scaling=routed_scaling, rope_theta=rope_theta,
              norm_eps=norm_eps, dtype=dtype, block_size=block_size)
-    cache = CacheConfig(layout="paged", block_size=block_size,
-                        n_blocks=n_blocks, n_prompt_entries=1)
-    rows, maxT = n_slots + 1, max_new_tokens + 1
     p = state_prefix
-    specs = _state_specs(p, rows, maxT, m, cache, context, probe_logits)
-    moe_layers = [li for li in range(n_layers) if li >= n_dense_layers]
 
-    def mark(sv):
-        absint.mark_pool_index_source(sv[f"{p}block_tab"], "block_table",
-                                      bound=n_blocks)
-        absint.mark_pool_index_source(sv[f"{p}active"], "lane_active")
-        return sv
+    def stack(sv, x, pos, cell, gate, tab, chunk):
+        pools = {li: (sv[f"{p}lat{li}{POOL_MARK}"],
+                      sv.get(f"{p}idx{li}{POOL_MARK}"))
+                 for li in range(n_layers)}
+        return glm_stack(x, pos, cell, gate, tab, pools, m,
+                         chunk=chunk is not None)
 
-    def pools(sv):
-        return {li: (sv[f"{p}lat{li}{POOL_MARK}"],
-                     sv.get(f"{p}idx{li}{POOL_MARK}"))
-                for li in range(n_layers)}
-
-    def embed(toks):
-        return layers.embedding(toks, size=[vocab, d_model], dtype=dtype,
-                                param_attr=ParamAttr(name="glm_emb"))
-
-    def add_to(var, delta):
-        layers.assign(layers.elementwise_add(var, delta), output=var)
-
-    def tick_body(sv):
-        tok_buf, stepv = sv[f"{p}tok_buf"], sv[f"{p}step"]
-        fin, act = sv[f"{p}finished"], sv[f"{p}active"]
-        tel_add(sv, p, "tel_ticks",
-                layers.fill_constant([1], "int64", 1.0))
-        tel_add(sv, p, "tel_occupancy",
-                layers.reduce_sum(act, keep_dim=True))
-        positions = layers.cast(layers.range(0, maxT, 1), "int64")
-        t_mask = layers.cast(
-            layers.equal(positions, layers.reshape(stepv, [rows, 1])),
-            "int64")
-        cur_tok = layers.reduce_sum(
-            layers.elementwise_mul(tok_buf, t_mask), dim=1,
-            keep_dim=True)                                  # [R,1]
-        pos = layers.elementwise_add(sv[f"{p}base"], stepv)
-        tab = sv[f"{p}block_tab"]
-        cell = layers.paged_cell_index(tab, pos, block_size)
-        # idle, dustbin and prefilling lanes (act = 0) write nothing
-        gate = layers.cast(act, "float32")
-        x, selected, chosen = glm_stack(embed(cur_tok), pos, cell, gate,
-                                        tab, pools(sv), m)
-        logits = layers.lm_head(
-            layers.rms_norm(x, norm_eps, param_attr="glm_out_norm.w"),
-            vocab, "glm_head.w")
-        tok = layers.cast(layers.argmax(logits, axis=-1), "int64")
-        if probe_logits:
-            layers.lane_probe_write(sv[f"{p}logits_hist"], logits, act,
-                                    step=stepv)
-        for li, sel in selected.items():
-            layers.lane_probe_write(sv[f"{p}sel_last{li}"], sel, act)
-        for li, idx in chosen.items():
-            layers.lane_probe_write(sv[f"{p}chosen_hist{li}"], idx, act,
-                                    step=stepv)
-            pairs, hit, load = layers.moe_tick_stats(
-                idx, act, first_held, m["experts_held"])
-            add_to(sv[f"{p}moe_pairs"], pairs)
-            add_to(sv[f"{p}moe_hit"], hit)
-            add_to(sv[f"{p}moe_load{li}"], load)
-        emit_lane_tokens(tok, tok_buf, stepv, fin, act, rows, maxT,
-                         end_id, room_limit=sv[f"{p}limit"])
-
-    def chunk_loop(sv, C, chunk_toks, chunk_lane, chunk_pos, chunk_len,
-                   n_chunks):
-        """The fed chunks of at most C tokens, one after another."""
-        j = layers.fill_constant([1], "int64", 0)
-        offs = layers.cast(layers.range(0, C, 1), "int64")
-        cond = layers.less_than(j, n_chunks)
-        loop = layers.While(cond)
-        with loop.block(), device_scope("glm.prefill_chunk"):
-            toks = layers.reshape(layers.gather(chunk_toks, j), [C, 1])
-            n = layers.gather(chunk_len, j)
-            pos = layers.elementwise_add(
-                offs, layers.gather(chunk_pos, j))
-            # rows past the chunk's length are padding: they write
-            # nothing, and what they compute is dropped
-            gate = layers.cast(layers.less_than(offs, n), "float32")
-            tab = layers.gather(sv[f"{p}block_tab"],
-                                layers.gather(chunk_lane, j))  # [1,NP]
-            cell = layers.paged_cell_index(tab, pos, block_size)
-            glm_stack(embed(toks), pos, cell, gate, tab, pools(sv), m,
-                      chunk=True)
-            tel_add(sv, p, "tel_chunks",
-                    layers.fill_constant([1], "int64", 1.0))
-            layers.increment(j, 1)
-            layers.less_than(j, n_chunks, cond=cond)
-
-    def prefill_body(sv):
-        A = max_chunks
-
-        def fed(name, shape):
-            return layers.data(name, shape=shape, dtype="int64",
-                               append_batch_size=False)
-
-        # the largest chunks first: a lane's prompt is cut into whole
-        # chunks of the largest size and one smaller rest, which has
-        # to find them cached
-        for C in sorted(chunk_sizes, reverse=True):
-            chunk_loop(sv, C, fed(f"chunk_toks_{C}", [A, C]),
-                       fed(f"chunk_lane_{C}", [A]),
-                       fed(f"chunk_pos_{C}", [A]),
-                       fed(f"chunk_len_{C}", [A]),
-                       fed(f"n_chunks_{C}", [1]))
-        slots, a_tok = fed("admit_slots", [A]), fed("admit_tok", [A])
-        a_base, a_limit = fed("admit_base", [A]), fed("admit_limit", [A])
-        # admission: the lanes whose prompt is cached now but for its
-        # last token, which is position 0 of their token row
-        oh, _, any_i, _, keep_i = lane_onehots(slots, A, rows)
-        oh_i = layers.cast(oh, "int64")
-
-        def scattered(v):       # [A] -> [rows]; the dustbin's is junk
-            return layers.reduce_sum(layers.elementwise_mul(
-                oh_i, layers.reshape(v, [A, 1])), dim=0)
-
-        start_col = layers.assign(
-            (np.arange(maxT) == 0).astype("int64"))
-        keep_col = layers.reshape(keep_i, [rows, 1])
-        tok_buf = sv[f"{p}tok_buf"]
-        layers.assign(layers.elementwise_add(
-            layers.elementwise_mul(tok_buf, keep_col),
-            layers.elementwise_mul(
-                layers.reshape(scattered(a_tok), [rows, 1]), start_col)),
-            output=tok_buf)
-        for name, new in (("step", None), ("finished", None),
-                          ("base", a_base), ("limit", a_limit)):
-            var = sv[f"{p}{name}"]
-            kept = layers.elementwise_mul(var, keep_i)
-            layers.assign(kept if new is None else
-                          layers.elementwise_add(kept, scattered(new)),
-                          output=var)
-        valid = layers.assign(
-            (np.arange(rows) < n_slots).astype("int64"))
-        admitted = layers.elementwise_mul(any_i, valid)
-        act = sv[f"{p}active"]
-        layers.assign(layers.elementwise_add(
-            layers.elementwise_mul(act, keep_i), admitted), output=act)
-        tel_add(sv, p, "tel_admit_miss",
-                layers.reduce_sum(admitted, keep_dim=True))
-
-    serves = {0: build_serve_program(specs, p, lambda sv: None, tick_body,
-                                     mark=mark)}
-    serves[PREFILL] = build_serve_program(specs, p, prefill_body,
-                                          tick_body, mark=mark)
-    state = {k: f"{p}{k}" for k in
-             ("tok_buf", "step", "finished", "active", "base", "limit",
-              "block_tab", "moe_pairs", "moe_hit")}
-    state.update(devtel.state_entries(p, True, chunked=True))
-    state.update({f"moe_load{li}": f"{p}moe_load{li}"
-                  for li in moe_layers})
-    probes = {"selected": {li: f"{p}sel_last{li}"
-                           for li in range(n_layers)},
-              "chosen": {li: f"{p}chosen_hist{li}" for li in moe_layers}}
-    if probe_logits:
-        probes["logits"] = f"{p}logits_hist"
-    bundle = DecoderOnlyStepBundle(
-        serves, fluid.Program(), state, specs, n_slots, maxT, context,
-        end_id, cache, chunk_sizes, max_chunks, probes=probes,
+    bundle = build_decoder_only_bundle(
+        stack, _layer_specs(p, n_slots + 1, n_blocks * block_size, m,
+                            context),
+        state_prefix=p, vocab=vocab, d_model=d_model, dtype=dtype,
+        norm_eps=norm_eps,
+        top_names=("glm_emb", "glm_out_norm.w", "glm_head.w"),
+        moe_layers=[li for li in range(n_layers) if li >= n_dense_layers],
+        first_held=first_held, experts_held=m["experts_held"],
+        top_k=top_k, n_slots=n_slots, block_size=block_size,
+        n_blocks=n_blocks, context=context,
+        max_new_tokens=max_new_tokens, chunk_sizes=chunk_sizes,
+        max_chunks=max_chunks, end_id=end_id, probe_logits=probe_logits,
+        chunk_scope="glm.prefill_chunk",
+        selected_probes={li: f"{p}sel_last{li}" for li in range(n_layers)},
         selection_size=min(index_topk, context))
     bundle.model = m
     return bundle

@@ -68,6 +68,15 @@ def swiglu(ctx):
     return {"Out": (jax.nn.silu(a) * b).astype(x.dtype)}
 
 
+@register_op("relu2")
+def relu2(ctx):
+    """relu(x)^2: the activation of a feed-forward that is not gated
+    (one up matrix; Nemotron-H's `mlp_hidden_act`)."""
+    x = ctx.input("X")
+    return {"Out": jnp.square(jax.nn.relu(x.astype(jnp.float32)))
+            .astype(x.dtype)}
+
+
 @register_op("short_conv")
 def short_conv(ctx):
     """The inside of a gated short convolution (Liquid's LFM2 mixer):
@@ -96,13 +105,23 @@ def moe_dropless(ctx):
     ExpertBias [E] (enters the choice only); W13 [n_held, D, 2F]; W2
     [n_held, F, D]. On amp's KEEP list: the router sees X as it comes
     (float32 from an RMS norm) and the experts run in bfloat16 under
-    AMP. Chosen, Load and PairsHere are free when unfetched."""
+    AMP. Chosen, Load and PairsHere are free when unfetched. attr
+    activation "relu2": W13 [n_held, D, F], no gate. ExpertX [..., De]
+    (optional): the experts' input where it is not the router's; W13,
+    W2 and Out are then De wide."""
     from .. import amp
     from ..parallel import moe as moe_mod
 
     x = ctx.input("X")
     shape = x.shape
     xt = x.reshape(-1, shape[-1])
+    more = {}
+    if ctx.input("ExpertX") is not None:
+        ex = ctx.input("ExpertX")
+        shape = ex.shape
+        more["expert_x"] = ex.reshape(-1, shape[-1])
+    if ctx.attr("activation", "swiglu") != "swiglu":
+        more["activation"] = ctx.attr("activation")
     out, idx, load, pairs = moe_mod.moe_dropless(
         xt, ctx.input("GateW"), ctx.input("ExpertBias"),
         ctx.input("W13"), ctx.input("W2"),
@@ -111,7 +130,7 @@ def moe_dropless(ctx):
         norm_topk=bool(ctx.attr("norm_topk", True)),
         scaling=float(ctx.attr("scaling", 1.0)),
         compute_dtype=jnp.bfloat16 if amp.enabled() else None,
-        scope=ctx.attr("scope", "moe"))
+        scope=ctx.attr("scope", "moe"), **more)
     return {"Out": out.reshape(shape), "Chosen": idx, "Load": load,
             "PairsHere": pairs}
 

@@ -100,7 +100,9 @@ def paged_decode_attention(ctx):
     cache position of a lane's first query: query j attends positions
     <= Pos + j, so stale cells past a lane's position are masked as
     the dense step's -1e9 bias masks them). attrs: block_size,
-    n_heads, scale. Out [R, q, H*Dh], the context rows. Idle and
+    n_heads, scale, n_kv_heads (default n_heads; fewer: grouped
+    queries, the pools [NB*BS, Hkv*Dh], query head h reads key-value
+    head h // (H / Hkv); the jnp route). Out [R, q, H*Dh], the context rows. Idle and
     dustbin lanes read whatever blocks their table rows name (block 0
     when cleared) and their rows are ignored downstream.
 
@@ -123,6 +125,11 @@ def paged_decode_attention(ctx):
               n_heads=int(ctx.attr("n_heads")),
               scale=float(ctx.attr("scale", 1.0)))
     pos = pos.reshape(q.shape[0])
+    n_kv = int(ctx.attr("n_kv_heads", 0)) or kw["n_heads"]
+    if n_kv != kw["n_heads"]:
+        note_route("paged_decode_attention", q.shape, False)
+        return grouped_paged_attention(q, pool_k, pool_v, tab, pos,
+                                       n_kv_heads=n_kv, **kw)
     if note_route("paged_decode_attention", q.shape,
                   PA.usable(q, pool_k, tab, kw["block_size"])):
         return PA.paged_decode_attention(q, pool_k, pool_v, tab, pos,
@@ -182,6 +189,86 @@ def _group_cells(tab, pos, block_size):
     page = jnp.clip(pos // block_size, 0, tab.shape[1] - 1)
     return jnp.take_along_axis(tab.astype(jnp.int32), page, axis=1) \
         * block_size + pos % block_size
+
+
+def grouped_paged_attention(q, pool_k, pool_v, tab, pos, *, block_size,
+                            n_heads, n_kv_heads, scale, live=None):
+    """Grouped-query attention over paged keys and values: q [G, n,
+    H*Dh]; pools [NB*BS, Hkv*Dh]; tab [G, NP]; pos [G] (query j of
+    group g sees the group's positions <= pos[g] + j) or [G, n] (each
+    query's own position). `live`: how many positions the longest row
+    reaches, where the caller knows it (many queries of one lane read
+    only the pages that hold them). Scores, softmax and accumulation
+    float32, operands as stored. Out [G, n, H*Dh] in q's dtype."""
+    g, n, hd = q.shape
+    dh = hd // n_heads
+    rep = n_heads // n_kv_heads
+    n_pages = tab.shape[1]
+    pos = pos.astype(jnp.int32)
+    if pos.ndim == 1:
+        pos = pos[:, None] + jnp.arange(n, dtype=jnp.int32)[None]
+    tab = tab.astype(jnp.int32)
+    # the pools stay [rows, Hkv*Dh] with the heads side by side on the
+    # lanes, and a key-value head is a slice of whole lane tiles: a
+    # [.., Hkv, Dh] view of a pool is another tiling, which the compiler
+    # makes by copying the pool whole, every tick (PERF.md, PR 34)
+    kb = pool_k.reshape(-1, block_size, pool_k.shape[-1])
+    vb = pool_v.reshape(-1, block_size, pool_v.shape[-1])
+    args = (q.reshape(g, n, n_kv_heads, rep, dh), pos)
+
+    def run(pages):
+        t = pages * block_size
+        k = kb.at[tab[:, :pages]].get(mode="promise_in_bounds").reshape(
+            g, t, -1)
+        v = vb.at[tab[:, :pages]].get(mode="promise_in_bounds").reshape(
+            g, t, -1)
+        at = jnp.arange(t, dtype=jnp.int32)
+
+        def block(args):
+            qb, pb = args       # [G, b, Hkv, rep, Dh], [G, b]
+            seen = (at[None, None] <= pb[..., None])[:, :, None]
+            heads = []
+            for kv in range(n_kv_heads):
+                mine = slice(kv * dh, (kv + 1) * dh)
+                s = jnp.einsum("gbrd,gtd->gbrt", qb[:, :, kv],
+                               k[..., mine],
+                               preferred_element_type=jnp.float32) * scale
+                s = jnp.where(seen, s, -jnp.inf)
+                m = jnp.maximum(s.max(-1, keepdims=True), -1e30)
+                p = jnp.exp(s - m)
+                p = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
+                heads.append(jnp.einsum(
+                    "gbrt,gtd->gbrd", p.astype(v.dtype), v[..., mine],
+                    preferred_element_type=jnp.float32))
+            out = jnp.stack(heads, 2)           # [G, b, Hkv, rep, Dh]
+            return out.reshape(g, qb.shape[1], hd).astype(q.dtype)
+
+        return _by_query_blocks(block, args)
+
+    return _over_live_pages(run, n_pages, block_size, live)
+
+
+@register_op("paged_prefill_attention", differentiable=False,
+             stop_gradient_slots=("Q", "PoolK", "PoolV", "Table", "Pos"))
+def paged_prefill_attention(ctx):
+    """Causal attention of a prefill chunk's queries over the lane's
+    paged prefix and the chunk itself. Q [N, H*Dh], N = G * n rows in
+    G groups (a chunk: one lane, G = 1); PoolK, PoolV [NB*BS, Hkv*Dh]
+    (after the chunk's own write); Table [G, NP]; Pos [N], each row's
+    cache position (it sees the positions <= its own). Only the pages
+    that hold the rows' positions are read (`_over_live_pages`). attrs
+    block_size, n_heads, n_kv_heads, scale. Out [N, H*Dh]."""
+    q, tab = ctx.input("Q"), ctx.input("Table")
+    g = tab.shape[0]
+    pos = ctx.input("Pos").reshape(g, -1).astype(jnp.int32)
+    n_heads = int(ctx.attr("n_heads"))
+    out = grouped_paged_attention(
+        q.reshape(g, -1, q.shape[-1]), ctx.input("PoolK"),
+        ctx.input("PoolV"), tab, pos,
+        block_size=int(ctx.attr("block_size")), n_heads=n_heads,
+        n_kv_heads=int(ctx.attr("n_kv_heads", 0)) or n_heads,
+        scale=float(ctx.attr("scale", 1.0)), live=pos.max() + 1)
+    return {"Out": out.reshape(q.shape)}
 
 
 @register_op("paged_cell_index", differentiable=False,

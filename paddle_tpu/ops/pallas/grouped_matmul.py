@@ -24,6 +24,21 @@ from .attention import _interp
 # rows, contraction, columns of one tile. 512 rows a tile keeps the
 # tiles a group's ragged edge wastes small against 4,096 rows a layer.
 TILING = (512, 1024, 1024)
+# rows of a tile where no group can have that many: a tile is computed
+# once for every group that has a row in it, so among groups of a
+# handful of rows each a tile of 512 is mostly other groups' rows
+FEW_ROWS = 128
+
+
+def row_tile(m, groups) -> int:
+    """Rows of a tile for m rows over `groups` held groups, from what
+    is known when the product is traced: TILING's, FEW_ROWS where the
+    rows would not fill a tile of FEW_ROWS a group even if every row
+    were a held group's, and all m rows where they fit one tile."""
+    tm = TILING[0]
+    if m <= tm:
+        return m
+    return FEW_ROWS if m < FEW_ROWS * groups else tm
 
 
 def usable(lhs, rhs) -> bool:
@@ -31,14 +46,14 @@ def usable(lhs, rhs) -> bool:
         return False
     m, k = lhs.shape
     n = rhs.shape[-1]
-    tm, tk, tn = _tiling(m, k, n)
+    tm, tk, tn = _tiling(m, k, n, rhs.shape[0])
     return (lhs.dtype == rhs.dtype and m % tm == 0
             and k % 128 == 0 and n % 128 == 0)
 
 
-def _tiling(m, k, n):
-    tm, tk, tn = TILING
-    return min(tm, m), min(tk, k), min(tn, n)
+def _tiling(m, k, n, groups):
+    _, tk, tn = TILING
+    return row_tile(m, groups), min(tk, k), min(tn, n)
 
 
 def grouped_matmul_reference(lhs, rhs, group_sizes):
@@ -64,6 +79,6 @@ def grouped_matmul(lhs, rhs, group_sizes):
 
         m, k = lhs.shape
         return ops.gmm(lhs, rhs, group_sizes.astype(jnp.int32), lhs.dtype,
-                       _tiling(m, k, rhs.shape[-1]),
+                       _tiling(m, k, rhs.shape[-1], rhs.shape[0]),
                        jnp.asarray(0, jnp.int32), None, False, _interp())
     return grouped_matmul_reference(lhs, rhs, group_sizes)

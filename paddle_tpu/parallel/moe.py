@@ -338,7 +338,8 @@ _untake_pairs.defvjp(_untake_pairs_fwd, _untake_pairs_bwd)
 
 def moe_dropless(x, wg, bias, w13, w2, first_held: int, top_k: int,
                  norm_topk: bool = True, scaling: float = 1.0,
-                 compute_dtype=None, scope: str = "moe"):
+                 compute_dtype=None, scope: str = "moe",
+                 activation: str = "swiglu", expert_x=None):
     """One rank's share of a dropless expert layer.
 
     x: [t, d]; wg: [d, E]; bias: [E]; w13: [n_held, d, 2f] (each held
@@ -352,15 +353,27 @@ def moe_dropless(x, wg, bias, w13, w2, first_held: int, top_k: int,
     (default: x's). Device scopes: `<scope>.route`, `.experts`,
     `.combine`.
 
+    `activation` "relu2": experts that are not gated, W2 relu(W1 x)^2,
+    w13 [n_held, d, f] the one up matrix. `expert_x` [t, d_e]: what the
+    experts read where the router reads something else (a latent
+    projection of x); w13, w2 and out are then d_e wide.
+
     Nothing is dropped whatever the routing: all t*k pairs are sorted,
     the held experts' first, on row buffers that hold every pair; the
     rows after the held experts' are never computed."""
-    from ..ops.pallas.grouped_matmul import grouped_matmul
+    from ..ops.pallas.grouped_matmul import grouped_matmul, row_tile
 
-    t, d = x.shape
+    t = x.shape[0]
     n_held = w13.shape[0]
     f = w2.shape[1]
     cd = compute_dtype or x.dtype
+    gated = activation == "swiglu"
+    ex = x if expert_x is None else expert_x
+    d = ex.shape[1]
+    # rows over a tile that do not fill whole tiles (LFM2's and GLM's
+    # do: nothing is added to their programs) are padded to whole
+    # tiles, the padding in the group that is not computed
+    pad = -(t * top_k) % row_tile(t * top_k, n_held)
     with jax.named_scope(f"{scope}.route"):
         idx, weight = route_dropless(x, wg, bias, top_k, norm_topk,
                                      scaling)
@@ -377,13 +390,21 @@ def moe_dropless(x, wg, bias, w13, w2, first_held: int, top_k: int,
         load = sizes[:n_held]
         here = held.reshape(t, top_k)
         w_here = jnp.where(here, weight, 0.0)
-    xc, w13c, w2c = x.astype(cd), w13.astype(cd), w2.astype(cd)
+    xc, w13c, w2c = ex.astype(cd), w13.astype(cd), w2.astype(cd)
     with jax.named_scope(f"{scope}.experts"):
         xs = _take_pairs(xc, order, inverse, top_k)
+        if pad:
+            xs = jnp.pad(xs, ((0, pad), (0, 0)))
+            sizes = sizes.at[n_held].add(pad)
         h = grouped_matmul(xs, w13c, sizes)              # [t*k, 2f]
-        a = (jax.nn.silu(h[:, :f].astype(jnp.float32))
-             * h[:, f:].astype(jnp.float32)).astype(cd)
+        if gated:
+            a = (jax.nn.silu(h[:, :f].astype(jnp.float32))
+                 * h[:, f:].astype(jnp.float32)).astype(cd)
+        else:
+            a = jnp.square(jax.nn.relu(h.astype(jnp.float32))).astype(cd)
         y = grouped_matmul(a, w2c, sizes)                # [t*k, d]
+        if pad:
+            y = y[:t * top_k]
     with jax.named_scope(f"{scope}.combine"):
         yp = _untake_pairs(y, inverse, order).reshape(t, top_k, d)
         # a select, not a product with 0: what the rows of experts held

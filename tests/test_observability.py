@@ -673,6 +673,43 @@ class TestSchemaStability:
 
 
 # --------------------------------------------------------------------
+# a decoder-only server whose lanes carry state of their own (PR 34)
+# --------------------------------------------------------------------
+BLOCKPOOL_STATE_FAMILIES = {
+    "paddle_tpu_blockpool_state_lanes",
+    "paddle_tpu_blockpool_state_bytes",
+    "paddle_tpu_blockpool_state_resets_total",
+    "paddle_tpu_blockpool_prefix_reuse_skipped_total",
+}
+BLOCKPOOL_STATE_STATS = {"state_lanes", "state_bytes", "state_resets",
+                         "prefix_reuse_skipped"}
+
+
+def test_lane_state_golden_families_and_pool_stats_keys():
+    """The pull provider of a decoder-only server with per-lane state
+    exposes the state's series beside the block pool's, and
+    pool_stats() holds their counters."""
+    from benchmark.chip import controls
+    from benchmark.chip.drivers import nemotron_serve
+
+    _set_level("metrics")
+    c = controls._sizes("nemotron-3-super-serve-ep4", True)
+    srv, _exe, _scope = nemotron_serve.build_server(c, 5)
+    try:
+        text = obs.metrics.expose()
+        stats = srv.pool_stats()
+    finally:
+        srv.close()
+    families = {TestSchemaStability._family(ln) for ln in text.splitlines()
+                if ln and not ln.startswith("#")}
+    missing = BLOCKPOOL_STATE_FAMILIES - families
+    assert not missing, f"expose() lost families: {sorted(missing)}"
+    assert "paddle_tpu_blockpool_blocks_in_use" in families
+    assert BLOCKPOOL_STATE_STATS <= set(stats)
+    assert stats["state_lanes"] == c["n_slots"]
+
+
+# --------------------------------------------------------------------
 # the program's spans in a JAX profile (PR 25)
 # --------------------------------------------------------------------
 EXE_SPANS = {"exe.feed", "exe.lookup", "exe.compile", "exe.state",
