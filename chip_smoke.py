@@ -391,6 +391,7 @@ def server_phase(c, meter, on_chip):
     from paddle_tpu.core.scope import Scope
     from paddle_tpu.inference import PagedContinuousGenerationServer
     from paddle_tpu.ops import pallas
+    from paddle_tpu.ops.paged_ops import ROUTE_LABELS
 
     scope = Scope()
     exe = fluid.Executor(fluid.TPUPlace(0))
@@ -441,13 +442,14 @@ def server_phase(c, meter, on_chip):
                              "plain_radix_admissions", "preemptions")},
         aot_failures=exe.aot_failures)
     # on the chip and in the rehearsal (interpret mode) the paged
-    # self-attention read is the kernel's, in every program that ran
-    paged_read = ("paged_decode_attention",
-                  (c["n_slots"] + 1, 1, c["d_model"]))
-    check({r for r in routes if r[:2] == paged_read}
-          == {paged_read + (True,)},
-          f"the paged read did not take its kernel: "
-          f"{sorted(set(routes))}")
+    # self-attention read and the cross-attention read of the prompt
+    # table are the kernel's, in every program that ran
+    for label in ROUTE_LABELS.values():
+        paged_read = (label, (c["n_slots"] + 1, 1, c["d_model"]))
+        check({r for r in routes if r[:2] == paged_read}
+              == {paged_read + (True,)},
+              f"{label} did not take its kernel: "
+              f"{sorted(set(routes))}")
 
     rows0, streamed0 = waves[0][0], waves[0][1]
     # every comparison is printed before any of them can end the run
@@ -682,17 +684,37 @@ def sweep_cases(tiny):
         rs.randint(0, pages * bs, (rws - 1,)), 0).astype(np.int32))
     kw = dict(block_size=bs, n_heads=hh, scale=dh ** -0.5)
 
-    def paged_fwd():
-        got = jax.jit(lambda *a: paged_attention.paged_decode_attention(
-            *a, **kw))(q, pk, pv, tab, step)
-        want = paged_attention.paged_attention_reference(
-            q, pk, pv, tab, step, **kw)
-        return [("out", got, want)]
+    def paged_fwd(args, kw):
+        def fwd():
+            got = jax.jit(
+                lambda *a: paged_attention.paged_decode_attention(
+                    *a, **kw))(*args)
+            return [("out", got,
+                     paged_attention.paged_attention_reference(
+                         *args, **kw))]
+        return [("fwd", fwd)]  # inference-only kernel
     cases.append(("paged_attention",
                   f"q({rws},1,{hh * dh}) pool({nb * bs},{hh * dh}) "
                   f"table({rws},{pages}) f32",
                   paged_attention.usable(q, pk, tab, bs), True,
-                  [("fwd", paged_fwd)], 1e-3))  # inference-only kernel
+                  paged_fwd((q, pk, pv, tab, step), kw), 1e-3))
+
+    # the same cell's cross-attention read: every lane on one of the
+    # 129 prompt entries (the dustbin last), an entry one block of 256
+    # rows, every lane at the last position
+    ents, seq = (5, 16) if tiny else (129, 256)
+    tk, tv = (rnd(i, (ents, seq, hh * dh)).reshape(-1, hh * dh)
+              for i in (20, 21))
+    ref = jnp.asarray(np.append(
+        rs.randint(0, ents - 1, (rws - 1,)), ents - 1
+    ).astype(np.int32))[:, None]
+    last = jnp.full((rws,), seq - 1, jnp.int32)
+    cases.append(("paged_attention_prompt_table",
+                  f"q({rws},1,{hh * dh}) table({ents},{seq},{hh * dh}) "
+                  f"ref({rws},1) f32",
+                  paged_attention.usable(q, tk, ref, seq), True,
+                  paged_fwd((q, tk, tv, ref, last),
+                            dict(kw, block_size=seq)), 1e-3))
     return cases
 
 

@@ -78,6 +78,7 @@ from ..observability import metrics as obs_metrics
 from ..observability import tracing as obs_tracing
 from ..observability.metrics import Histogram
 from ..observability.tracing import cache_tier as _cache_tier
+from ..ops.paged_ops import ROUTE_LABELS
 
 SEQ_SUFFIX = "@SEQ_LEN"
 
@@ -2429,15 +2430,21 @@ class ContinuousGenerationServer:
                 "cancelled": self._n_cancelled,
                 "deadline_expired": self._n_deadline,
                 # which route each bound serve program's paged
-                # self-attention took when it was traced ("kernel",
+                # self-attention, and its read of the prompt table for
+                # cross-attention, took when it was traced ("kernel",
                 # "reference"; a program not traced yet, or a dense
                 # one, lists none): trace-time record, no tick reads it
-                "self_attention_routes": {
+                **{stat: {
                     str(key): sorted({
                         "kernel" if routed else "reference"
                         for kernel, _, routed in h.kernel_routes()
-                        if kernel == "paged_decode_attention"})
-                    for key, h in self._serves.items()},
+                        if kernel == label})
+                    for key, h in self._serves.items()}
+                   for stat, label in (
+                       ("self_attention_routes",
+                        ROUTE_LABELS["cells"]),
+                       ("cross_attention_routes",
+                        ROUTE_LABELS["prompt_table"]))},
             }
             spec = self._speculative_stats_locked()
             if spec is not None:
@@ -3861,7 +3868,7 @@ class DisaggregatedPrefillWorker:
         rows)`` / ``on_fail(req, prompt, entry, exc)`` fire on the
         WORKER thread (never under this worker's lock) — ``rows``
         maps each cross-pool state name to the entry's finished
-        [H, S, Dh] row, copied off this scope."""
+        [S, H*Dh] rows, copied off this scope."""
         with self._cv:
             if not self._running or self._closed:
                 raise ServerClosed(

@@ -475,7 +475,8 @@ __all__.append("masked_pool_write")
 
 
 def paged_decode_attention(q, pool_k, pool_v, block_tab, pos, block_size,
-                           n_heads, scale=1.0, name=None, n_kv_heads=None):
+                           n_heads, scale=1.0, name=None, n_kv_heads=None,
+                           reads="cells"):
     """Context rows ``[R, q, H*Dh]`` of the decode tick's queries ``q``
     over each lane's own cache positions, read from the SHARED
     ``[NB*BS, H*Dh]`` pools through the lane's row of ``block_tab``
@@ -487,13 +488,18 @@ def paged_decode_attention(q, pool_k, pool_v, block_tab, pos, block_size,
     fills. Reference counterpart: none (the reference's decode caches
     are dense per-request tensors,
     tests/unittests/dist_transformer.py:1498). `n_kv_heads` fewer than
-    `n_heads`: grouped queries over pools ``[NB*BS, Hkv*Dh]``."""
+    `n_heads`: grouped queries over pools ``[NB*BS, Hkv*Dh]``.
+    `reads="prompt_table"` names the cross-attention read (the prompt
+    table's rows behind ``prompt_ref`` as a table of one block a
+    lane) in the routing record; it chooses nothing."""
     helper = LayerHelper("paged_decode_attention", input=q, name=name)
     out = helper.create_variable_for_type_inference(q.dtype, True)
     attrs = {"block_size": int(block_size), "n_heads": int(n_heads),
              "scale": float(scale)}
     if n_kv_heads and n_kv_heads != n_heads:
         attrs["n_kv_heads"] = int(n_kv_heads)
+    if reads != "cells":
+        attrs["reads"] = reads
     helper.append_op(
         "paged_decode_attention",
         {"Q": q, "PoolK": pool_k, "PoolV": pool_v, "Table": block_tab,

@@ -18,10 +18,13 @@ public signatures and delegate here:
   ``[rows, H, maxT, Dh]`` KV buffers, the r10 design) or ``paged``
   (a SHARED block pool ``[n_blocks * block_size, H * Dh]`` per layer,
   one row a cache cell, + per-lane int32 block-table rows;
-  cross-attention K/V lives in a refcounted prompt-entry pool so
-  identical prompts prefill ONCE). Pools are stored in the shape they
-  are addressed in: reads gather rows (cells, prompt entries) along
-  axis 0, so no tick copies or relayouts a pool or the table; writes
+  cross-attention K/V lives in a refcounted prompt-entry pool
+  ``[n_prompt_entries + 1, seq_len, H * Dh]`` so identical prompts
+  prefill ONCE). Pools are stored in the shape they are addressed in,
+  ``H * Dh`` on the minor axis: a tick's attention reads a lane's
+  cells, and its prompt entry as one block of ``seq_len`` rows, where
+  they lie (``paged_decode_attention``), so no tick copies or
+  relayouts a pool or the table; writes
   go through the ``masked_pool_write`` registry op whose disjoint
   one-hot masks are the lane-exclusivity contract checker PTA110
   enforces (shared-pool aliasing is the silent cross-request KV
@@ -523,6 +526,66 @@ class _DenseSpanCache(_DenseViewAttention):
         return self.kc, self.vc
 
 
+class _DenseCross:
+    """Per-layer cross-attention over per-lane ``[R, H, S, Dh]`` vars
+    (the dense layouts, the whole-loop front's projections and the
+    speculative draft's ``draft_cross_*`` state): the shared
+    matmul / softmax / matmul over the whole prompt."""
+
+    def __init__(self, ck, cv):
+        self.ck, self.cv = ck, cv
+
+    def attend(self, q2, n_heads, scale):
+        """q2 [R,q,H*Dh] query rows -> context rows [R,q,H*Dh]."""
+        q, d_model = q2.shape[1], q2.shape[2]
+        q2h = heads_of(q2, q, n_heads, d_model // n_heads)
+        s2 = layers.scale(
+            layers.matmul(q2h, self.ck, transpose_y=True),
+            scale=scale)  # [R,H,q,S]
+        p2 = layers.softmax(s2, axis=-1)
+        return layers.reshape(
+            layers.transpose(layers.matmul(p2, self.cv),
+                             perm=[0, 2, 1, 3]),
+            [0, q, d_model])
+
+
+class _PagedPromptCross:
+    """Per-layer cross-attention of the paged layout: a lane's prompt
+    entry is read where the ``[E+1, S, H*Dh]`` table stores it, through
+    the read the self pools take. The table is ``(E+1) * S`` rows of
+    ``H*Dh`` in blocks of ``S``, ``prompt_ref`` a block table of one
+    block a lane, and every lane stands at position ``S - 1`` (a
+    prompt is exactly ``S`` positions, so nothing is masked, for any
+    number of queries). No ``[R, H, S, Dh]`` copy of the lanes'
+    entries exists; idle lanes read the dustbin entry ``E``."""
+
+    def __init__(self, pool_k, pool_v, table, last_pos):
+        self.pool_k, self.pool_v = pool_k, pool_v
+        self.table = table                # [rows, 1]: prompt_ref
+        self.last_pos = last_pos          # [rows], all S - 1
+
+    def attend(self, q2, n_heads, scale):
+        """q2 [R,q,H*Dh] query rows -> context rows [R,q,H*Dh]."""
+        seq_len, width = self.pool_k.shape[1], self.pool_k.shape[2]
+        return layers.paged_decode_attention(
+            q2, layers.reshape(self.pool_k, [-1, width]),
+            layers.reshape(self.pool_v, [-1, width]), self.table,
+            self.last_pos, seq_len, n_heads, scale=scale,
+            reads="prompt_table")
+
+
+def _paged_prompt_cross(sv, state_prefix, n_layers, rows, seq_len):
+    """The paged tick bodies' per-layer cross-access objects over the
+    bundle's prompt table."""
+    table = layers.reshape(sv[f"{state_prefix}prompt_ref"], [rows, 1])
+    last_pos = layers.fill_constant([rows], "int32",
+                                    float(seq_len - 1))
+    return [_PagedPromptCross(
+        sv[f"{state_prefix}cross_k{li}{POOL_MARK}"],
+        sv[f"{state_prefix}cross_v{li}{POOL_MARK}"], table, last_pos)
+        for li in range(n_layers)]
+
+
 @dataclass(frozen=True)
 class SamplingConfig:
     """Emission-lane sampling policy (temperature/top-k/top-p) for a
@@ -665,7 +728,7 @@ class DraftConfig:
                 int(self.sharded))
 
 
-def cached_decoder_step(x, caches, cross_kv, att_bias, d_model,
+def cached_decoder_step(x, caches, cross, att_bias, d_model,
                         n_heads, d_inner, prefix="", q=1,
                         qkv_interleaved=False):
     """One KV-cached decoder-stack step over a [R,q,D] row batch
@@ -681,8 +744,10 @@ def cached_decoder_step(x, caches, cross_kv, att_bias, d_model,
     write and ``attend``, the context rows over the lane's cache (the
     dense objects share matmul/softmax/matmul over their vars, the
     paged object reads its pools in place).
-    ``cross_kv``: per-layer (ck, cv) [R,H,S,Dh] encoder projections
-    (vars for dense, pool gathers for paged). ``att_bias`` is the
+    ``cross``: per-layer cross-access objects owning the attention
+    over the prompt's encoder projections (_DenseCross over per-lane
+    [R,H,S,Dh] vars; _PagedPromptCross reads the lanes' entries of the
+    paged prompt table in place). ``att_bias`` is the
     0/-1e9 validity bias the dense objects add to their [R,H,q,maxT]
     attention scores — for q>1 it must be per-query-position causal
     ([R,1,q,maxT]: query j masks cache positions > t+j); the paged
@@ -743,16 +808,7 @@ def cached_decoder_step(x, caches, cross_kv, att_bias, d_model,
             x, d_model, num_flatten_dims=2, bias_attr=False,
             param_attr=T._attn_proj_attr(f"{prefix}dec{li}_cross",
                                          "q", d_model))
-        q2h = heads_of(q2, q, n_heads, head_dim)
-        ck, cv = cross_kv[li]
-        s2 = layers.scale(
-            layers.matmul(q2h, ck, transpose_y=True),
-            scale=scale)  # [R,H,q,S]
-        p2 = layers.softmax(s2, axis=-1)
-        ctx2 = layers.reshape(
-            layers.transpose(layers.matmul(p2, cv),
-                             perm=[0, 2, 1, 3]),
-            [0, q, d_model])
+        ctx2 = cross[li].attend(q2, n_heads, scale)  # [R,q,HD]
         cross_out = layers.fc(
             ctx2, d_model, num_flatten_dims=2,
             bias_attr=False,
@@ -890,8 +946,9 @@ def build_incremental_decode_program(seq_len=16, max_out_len=16,
                            param_attr=T._attn_proj_attr(
                                f"dec{li}_cross", "kv", d_model))
             k, v = layers.split(kv, 2, dim=2)
-            cross_kv.append((heads_of(k, seq_len, n_heads, head_dim),
-                             heads_of(v, seq_len, n_heads, head_dim)))
+            cross_kv.append(_DenseCross(
+                heads_of(k, seq_len, n_heads, head_dim),
+                heads_of(v, seq_len, n_heads, head_dim)))
 
         positions = layers.cast(layers.range(0, maxT, 1), "int64")
         posf = layers.cast(positions, "float32")
@@ -1478,10 +1535,12 @@ def _slot_state_specs(prefix, rows, maxT, seq_len, n_heads,
         specs[f"{prefix}self_k{li}{POOL_MARK}"] = (cells, "float32")
         specs[f"{prefix}self_v{li}{POOL_MARK}"] = (cells, "float32")
         # +1: the dustbin entry padded admission rows scatter into
+        # (entry-major rows of the same width: the tick reads a lane's
+        # entry as one block of seq_len rows, as it reads the cells)
         specs[f"{prefix}cross_k{li}{POOL_MARK}"] = (
-            (E + 1, n_heads, seq_len, head_dim), "float32")
+            (E + 1, seq_len, n_heads * head_dim), "float32")
         specs[f"{prefix}cross_v{li}{POOL_MARK}"] = (
-            (E + 1, n_heads, seq_len, head_dim), "float32")
+            (E + 1, seq_len, n_heads * head_dim), "float32")
     return specs
 
 
@@ -1552,9 +1611,9 @@ def interleave_qkv_params(scope, n_layers: int, n_heads: int,
 def _tp_state_placements(state_prefix, n_layers, cache, sharding
                          ) -> Dict[str, dict]:
     """{slot-state name -> {dim: axis}}: KV sharded along heads (dim
-    1 of the dense ``[R, H, T, Dh]`` lane buffers, of the paged
-    ``[NB*BS, H*Dh]`` self pool, whose heads are the major part of
-    that axis, and of the ``[E+1, H, S, Dh]`` cross pool).
+    1 of the dense ``[R, H, T, Dh]`` lane buffers and of the paged
+    ``[NB*BS, H*Dh]`` self pool, dim 2 of the ``[E+1, S, H*Dh]``
+    prompt table: heads are the major part of an ``H*Dh`` axis).
     Tables/masks/counters/draft state stay replicated —
     block tables in particular remain host-owned replicated int32, so
     the ownership story (PTA190/191) is untouched."""
@@ -1569,8 +1628,8 @@ def _tp_state_placements(state_prefix, n_layers, cache, sharding
         else:
             out[f"{state_prefix}self_k{li}{POOL_MARK}"] = {1: ax}
             out[f"{state_prefix}self_v{li}{POOL_MARK}"] = {1: ax}
-            out[f"{state_prefix}cross_k{li}{POOL_MARK}"] = {1: ax}
-            out[f"{state_prefix}cross_v{li}{POOL_MARK}"] = {1: ax}
+            out[f"{state_prefix}cross_k{li}{POOL_MARK}"] = {2: ax}
+            out[f"{state_prefix}cross_v{li}{POOL_MARK}"] = {2: ax}
     return out
 
 
@@ -1674,15 +1733,15 @@ def enc_param_placements(n_layers: int, sharding: "ShardingConfig",
 def _prefill_state_placements(state_prefix, n_layers, cache, sharding
                               ) -> Dict[str, dict]:
     """Prefill-phase slot-state placements: the cross pools it WRITES
-    sharded along heads (dim 1 of ``[E+1, H, S, Dh]`` — the same
-    tensor layout the decode plan reads, so the handoff is a
+    sharded along heads (dim 2 of ``[E+1, S, H*Dh]``, heads major —
+    the same tensor layout the decode plan reads, so the handoff is a
     device_put, not a re-layout) plus the chunk staging pools along
-    d_model (the heads-concat axis)."""
+    d_model (the same heads-concat axis)."""
     ax = sharding.axis
     out: Dict[str, dict] = {}
     for li in range(n_layers):
-        out[f"{state_prefix}cross_k{li}{POOL_MARK}"] = {1: ax}
-        out[f"{state_prefix}cross_v{li}{POOL_MARK}"] = {1: ax}
+        out[f"{state_prefix}cross_k{li}{POOL_MARK}"] = {2: ax}
+        out[f"{state_prefix}cross_v{li}{POOL_MARK}"] = {2: ax}
     if cache.chunked:
         out[f"{state_prefix}chunk_stage_a{POOL_MARK}"] = {2: ax}
         out[f"{state_prefix}chunk_stage_b{POOL_MARK}"] = {2: ax}
@@ -1833,8 +1892,9 @@ def _param_probe(prefix, seq_len, max_out_len, d_model, n_heads,
                                 f"{prefix}dec{li}_cross", "kv",
                                 d_model))
             k, v = layers.split(kvp, 2, dim=2)
-            cross.append((heads_of(k, seq_len, n_heads, head_dim),
-                          heads_of(v, seq_len, n_heads, head_dim)))
+            cross.append(_DenseCross(
+                heads_of(k, seq_len, n_heads, head_dim),
+                heads_of(v, seq_len, n_heads, head_dim)))
         ids = layers.assign(np.zeros((1, 1), "int64"))
         x = layers.unsqueeze(
             layers.embedding(ids, size=[vocab, d_model],
@@ -2563,13 +2623,13 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
         return src, enc
 
     def _cross_proj(enc, li):
+        """Layer li's cross-attention keys and values of the encoded
+        prompts, as rows: two [A, S, H*Dh]."""
         kvp = layers.fc(enc, 2 * d_model, num_flatten_dims=2,
                         bias_attr=False,
                         param_attr=T._attn_proj_attr(
                             f"dec{li}_cross", "kv", d_model))
-        k, v = layers.split(kvp, 2, dim=2)
-        return (heads_of(k, seq_len, n_heads, head_dim),
-                heads_of(v, seq_len, n_heads, head_dim))
+        return layers.split(kvp, 2, dim=2)
 
     # --- admission bodies: admit up to A prompts in ONE dispatch ----
     def _admit_body_dense(sv, A):
@@ -2582,7 +2642,8 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
         ohT = layers.transpose(oh, perm=[1, 0])  # [rows, A]
         flat = n_heads * seq_len * head_dim
         for li in range(n_layers):
-            kh, vh = _cross_proj(enc, li)
+            kh, vh = (heads_of(kv, seq_len, n_heads, head_dim)
+                      for kv in _cross_proj(enc, li))
             for var, new in (
                     (sv[f"{state_prefix}cross_k{li}"], kh),
                     (sv[f"{state_prefix}cross_v{li}"], vh)):
@@ -2621,11 +2682,11 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
                                       bound=E + 1)
         seeds = _seeds_data(A)
         for li in range(n_layers):
-            kh, vh = _cross_proj(enc, li)
+            k, v = _cross_proj(enc, li)
             for var, new in (
-                    (sv[f"{state_prefix}cross_k{li}{POOL_MARK}"], kh),
-                    (sv[f"{state_prefix}cross_v{li}{POOL_MARK}"],
-                     vh)):
+                    (sv[f"{state_prefix}cross_k{li}{POOL_MARK}"], k),
+                    (sv[f"{state_prefix}cross_v{li}{POOL_MARK}"], v)):
+                # the projection's rows are the entry as it is stored
                 layers.masked_pool_write(
                     var, new, pslots, leading_dims=1,
                     exclusive_via="host_indices")
@@ -2796,8 +2857,8 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
                                       sv[f"{state_prefix}self_v{li}"],
                                       write_mask, keep_mask)
                       for li in range(n_layers)]
-            cross_kv = [(sv[f"{state_prefix}cross_k{li}"],
-                         sv[f"{state_prefix}cross_v{li}"])
+            cross_kv = [_DenseCross(sv[f"{state_prefix}cross_k{li}"],
+                                    sv[f"{state_prefix}cross_v{li}"])
                         for li in range(n_layers)]
         else:
             # cell addresses through the HOST-owned block table:
@@ -2828,16 +2889,8 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
                 sv[f"{state_prefix}self_v{li}{POOL_MARK}"],
                 write_idx, gate, block_tab, stepv, rows, BS)
                 for li in range(n_layers)]
-            pref = sv[f"{state_prefix}prompt_ref"]
-            cross_kv = []
-            for li in range(n_layers):
-                pair = []
-                for tag in ("k", "v"):
-                    pool = sv[f"{state_prefix}cross_{tag}{li}"
-                              f"{POOL_MARK}"]
-                    # entries along axis 0 of the stored 4-D table
-                    pair.append(layers.gather(pool, pref))  # [R,H,S,Dh]
-                cross_kv.append(tuple(pair))
+            cross_kv = _paged_prompt_cross(sv, state_prefix, n_layers,
+                                           rows, seq_len)
         x = cached_decoder_step(x, caches, cross_kv, att_bias,
                                 d_model, n_heads, d_inner,
                                 qkv_interleaved=qkv_il)
@@ -3075,9 +3128,10 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
                         sv[f"{state_prefix}draft_self_v{li}"],
                         wm, km)
                     for li in range(draft.n_layers)]
-                dcross = [(sv[f"{state_prefix}draft_cross_k{li}"],
-                           sv[f"{state_prefix}draft_cross_v{li}"])
-                          for li in range(draft.n_layers)]
+                dcross = [_DenseCross(
+                    sv[f"{state_prefix}draft_cross_k{li}"],
+                    sv[f"{state_prefix}draft_cross_v{li}"])
+                    for li in range(draft.n_layers)]
                 x = cached_decoder_step(x, dcaches, dcross, dbias,
                                         dd, dH, draft.d_inner,
                                         prefix=draft.prefix)
@@ -3143,8 +3197,8 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
                 sv[f"{state_prefix}self_k{li}"],
                 sv[f"{state_prefix}self_v{li}"], t_mask_q, keep)
                 for li in range(n_layers)]
-            cross_kv = [(sv[f"{state_prefix}cross_k{li}"],
-                         sv[f"{state_prefix}cross_v{li}"])
+            cross_kv = [_DenseCross(sv[f"{state_prefix}cross_k{li}"],
+                                    sv[f"{state_prefix}cross_v{li}"])
                         for li in range(n_layers)]
         else:
             block_tab = sv[f"{state_prefix}block_tab"]     # [R,NP]
@@ -3176,15 +3230,8 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
                 sv[f"{state_prefix}self_v{li}{POOL_MARK}"],
                 write_idx, gate, block_tab, stepv, rows, BS, q=Q)
                 for li in range(n_layers)]
-            pref = sv[f"{state_prefix}prompt_ref"]
-            cross_kv = []
-            for li in range(n_layers):
-                pair = []
-                for tag in ("k", "v"):
-                    pool = sv[f"{state_prefix}cross_{tag}{li}"
-                              f"{POOL_MARK}"]
-                    pair.append(layers.gather(pool, pref))  # [R,H,S,Dh]
-                cross_kv.append(tuple(pair))
+            cross_kv = _paged_prompt_cross(sv, state_prefix, n_layers,
+                                           rows, seq_len)
         x = cached_decoder_step(x, caches, cross_kv, bias, d_model,
                                 n_heads, d_inner, q=Q,
                                 qkv_interleaved=qkv_il)    # [R,Q,D]
@@ -3295,8 +3342,8 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
                             sv[f"{state_prefix}draft_self_v{li}"],
                             wm, km)
             for li in range(draft.n_layers)]
-        dcross = [(sv[f"{state_prefix}draft_cross_k{li}"],
-                   sv[f"{state_prefix}draft_cross_v{li}"])
+        dcross = [_DenseCross(sv[f"{state_prefix}draft_cross_k{li}"],
+                              sv[f"{state_prefix}draft_cross_v{li}"])
                   for li in range(draft.n_layers)]
         cached_decoder_step(x, dcaches, dcross, dbias, dd, dH,
                             draft.d_inner, prefix=draft.prefix)
@@ -3489,9 +3536,9 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
                          layers.reshape(x2, [C, d_model]))
             return
         # final phase: project the chunk's cross-attention K/V for
-        # every decoder layer and install it into the prompt entry's
-        # cross pools — the entry layout is heads_of's [H, S, Dh], so
-        # the positional merge happens in a [S, H*Dh] view
+        # every decoder layer and merge it into the prompt entry's
+        # rows of the cross pools, which are stored [S, H*Dh] an entry
+        # as the staging pools are
         xrow = _stage_row(stage[L % 2], d_model)
         x = _chunk_of(xrow, d_model)
         for li in range(n_layers):
@@ -3503,22 +3550,8 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
             for tag, val in (("k", k), ("v", v)):
                 pool = sv[f"{state_prefix}cross_{tag}{li}"
                           f"{POOL_MARK}"]
-                row = layers.reshape(
-                    layers.transpose(layers.gather(pool, entry),
-                                     perm=[0, 2, 1, 3]),
-                    [S, d_model])
-                merged = layers.elementwise_add(
-                    layers.elementwise_mul(row, keep),
-                    layers.matmul(cselT,
-                                  layers.reshape(val, [C, d_model])))
-                layers.masked_pool_write(
-                    pool,
-                    layers.transpose(
-                        layers.reshape(merged,
-                                       [1, S, n_heads, head_dim]),
-                        perm=[0, 2, 1, 3]),
-                    entry, leading_dims=1,
-                    exclusive_via="host_indices")
+                _stage_merge(pool, _stage_row(pool, d_model),
+                             layers.reshape(val, [C, d_model]))
 
     serves = {0: _build_serve("miss", 0)}
     for A in admit_buckets:

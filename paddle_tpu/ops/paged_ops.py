@@ -8,9 +8,11 @@ shared block pool follows vLLM's PagedAttention block tables
 one persistable tensor, lanes address it through host-allocated
 int32 tables, ALL writes funnel through ``masked_pool_write`` so the
 lane-exclusivity contract is one auditable surface (analysis checker
-PTA110), and a decode tick's self-attention reads the pool where it
-is stored through ``paged_decode_attention`` (the cross-attention
-prompt table and the COW copy still read by plain `gather`).
+PTA110), and a decode tick's attention reads the pools where they are
+stored through ``paged_decode_attention``: the self pools behind the
+block table, the cross-attention prompt table behind ``prompt_ref``
+(the COW copy and the chunked prefill's staging rows still read by
+plain `gather`).
 """
 from __future__ import annotations
 
@@ -86,12 +88,21 @@ def masked_pool_write(ctx):
     return out.reshape(pool.shape)
 
 
+# what the routing record calls the op's decision, by what it reads
+ROUTE_LABELS = {"cells": "paged_decode_attention",
+                "prompt_table": "paged_decode_attention.prompt_table"}
+
+
 @register_op("paged_decode_attention", differentiable=False,
              stop_gradient_slots=("Q", "PoolK", "PoolV", "Table",
                                   "Pos"))
 def paged_decode_attention(ctx):
-    """Self-attention of the decode tick's queries over a lane's own
-    cache positions, read from the SHARED pools where they are stored.
+    """Attention of the decode tick's queries over a lane's own
+    positions, read from the SHARED pools where they are stored: the
+    self-attention over its cache cells and (``reads`` =
+    "prompt_table") the cross-attention over its prompt entry, a table
+    of one block of ``seq_len`` rows a lane with every lane at the
+    last position.
 
     inputs: Q [R, q, H*Dh] (this tick's query rows); PoolK, PoolV
     [NB*BS, H*Dh] (after this tick's ``masked_pool_write``); Table
@@ -102,9 +113,12 @@ def paged_decode_attention(ctx):
     the dense step's -1e9 bias masks them). attrs: block_size,
     n_heads, scale, n_kv_heads (default n_heads; fewer: grouped
     queries, the pools [NB*BS, Hkv*Dh], query head h reads key-value
-    head h // (H / Hkv); the jnp route). Out [R, q, H*Dh], the context rows. Idle and
-    dustbin lanes read whatever blocks their table rows name (block 0
-    when cleared) and their rows are ignored downstream.
+    head h // (H / Hkv); the jnp route), reads ("cells" by default,
+    or "prompt_table": which read of a tick this is, for the routing
+    record alone: no route depends on it). Out [R, q, H*Dh], the
+    context rows. Idle and dustbin lanes read whatever blocks their
+    table rows name (block 0 when cleared, the dustbin entry of the
+    prompt table) and their rows are ignored downstream.
 
     Nothing of shape ``[R, H, maxT, Dh]`` is built: the routes in
     ops/pallas/paged_attention.py (a Pallas kernel for q = 1 on one
@@ -126,11 +140,12 @@ def paged_decode_attention(ctx):
               scale=float(ctx.attr("scale", 1.0)))
     pos = pos.reshape(q.shape[0])
     n_kv = int(ctx.attr("n_kv_heads", 0)) or kw["n_heads"]
+    label = ROUTE_LABELS[ctx.attr("reads", "cells")]
     if n_kv != kw["n_heads"]:
-        note_route("paged_decode_attention", q.shape, False)
+        note_route(label, q.shape, False)
         return grouped_paged_attention(q, pool_k, pool_v, tab, pos,
                                        n_kv_heads=n_kv, **kw)
-    if note_route("paged_decode_attention", q.shape,
+    if note_route(label, q.shape,
                   PA.usable(q, pool_k, tab, kw["block_size"])):
         return PA.paged_decode_attention(q, pool_k, pool_v, tab, pos,
                                          **kw)

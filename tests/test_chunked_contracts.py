@@ -247,6 +247,102 @@ class TestRadixAwarePreemption:
             srv.close(1.0)
 
 
+class TestPromptEntryWriters:
+    """Every writer of the prompt table leaves an entry as the
+    projection's rows, ``[S, H*Dh]`` as stored: the monolithic miss
+    admission, the chunked prefill's final install, and the
+    disaggregated handoff that copies an entry from the prefill
+    worker's scope. The projection is the dense layout's admission of
+    the same prompt (its per-lane ``[H, S, Dh]``, heads folded back)."""
+
+    @pytest.fixture(scope="class")
+    def projection(self, built):
+        import numpy as np
+
+        scope, exe = built["scope"], built["exe"]
+        with unique_name.guard():
+            dense = T.build_decode_step_program(
+                n_slots=N_SLOTS, admit_buckets=[1],
+                state_prefix="@ccd/", seq_len=S, max_out_len=MAXT,
+                d_model=D, n_heads=H, n_layers=L, d_inner=32, vocab=V,
+                start_id=2, end_id=1)
+        dense.init_slot_state(scope)
+        src = np.random.RandomState(5).randint(
+            3, V, (1, S)).astype(np.int64)
+        exe.run(dense.serves[1],
+                feed={"src_ids": src, "slots": np.array([0], np.int64),
+                      "n_steps": np.array([0], np.int64),
+                      "min_active": np.array([0], np.int64)},
+                fetch_list=[dense.state["active"]], scope=scope)
+        rows = {f"cross_{kind}{li}": np.asarray(scope._get(
+            f"@ccd/cross_{kind}{li}"))[0].transpose(1, 0, 2).reshape(
+                S, D) for kind in "kv" for li in range(L)}
+        return src, rows
+
+    def _entry(self, scope, entry):
+        import numpy as np
+
+        from paddle_tpu.models.decode_engine import POOL_MARK
+
+        return {f"cross_{kind}{li}": np.asarray(scope._get(
+            f"@cc/cross_{kind}{li}{POOL_MARK}"))[entry]
+            for kind in "kv" for li in range(L)}
+
+    @pytest.mark.parametrize("writer", ["miss_admission",
+                                        "chunked_install", "handoff"])
+    def test_entry_reads_back_equal_to_the_projection(
+            self, built, projection, writer):
+        import numpy as np
+
+        from paddle_tpu.inference.serving import \
+            DisaggregatedPrefillWorker
+
+        b, exe, scope = built["bundle"], built["exe"], built["scope"]
+        src, want = projection
+        idle = {"n_steps": np.array([0], np.int64),
+                "min_active": np.array([0], np.int64)}
+        b.init_slot_state(scope)
+        if writer == "miss_admission":
+            exe.run(b.serves[("miss", 1)],
+                    feed={"src_ids": src,
+                          "slots": np.array([0], np.int64),
+                          "prompt_slots": np.array([1], np.int64),
+                          **idle},
+                    fetch_list=[b.state["active"]], scope=scope)
+            got = self._entry(scope, 1)
+        elif writer == "chunked_install":
+            for key in b.chunk_phase_keys:
+                for pos in range(0, S, C):
+                    feed = {"chunk_entry": np.array([0], np.int64),
+                            "chunk_pos": np.array([pos], np.int64),
+                            **idle}
+                    if key[1] == 0:
+                        feed["chunk_toks"] = src[:, pos:pos + C]
+                    exe.run(b.serves[key], feed=feed,
+                            fetch_list=[b.state["active"]],
+                            scope=scope)
+            got = self._entry(scope, 0)
+        else:
+            worker = DisaggregatedPrefillWorker(
+                b, executor=exe, scope=Scope(), params_from=scope)
+            try:
+                with PagedContinuousGenerationServer(
+                        b, executor=exe, scope=scope,
+                        steps_per_tick=4,
+                        prefill_worker=worker) as srv:
+                    srv.submit(src[0]).result(timeout=120.0)
+                    assert srv.pool_stats()["disagg_handoffs"] == 1
+                    got = self._entry(
+                        scope, srv._prefix._by_prompt[tuple(src[0])])
+            finally:
+                worker.close()
+        for name, rows in want.items():
+            assert got[name].shape == (S, D)
+            np.testing.assert_allclose(got[name], rows, rtol=1e-5,
+                                       atol=1e-6, err_msg=name)
+        b.init_slot_state(scope)
+
+
 class TestAnalysisContracts:
     def test_chunk_cursor_source_registered(self):
         srcs = absint.pool_index_sources()

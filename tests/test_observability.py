@@ -724,7 +724,8 @@ CONTINUOUS_STATS_KEYS = {
     "cache_hit_count", "disk_load_count", "cache_evict_count",
     "warmed_compiles", "latency_ms", "ttft_ms", "queue_wait_ms",
     "per_token_ms", "tokens", "retired_per_s", "cancelled",
-    "deadline_expired", "self_attention_routes", "device_telemetry"}
+    "deadline_expired", "self_attention_routes",
+    "cross_attention_routes", "device_telemetry"}
 
 
 @pytest.fixture(scope="module")

@@ -290,6 +290,62 @@ class TestPTA190UncheckedRead:
         assert len(ds) == 2 and all(d.severity == ERROR for d in ds)
 
 
+def _prompt_read_fixture(bound, entries=4):
+    """The paged tick's cross-attention read as the engine wires it
+    (``decode_engine._PagedPromptCross``): a prompt table of
+    ``entries`` entries of 4 rows, the dustbin among them, behind a
+    ``prompt_ref`` marked with ``bound``."""
+    from paddle_tpu.models.decode_engine import _PagedPromptCross
+
+    main, startup, g = _guarded()
+    with g:
+        blk = main.global_block
+        tk = _mk_pool(blk, "@own/cross_k0@POOL", (entries, 4, 16))
+        tv = _mk_pool(blk, "@own/cross_v0@POOL", (entries, 4, 16))
+        pref = _mk_state(blk, "@own/prompt_ref", (3,))
+        absint.mark_pool_index_source(pref, "prompt_entry_ref",
+                                      bound=bound)
+        q = layers.data("q", shape=[3, 1, 16], dtype="float32",
+                        append_batch_size=False)
+        last = layers.fill_constant([3], "int32", 3.0)
+        _PagedPromptCross(tk, tv, layers.reshape(pref, [3, 1]),
+                          last).attend(q, 2, 0.5)
+    return main
+
+
+class TestPTA190PromptTableRead:
+    """The cross-attention read of a paged tick is the same unchecked
+    read: PTA190 proves it from ``prompt_ref``'s mark
+    (``prompt_entry_ref``, bound E + 1) against the table's E + 1
+    blocks of ``seq_len`` rows, through the reshapes on the way."""
+
+    def test_proven_from_prompt_entry_ref(self):
+        main = _prompt_read_fixture(bound=4)
+        for code in ("PTA190", "PTA191", "PTA192"):
+            assert not _diags(main, code), code
+        facts = absint.analyze(main)
+        reads = [a for a in facts.pool_accesses if a.kind == "read"]
+        assert [(a.pool, a.axis_size, a.unchecked) for a in reads] == [
+            ("@own/cross_k0@POOL", 4, True),
+            ("@own/cross_v0@POOL", 4, True)]
+        for a in reads:
+            assert a.index_fact.tags == ("prompt_entry_ref",)
+            assert a.index_fact.bound == 4
+        ledger = facts.ownership_ledger()
+        assert ledger["proven_reads"] == 2 and ledger["unproven"] == 0
+
+    @pytest.mark.parametrize("bound,entries,needle", [
+        (5, 4, "exceeds"), (None, 4, "unprovable"), (4, 3, "exceeds"),
+    ], ids=["bound_past_the_table", "no_bound", "table_one_short"])
+    def test_bound_past_the_table_is_error(self, bound, entries,
+                                           needle):
+        ds = [d for d in _diags(_prompt_read_fixture(bound, entries),
+                                "PTA190") if needle in d.message]
+        assert len(ds) == 2 and {d.severity for d in ds} == {ERROR}
+        assert {d.var for d in ds} == {"@own/cross_k0@POOL",
+                                       "@own/cross_v0@POOL"}
+
+
 class TestProvenanceSoundness:
     """Regression pins for the review-found holes in the bound/
     one-hot algebra: each was a way to certify a LYING bound (a

@@ -78,6 +78,59 @@ class TestSmokeParity:
         np.testing.assert_array_equal(got, want)
 
 
+    def test_paged_tick_under_tp_keeps_the_prompt_table_sharded(self):
+        """A paged bundle under tp=2: the prompt table is placed on
+        its ``H*Dh`` axis like the self pools, the tick's reads of it
+        imply no collective (the prover's events on the program the
+        executor runs), and the served tokens are the single-device
+        whole loop's."""
+        from paddle_tpu.analysis import absint
+        from paddle_tpu.inference import PagedContinuousGenerationServer
+        from paddle_tpu.models.decode_engine import POOL_MARK
+
+        exe = fluid.Executor(fluid.TPUPlace(0))
+        scope = _init_scope(exe)
+        srcs = np.random.RandomState(5).randint(
+            3, DIMS["vocab"], (4, DIMS["seq_len"])).astype(np.int64)
+        with unique_name.guard():
+            inc_m, _, _, inc_buf = T.build_incremental_decode_program(
+                **DIMS)
+        want, = exe.run(inc_m, feed={"src_ids": srcs},
+                        fetch_list=[inc_buf], scope=scope)
+        want = apply_eos_sentinel(np.asarray(want), DIMS["end_id"])
+        with unique_name.guard():
+            b = T.build_decode_step_program(
+                n_slots=2, admit_buckets=[2], state_prefix="@fsp/",
+                sharding=ShardingConfig(tp=2),
+                cache=CacheConfig(layout="paged", block_size=4,
+                                  n_blocks=8, n_prompt_entries=4),
+                **DIMS)
+        places = b.sharding_plan.placements
+        for li in range(DIMS["n_layers"]):
+            for tag in "kv":
+                assert places[f"@fsp/cross_{tag}{li}{POOL_MARK}"] == {
+                    2: "tp"}
+                assert places[f"@fsp/self_{tag}{li}{POOL_MARK}"] == {
+                    1: "tp"}
+        facts = absint.analyze(b.serves[0])
+        reads = [es.site.op for es in facts.collective_events
+                 if es.site.op.type == "paged_decode_attention"]
+        assert not reads
+        n_reads = sum(op.type == "paged_decode_attention"
+                      for blk in b.serves[0].blocks for op in blk.ops)
+        assert n_reads == 2 * DIMS["n_layers"]
+        with PagedContinuousGenerationServer(b, executor=exe,
+                                             scope=scope) as srv:
+            outs = [srv.submit(s) for s in srcs]
+            got = np.stack([o.result(120.0) for o in outs])
+            st = srv.stats()
+        np.testing.assert_array_equal(got, want)
+        # a program a mesh places takes the reference route
+        for stat in ("self_attention_routes", "cross_attention_routes"):
+            assert {tuple(v) for v in st[stat].values() if v} == {
+                ("reference",)}
+
+
 class TestIdentity:
     def _bundle(self, prefix, sharding=None):
         with unique_name.guard():

@@ -786,6 +786,74 @@ class TestPagedSpecOpRules:
                            "collective-permute"):
             assert collective not in hlo, collective
 
+    def test_prompt_table_read_keeps_head_shard_no_collective(self):
+        """The cross-attention read of a paged tick as the engine
+        wires it: the ``[E+1, S, H*Dh]`` prompt table sharded on its
+        ``H*Dh`` axis under tp. The reshapes to rows and to a table of
+        one block a lane carry the placement, the context rows come
+        out sharded the same way, and neither the rules nor GSPMD's
+        program for the reference route hold a collective."""
+        from paddle_tpu.models.decode_engine import _PagedPromptCross
+
+        E1, S, H, Dh, R = 4, 8, 4, 8, 5
+        main, startup, g = _guarded()
+        with g:
+            tables = []
+            for tag in "kv":
+                t = main.global_block.create_var(
+                    name=f"@rule3/cross_{tag}0@POOL",
+                    shape=(E1, S, H * Dh), dtype="float32",
+                    persistable=True, stop_gradient=True)
+                absint.mark_sharded(t, {2: "tp"})
+                tables.append(t)
+            q = _data("q", (R, 1, H * Dh), {2: "tp"})
+            pref = _data("pref", (R,), dtype="int32")
+            absint.mark_pool_index_source(pref, "prompt_entry_ref",
+                                          bound=E1)
+            last = layers.fill_constant([R], "int32", float(S - 1))
+            out = _PagedPromptCross(
+                tables[0], tables[1], layers.reshape(pref, [R, 1]),
+                last).attend(q, H, 0.5)
+        absint.set_mesh(main, MESH)
+        facts = self._facts(main)
+        assert facts.converged
+        assert facts.spec(out.name) == ShardSpec.of({2: "tp"})
+        assert not facts.collective_events
+        assert not _diags(main, "PTA190")
+
+        import jax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+        from paddle_tpu.ops import pallas
+        from paddle_tpu.ops.pallas.paged_attention import \
+            paged_attention_reference
+
+        def fn(q, tk, tv, ref):
+            with pallas.auto_partitioned():
+                return paged_attention_reference(
+                    q, tk.reshape(-1, H * Dh), tv.reshape(-1, H * Dh),
+                    ref.reshape(R, 1),
+                    np.full((R,), S - 1, np.int32), block_size=S,
+                    n_heads=H, scale=0.5)
+
+        arrays = [np.zeros((R, 1, H * Dh), np.float32),
+                  np.zeros((E1, S, H * Dh), np.float32),
+                  np.zeros((E1, S, H * Dh), np.float32),
+                  np.zeros((R,), np.int32)]
+        pspecs = [(None, None, "tp"), (None, None, "tp"),
+                  (None, None, "tp"), ()]
+        got = _jax_out_pspec(fn, arrays, pspecs, 3)
+        assert got == _spec_to_pspec(facts.spec(out.name), 3)
+        mesh = Mesh(np.array(jax.devices()[:8]).reshape(4, 2),
+                    ("dp", "tp"))
+        hlo = jax.jit(fn).lower(*[
+            jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=NamedSharding(
+                mesh, PartitionSpec(*p)))
+            for a, p in zip(arrays, pspecs)]).compile().as_text()
+        for collective in ("all-reduce", "all-gather", "all-to-all",
+                           "collective-permute"):
+            assert collective not in hlo, collective
+
     def test_paged_decode_attention_sharded_table_is_an_event(self):
         NB, BS, H, Dh, R, NP = 8, 4, 4, 8, 8, 2
         main, startup, g = _guarded()

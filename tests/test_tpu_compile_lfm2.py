@@ -186,3 +186,46 @@ def test_glm_prefill_chunk_threshold_and_dense_attention(one_chip, chunk):
     # a block of queries at a time: scores of 128 queries by 32 heads,
     # then of 16 queries by 64 heads, over 36,864 positions
     assert _temp_gb(compiled) < 2.0
+
+
+def test_prompt_table_read_at_the_serve_cells_size(one_chip):
+    """The cross-attention read of the same cell: 33 lanes each on one
+    of 129 prompt entries of 256 rows, the tables in as stored
+    (``[E+1, S, H*Dh]``) and merged into rows on the way to the
+    kernel, one block of 256 rows a lane, every lane at position 255.
+    The self and the cross read of a layer share the kernel's name
+    and a program holds one lowering of each shape."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    rows, heads, dim, seq, entries, layers = 33, 16, 64, 256, 129, 6
+    width = heads * dim
+
+    def tick(q, tables, ref):
+        last = jnp.full((rows,), seq - 1, jnp.int32)
+        for li in range(layers):
+            q = q + pa.paged_decode_attention(
+                q, tables[2 * li].reshape(-1, width),
+                tables[2 * li + 1].reshape(-1, width),
+                ref.reshape(rows, 1), last, block_size=seq,
+                n_heads=heads, scale=dim ** -0.5)
+        return q
+    lowered = jax.jit(tick).lower(
+        _spec(one_chip, (rows, 1, width), jnp.float32),
+        [_spec(one_chip, (entries, seq, width), jnp.float32)
+         for _ in range(2 * layers)],
+        _spec(one_chip, (rows,), jnp.int32))
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    compiled = lowered.compile()
+    assert _kernel_names(compiled) == {"paged_decode_attention"}
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == layers
+    # no gathered copy of the lanes' entries, head-major or as rows,
+    # and merging entries into rows moves nothing: no copy of a table
+    for shape in ("f32[33,16,256,64]", "f32[33,256,1024]",
+                  "f32[33,1,256,1024]"):
+        assert shape not in text, shape
+    assert "copy-start" not in text
+    import re
+
+    assert not re.search(r"= f32\[(129,256|33024),1024\]\S* copy\(",
+                         text)
