@@ -761,31 +761,34 @@ def _scope_state(scope, groups, placement, transfers):
     the scope placed."""
     device, mesh, rule = placement.device, placement.mesh, placement.rule
     outs, going = [], []
-    for names in groups:
-        out = {}
-        outs.append(out)
-        for n in names:
-            v = scope._get(n)
-            if v is None:
-                raise RuntimeError(
-                    f"Variable {n!r} is used before initialization -- "
-                    f"run the startup program first")
-            if rule is not None:
-                v = rule(n, v)
-            elif device is not None:
-                if not isinstance(v, jax.Array):
-                    going.append((out, n, np.asarray(v)))
-                    continue
-            else:
-                placed = _onto_mesh(v, mesh)
-                if placed is not v:
-                    scope._set(n, placed)
-                    v = placed
-            out[n] = v
-    placed = transfers.put([v for _, _, v in going], device)
-    for (out, n, _), v in zip(going, placed):
-        out[n] = v
-        scope._set(n, v)
+    with _span("exe.state.gather"):
+        for names in groups:
+            out = {}
+            outs.append(out)
+            for n in names:
+                v = scope._get(n)
+                if v is None:
+                    raise RuntimeError(
+                        f"Variable {n!r} is used before initialization"
+                        f" -- run the startup program first")
+                if rule is not None:
+                    v = rule(n, v)
+                elif device is not None:
+                    if not isinstance(v, jax.Array):
+                        going.append((out, n, np.asarray(v)))
+                        continue
+                else:
+                    placed = _onto_mesh(v, mesh)
+                    if placed is not v:
+                        scope._set(n, placed)
+                        v = placed
+                out[n] = v
+    if going:
+        with _span("exe.state.put"):
+            placed = transfers.put([v for _, _, v in going], device)
+            for (out, n, _), v in zip(going, placed):
+                out[n] = v
+                scope._set(n, v)
     return outs
 
 

@@ -122,7 +122,7 @@ class DecoderOnlyPagedServer(PagedContinuousGenerationServer):
                 f"a decoder-only bundle takes a prompt, max_new_tokens "
                 f"and cache_tokens; {sorted(extra)} belong to the "
                 f"encoder-decoder bundles")
-        with obs_tracing.span("slotpool.submit"):
+        with self._submit_span():
             return self._enqueue_prompt(src_ids, max_new_tokens,
                                         cache_tokens, stream, stream_cb,
                                         deadline_ms)
@@ -283,6 +283,7 @@ class DecoderOnlyPagedServer(PagedContinuousGenerationServer):
         chunks, admits = self._plan
         a = self.bundle.max_chunks
         feed = {}
+        n_chunks = positions = 0
         for size, cut in chunks.items():
             toks = np.zeros((a, size), np.int64)
             lane = np.full((a,), self.bundle.dustbin, np.int64)
@@ -291,8 +292,8 @@ class DecoderOnlyPagedServer(PagedContinuousGenerationServer):
             for i, (slot, at, n) in enumerate(cut):
                 toks[i, :n] = self._lanes[slot].prompt[at:at + n]
                 lane[i], pos[i], length[i] = slot, at, n
-                self._dec["prefill_tokens"] += n
-            self._dec["prefill_chunks"] += len(cut)
+                positions += n
+            n_chunks += len(cut)
             feed.update({
                 f"chunk_toks_{size}": toks, f"chunk_lane_{size}": lane,
                 f"chunk_pos_{size}": pos, f"chunk_len_{size}": length,
@@ -306,6 +307,12 @@ class DecoderOnlyPagedServer(PagedContinuousGenerationServer):
             self._lane_base[slot] = base[i]
         feed.update({"admit_slots": slots, "admit_tok": tok,
                      "admit_base": base, "admit_limit": limit})
+        self._dec["prefill_chunks"] += n_chunks
+        self._dec["prefill_tokens"] += positions
+        rec = obs_tracing.current_cycle()
+        if rec is not None:
+            rec.attrs.update(prefill_chunks=n_chunks,
+                             prefill_positions=positions)
         return self.bundle.PREFILL, feed
 
     def _admission_feed(self, admits):
@@ -432,17 +439,19 @@ class DecoderOnlyPagedServer(PagedContinuousGenerationServer):
         the lane's and are freed) and, with `record_probes`, keeps what
         the probes hold of its lane."""
         self._filling.pop(slot, None)
-        if req.harvest and self._harvest_ok:
-            keep = 0 if self._lane_state else \
+        harvest = req.harvest and self._harvest_ok
+        if harvest and self._record_probes:
+            with obs_tracing.span("slotpool.retire.probe"):
+                self._keep_probe(slot, req)
+        with obs_tracing.span("slotpool.retire.tree"):
+            keep = 0 if self._lane_state or not harvest else \
                 min(req.cache_tokens, len(req.prompt)) // self._bs
             if keep:
                 self._radix.insert(
                     _ROOT, _chunks(req.prompt[:keep * self._bs],
                                    self._bs),
                     [int(b) for b in self._tab[slot, :keep]])
-            if self._record_probes:
-                self._keep_probe(slot, req)
-        self._free_lane_locked(slot)
+            self._free_lane_locked(slot)
         self._tab[slot, :] = 0
 
     def _keep_probe(self, slot, req):

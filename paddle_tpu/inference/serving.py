@@ -358,6 +358,16 @@ def _pct_dict(vals):
 _obs_server_seq = itertools.count(1)
 
 
+# the phases of a scheduler cycle that get a histogram
+# (`paddle_tpu_server_cycle_ms{phase=...}`, `stats()["cycle_ms"]`), by
+# the span that times each; `gc` and `wall` are the ring's own
+_CYCLE_PHASES = {
+    **{name: "slotpool." + name for name in
+       ("plan", "feed", "dispatch", "retire", "deliver")},
+    **{name: name for name in ("exe.feed", "exe.state", "exe.call",
+                               "exe.store", "exe.fetch")}}
+
+
 def _obs_server_id(server) -> str:
     """Stable per-instance metrics label, e.g. InferenceServer-3
     (itertools.count: thread-safe like Executor._obs_seq — servers
@@ -1380,6 +1390,9 @@ class ContinuousGenerationServer:
         self._t_start = time.monotonic()
         self._t_window = self._t_start
         self._obs_id = _obs_server_id(self)
+        # one record a scheduler cycle, at every flag level
+        self._cycles = obs_tracing.CycleRing(
+            owner=self._obs_id, phases=_CYCLE_PHASES)
         obs_metrics.register_provider(self)
 
         if start:
@@ -1518,10 +1531,19 @@ class ContinuousGenerationServer:
           never extend the session history. Distinct generations need
           a sampled bundle — greedy branches are identical.
         """
-        with obs_tracing.span("slotpool.submit"):
+        with self._submit_span():
             return self._enqueue(src_ids, seed, session_id,
                                  extend_tokens, n_best, stream,
                                  stream_cb, deadline_ms)
+
+    def _submit_span(self):
+        """The `slotpool.submit` span of one `submit`, on the caller's
+        thread. A callback that submits from the scheduler's thread
+        does so inside the open cycle, whose record counts it."""
+        rec = obs_tracing.current_cycle()
+        if rec is not None:
+            rec.attrs["submitted"] = rec.attrs.get("submitted", 0) + 1
+        return obs_tracing.span("slotpool.submit")
 
     def _enqueue(self, src_ids, seed, session_id, extend_tokens, n_best,
                  stream, stream_cb, deadline_ms):
@@ -1971,43 +1993,59 @@ class ContinuousGenerationServer:
     def _loop(self):
         while True:
             failures = []
-            with self._cv:
-                if self._idle_locked():
-                    with obs_tracing.span("slotpool.wait"):
-                        while self._idle_locked():
-                            self._cv.wait()
-                if not self._running:
-                    return
-                with obs_tracing.span("slotpool.plan") as sp:
-                    cancels = self._shed_cancelled_locked(
-                        time.monotonic())
-                    admits = self._plan_admissions_locked(failures)
-                    drain = not self._queue
-                    # empty queue: let the burst run — the device
-                    # loop exits by itself once the pool drains
-                    n_steps, min_active, run = \
-                        self._plan_burst_locked(admits, drain,
-                                                failures)
-                    if sp.recording:
-                        sp.attrs.update(
-                            admits=len(admits),
-                            queue_depth=len(self._queue),
-                            tier=self._admit_tier or "none")
+            # the cycle's record: opened after the wait, where the
+            # planning starts and under the lock the wait held, and
+            # closed after the last delivery
+            rec = obs_tracing.cycle("slotpool.cycle", self._cycles)
+            try:
+                with self._cv:
+                    if self._idle_locked():
+                        with obs_tracing.span("slotpool.wait"):
+                            while self._idle_locked():
+                                self._cv.wait()
+                    if not self._running:
+                        return
+                    rec.__enter__()
+                    with obs_tracing.span("slotpool.plan") as sp:
+                        cancels = self._shed_cancelled_locked(
+                            time.monotonic())
+                        admits = self._plan_admissions_locked(failures)
+                        drain = not self._queue
+                        # empty queue: let the burst run — the device
+                        # loop exits by itself once the pool drains
+                        n_steps, min_active, run = \
+                            self._plan_burst_locked(admits, drain,
+                                                    failures)
+                        planned = dict(admits=len(admits),
+                                       queue_depth=len(self._queue),
+                                       tier=self._admit_tier or "none")
+                        # `submitted`: what the callers' callbacks
+                        # send back in on this thread during the
+                        # cycle (`_submit_span`)
+                        rec.attrs.update(planned, submitted=0)
+                        if sp.recording:
+                            sp.attrs.update(planned)
+                    if run:
+                        self._busy = True  # drain() waits on this
+                # failing futures fires their done-callbacks
+                # synchronously — never under the scheduler lock
+                if cancels or failures:
+                    with obs_tracing.span("slotpool.deliver"):
+                        self._finalize_cancelled(cancels)
+                        self._fail_requests(failures)
                 if run:
-                    self._busy = True  # drain() waits on this
-            # failing futures fires their done-callbacks synchronously
-            # — never under the scheduler lock
-            if cancels or failures:
-                with obs_tracing.span("slotpool.deliver"):
-                    self._finalize_cancelled(cancels)
-                    self._fail_requests(failures)
-            if run:
-                try:
-                    self._cycle(admits, n_steps, min_active)
-                finally:
-                    with self._cv:
-                        self._busy = False
-                        self._cv.notify_all()
+                    try:
+                        self._cycle(admits, n_steps, min_active)
+                    finally:
+                        with self._cv:
+                            self._busy = False
+                            self._cv.notify_all()
+                else:
+                    # a pass that dispatched nothing is no cycle
+                    rec.drop()
+            finally:
+                if obs_tracing.current_cycle() is rec:
+                    rec.__exit__(None, None, None)
 
     def _cycle(self, admits, n_steps, min_active):
         """ONE fused dispatch per scheduler cycle: admit up to A
@@ -2016,6 +2054,14 @@ class ContinuousGenerationServer:
         — admission cost scales with buckets, not requests, and the
         dispatch overhead amortizes over the whole burst."""
         with obs_tracing.span("slotpool.feed"):
+            # the open record of this cycle (none under a test's own
+            # drive): its counts are set inside the phases they
+            # belong to, so that no moment of a cycle lies between
+            # two spans for the record's sake
+            rec = obs_tracing.current_cycle()
+            transfers = self.executor._transfers
+            placed0 = transfers.placed_arrays
+            fetched0 = transfers.fetched_arrays
             feed = {"n_steps": np.array([n_steps], np.int64),
                     "min_active": np.array([max(0, min_active)],
                                            np.int64)}
@@ -2045,6 +2091,9 @@ class ContinuousGenerationServer:
                     key = ("k", kv, key)
                     k_used = kv
             self._pre_dispatch()
+            if rec is not None:
+                rec.key = key
+                rec.attrs["n_steps"] = n_steps
         try:
             c0 = self.executor.compile_count
             d0 = self.executor.disk_load_count
@@ -2066,7 +2115,10 @@ class ContinuousGenerationServer:
                         # telemetry counters and annotate the span
                         # the flight recorder retains (exit reason,
                         # ticks, occupancy)
-                        self._absorb_devtel(key, outs, wall_s, sp)
+                        ticks = self._absorb_devtel(key, outs, wall_s,
+                                                    sp)
+                        if rec is not None:
+                            rec.attrs["ticks"] = ticks
                     if self._spec_names:
                         # delta the device-side spec counters for
                         # this dispatch: the acceptance-rate sample
@@ -2130,6 +2182,11 @@ class ContinuousGenerationServer:
                         and req.trace.owner == "server":
                     req.trace.finish()
             self._finalize_cancelled(cancels)
+            if rec is not None:
+                rec.attrs.update(
+                    retired=len(retired), delivered=len(stream_out),
+                    placed_arrays=transfers.placed_arrays - placed0,
+                    fetched_arrays=transfers.fetched_arrays - fetched0)
 
     def _retire_lanes(self, outs):
         """The sweep over the lanes after a dispatch, under the lock:
@@ -2287,7 +2344,7 @@ class ContinuousGenerationServer:
                 outs[off:off + len(self._devtel.fetch_names)])
         ticks = deltas.get("tel_ticks", 0)
         if not ticks:
-            return
+            return 0
         if sp.recording:
             sp.attrs["ticks"] = ticks
             sp.attrs["occupancy_integral"] = deltas.get(
@@ -2296,7 +2353,7 @@ class ContinuousGenerationServer:
             if reason is not None:
                 sp.attrs["exit_reason"] = reason
         if not obs_metrics.metrics_on():
-            return
+            return ticks
         # per-tick cost comes from the KEY-0 serve snapshot — the
         # pure-burst program (no admission body), so its one-While-
         # body cost IS one tick. A per-key snapshot would fold the
@@ -2324,6 +2381,7 @@ class ContinuousGenerationServer:
                     else work + max(0.0, kflops - flops)
             if work:
                 obs_costmodel.observe(work, wall_s)
+        return ticks
 
     def _host_tel_locked(self, reset: bool) -> dict:
         """Host-side supplement to stats()['device_telemetry']
@@ -2419,6 +2477,10 @@ class ContinuousGenerationServer:
                 "ttft_ms": _pct_dict(self._ttft),
                 "queue_wait_ms": _pct_dict(self._queue_wait),
                 "per_token_ms": _pct_dict(self._per_token),
+                # the scheduler's cycles by phase (p50/p95/max), and
+                # how many took over twice their serve key's median
+                "cycle_ms": self._cycles.summary(),
+                "slow_cycles": self._cycles.slow_cycles,
                 "tokens": self._n_tokens,
                 "retired_per_s": (
                     round(self._n_done / done_span, 1)
@@ -2464,6 +2526,7 @@ class ContinuousGenerationServer:
                 self._ttft.clear()
                 self._queue_wait.clear()
                 self._per_token.clear()
+                self._cycles.clear()
                 self._acc_hist.clear()
                 self._spec_base = dict(self._spec_tot)
                 self._per_k_base = {k: dict(v) for k, v in
@@ -2503,7 +2566,8 @@ class ContinuousGenerationServer:
                 ("paddle_tpu_request_queue_wait_ms", lab,
                  self._queue_wait),
                 ("paddle_tpu_per_token_ms", lab, self._per_token),
-            ]
+            ] + self._cycles.metric_samples(
+                "paddle_tpu_server_cycle_ms", lab)
             if self._spec_k > 0:
                 t = self._spec_tot
                 samples += [
@@ -3533,14 +3597,17 @@ class PagedContinuousGenerationServer(ContinuousGenerationServer):
             self._advance_prefill()
 
     def _release_lane(self, slot, req):
-        sid = self._lane_sess[slot]
-        if sid is not None and req.harvest and self._harvest_ok:
-            self._harvest_session_locked(slot, sid)
-        elif (sid is None and req.harvest and self._harvest_ok
-                and self._radix_ok and self._radix_reuse
-                and self._spec_k == 0 and not self._needs_seeds):
-            self._harvest_plain_locked(slot, req)
-        self._free_lane_locked(slot)
+        # `slotpool.retire.tree`: what a lane's end costs in the radix
+        # tree and the pools (the harvest's inserts, the releases)
+        with obs_tracing.span("slotpool.retire.tree"):
+            sid = self._lane_sess[slot]
+            if sid is not None and req.harvest and self._harvest_ok:
+                self._harvest_session_locked(slot, sid)
+            elif (sid is None and req.harvest and self._harvest_ok
+                    and self._radix_ok and self._radix_reuse
+                    and self._spec_k == 0 and not self._needs_seeds):
+                self._harvest_plain_locked(slot, req)
+            self._free_lane_locked(slot)
 
     def _harvest_session_locked(self, slot, sid):
         """Adopt a retiring session turn into the radix tree: the

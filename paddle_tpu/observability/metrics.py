@@ -191,6 +191,11 @@ class Histogram:
     def sum(self) -> float:
         return self._sum
 
+    @property
+    def max(self) -> Optional[float]:
+        """The largest value observed, exact; None when empty."""
+        return self._max
+
     def reset(self):
         """Window reset (the servers' ``stats(reset=True)`` contract)."""
         with self._lock:

@@ -42,29 +42,46 @@ request spend its 300 ms" — so this module adds:
   the clock of the device's operations (benchmark/chip/program_spans.py
   reads them back).
 
+* **The cycle record** — a third sink of ``span``, kept at every
+  flag level: inside ``with cycle(name, ring)`` every span that closes
+  on the thread adds its length under its name to the open record,
+  which closes with the counts its owner sets and the collector's
+  runs (four times a second, and at every slow one, also with the
+  thread's and the process's processor time), and goes to the
+  ring (``CycleRing``: the last 512 records, a histogram a phase,
+  the slow ones to the flight recorder) and, while a profile runs,
+  into it as a short ``paddle_tpu:<name>`` marker whose metadata is
+  the record. The serving scheduler opens one a cycle
+  (``slotpool.cycle``); a training loop wraps ``exe.run`` the same
+  way.
+
 Everything here is always compiled in. ``FLAGS_observability``
-decides the in-process sinks: at ``off``/``metrics`` no request trace
-is opened, no span is kept in the process and ``dump_trace`` writes an
-empty trace.
+decides the request sinks: at ``off``/``metrics`` no request trace
+is opened, no span is kept in the process beyond the open cycle's
+sums and ``dump_trace`` writes an empty trace.
 """
 from __future__ import annotations
 
 import collections
+import gc
 import itertools
 import json
 import os
+import statistics
 import threading
 import time
+import weakref
 from typing import Dict, List, Optional
 
 from jax.profiler import TraceAnnotation
 
-from .metrics import metrics_on, trace_on
+from .metrics import Histogram, metrics_on, trace_on
 
 __all__ = ["Span", "Trace", "Tracer", "TRACER", "trace_on",
            "metrics_on", "start_request", "current_request_trace",
            "request_context", "ambient", "ambient_traces", "span",
-           "record_global_event", "dump_trace", "reset", "SPAN_PREFIX"]
+           "record_global_event", "dump_trace", "reset", "SPAN_PREFIX",
+           "CycleRing", "cycle", "current_cycle", "EXE_PHASES"]
 
 # what every program span is called in a profiler trace
 SPAN_PREFIX = "paddle_tpu:"
@@ -216,7 +233,17 @@ record_global_event = TRACER.record_global_event
 
 
 # --- ambient context (cross-layer span attachment) ---------------------
-_tls = threading.local()
+class _ThreadState(threading.local):
+    """What is parked on a thread, with nothing parked as the class's
+    defaults: a read of an unset `threading.local` attribute raises
+    inside `getattr`, ten times the cost of a plain lookup, and every
+    span reads two of these."""
+    request_trace = None
+    batch_traces = None
+    cycle = None
+
+
+_tls = _ThreadState()
 
 
 class request_context:
@@ -229,7 +256,7 @@ class request_context:
         self._trace = trace
 
     def __enter__(self):
-        self._prev = getattr(_tls, "request_trace", None)
+        self._prev = _tls.request_trace
         _tls.request_trace = self._trace
         return self._trace
 
@@ -239,7 +266,7 @@ class request_context:
 
 
 def current_request_trace() -> Optional[Trace]:
-    return getattr(_tls, "request_trace", None)
+    return _tls.request_trace
 
 
 class ambient:
@@ -251,7 +278,7 @@ class ambient:
         self._traces = [t for t in (traces or []) if t is not None]
 
     def __enter__(self):
-        self._prev = getattr(_tls, "batch_traces", None)
+        self._prev = _tls.batch_traces
         _tls.batch_traces = self._traces
         return self._traces
 
@@ -261,7 +288,7 @@ class ambient:
 
 
 def ambient_traces() -> List[Trace]:
-    return getattr(_tls, "batch_traces", None) or []
+    return _tls.batch_traces or []
 
 
 def cache_tier(exe, compiles_before, disk_loads_before) -> str:
@@ -284,13 +311,15 @@ class span:
     profiler's trace as ``paddle_tpu:<name>`` (reference
     platform/profiler.h:81 RecordEvent, which feeds the reference's
     device tracer the same way). With neither sink it costs one
-    thread-local lookup and one call into the profiler's gate.
+    thread-local lookup and one call into the profiler's gate. Inside
+    an open cycle record (``cycle``) it also adds its length to the
+    record under its name: two clock reads and one dictionary update.
 
     Attributes that cost anything to compute are set late, and only
     for a sink: ``if sp.recording: sp.attrs[...] = ...`` inside the
     block."""
 
-    __slots__ = ("name", "attrs", "_traces", "_t0", "_ann")
+    __slots__ = ("name", "attrs", "_traces", "_cycle", "_t0", "_ann")
 
     def __init__(self, name: str, **attrs):
         self.name = name
@@ -298,12 +327,14 @@ class span:
 
     @property
     def recording(self) -> bool:
-        """True inside the block when some sink takes the span."""
+        """True inside the block when some sink takes the span's
+        attributes (a cycle record takes its length only)."""
         return bool(self._traces) or self._ann is not None
 
     def __enter__(self):
-        traces = self._traces = getattr(_tls, "batch_traces", None)
-        if traces:
+        traces = self._traces = _tls.batch_traces
+        open_cycle = self._cycle = _tls.cycle
+        if traces or open_cycle is not None:
             self._t0 = time.monotonic()
         if _profiling():
             self._ann = TraceAnnotation(SPAN_PREFIX + self.name)
@@ -317,6 +348,12 @@ class span:
             if self.attrs:
                 self._ann.set_metadata(**self.attrs)
             self._ann.__exit__(*exc)
+        open_cycle = self._cycle
+        if open_cycle is not None:
+            t1 = time.monotonic()
+            phases = open_cycle.phases
+            phases[self.name] = phases.get(self.name, 0.0) \
+                + (t1 - self._t0)
         if self._traces:
             t1 = time.monotonic()
             for tr in self._traces:
@@ -349,6 +386,258 @@ class execute_span(span):
             self.attrs["cache"] = cache_tier(self._exe, self._c0,
                                              self._d0)
         return super().__exit__(*exc)
+
+
+# --- the cycle record -------------------------------------------------
+# A ring keeps this many records. A cycle is slow when it takes over
+# SLOW_CYCLE_FACTOR times the median of the ring's cycles with its key,
+# once the ring holds SLOW_CYCLE_MIN of those; every REFRESH_EVERY
+# records the ring works the medians out again. The processor time is
+# read when a cycle closes CPU_READ_S or more after the last reading,
+# and when a slow one closes, for the cycles since the reading before:
+# under the chip machine's kernel (gVisor) one reading of
+# `time.thread_time` or `time.process_time` costs 6-10 microseconds
+# and steps by 10 ms, so a reading a cycle would be most of a short
+# cycle's record and say little. Constants, not flags.
+CYCLE_RING_SIZE = 512
+SLOW_CYCLE_FACTOR = 2.0
+SLOW_CYCLE_MIN = 32
+REFRESH_EVERY = 32
+CPU_READ_S = 0.25
+# the executor's spans of one dispatch: the phases a ring keeps a
+# histogram of unless it is given its own
+EXE_PHASES = ("exe.feed", "exe.lookup", "exe.state", "exe.call",
+              "exe.store", "exe.fetch")
+
+# the collector's runs, process-wide (a collection holds every thread
+# of the interpreter): [seconds, runs, start of the run in progress]
+_gc_seen = [0.0, 0, 0.0]
+_gc_rings = [0]
+# the run in progress in a profile, while one is being taken
+_gc_marker = []
+
+
+def _on_gc(phase, info):
+    """`gc.callbacks` hook, installed while any ring exists: the
+    collector's time for the open records and, while a profile runs,
+    each run as `paddle_tpu:gc`."""
+    if phase == "start":
+        _gc_seen[2] = time.monotonic()
+        if _profiling():
+            ann = TraceAnnotation(SPAN_PREFIX + "gc",
+                                  generation=info["generation"])
+            ann.__enter__()
+            _gc_marker.append(ann)
+    else:
+        _gc_seen[0] += time.monotonic() - _gc_seen[2]
+        _gc_seen[1] += 1
+        if _gc_marker:
+            _gc_marker.pop().__exit__(None, None, None)
+
+
+def _gc_unwatch():
+    _gc_rings[0] -= 1
+    if not _gc_rings[0] and _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+
+
+def _ms(seconds: float) -> float:
+    return round(seconds * 1e3, 4)
+
+
+class CycleRing:
+    """What a loop keeps of its cycles at every flag level: the last
+    CYCLE_RING_SIZE records (`records()`), one fixed-bucket histogram
+    a phase (`phases`: {label: span name}; `wall` and `gc` beside
+    them; `summary()` gives p50/p95/max, `metric_samples` the series),
+    the count of slow cycles, and each slow one as a `slow_cycle`
+    incident in the flight recorder, which is gated on
+    FLAGS_observability."""
+
+    def __init__(self, owner: str = "", phases=None):
+        self.owner = owner
+        labels = dict(phases) if phases is not None \
+            else {name: name for name in EXE_PHASES}
+        self._hist = {label: Histogram() for label in
+                      (*labels, "gc", "wall")}
+        self._observed = [(name, self._hist[label])
+                          for label, name in labels.items()]
+        self._lock = threading.Lock()
+        self._records = collections.deque(maxlen=CYCLE_RING_SIZE)
+        self._medians = {}
+        self._pushed = 0
+        self.slow_cycles = 0
+        # the last processor-time reading: (thread, thread's seconds,
+        # process's seconds, cycles pushed by then, when)
+        self._cpu = None
+        if not _gc_rings[0]:
+            gc.callbacks.append(_on_gc)
+        _gc_rings[0] += 1
+        weakref.finalize(self, _gc_unwatch)
+
+    def _push(self, rec):
+        phases = rec.phases
+        with self._lock:
+            for name, hist in self._observed:
+                seconds = phases.get(name)
+                if seconds is not None:
+                    hist.observe(seconds * 1e3)
+            if rec.gc_runs:
+                self._hist["gc"].observe(rec.gc * 1e3)
+            self._hist["wall"].observe(rec.wall * 1e3)
+            median = self._medians.get(rec.key)
+            self._records.append(rec)
+            self._pushed += 1
+            if not self._pushed % REFRESH_EVERY:
+                walls = collections.defaultdict(list)
+                for r in self._records:
+                    walls[r.key].append(r.wall)
+                self._medians = {
+                    key: statistics.median(v) for key, v in walls.items()
+                    if len(v) >= SLOW_CYCLE_MIN}
+            slow = median is not None \
+                and rec.wall > SLOW_CYCLE_FACTOR * median
+            closed = rec.t0 + rec.wall
+            if slow or self._cpu is None \
+                    or closed - self._cpu[4] >= CPU_READ_S:
+                self._read_cpu(rec, closed)
+            if not slow:
+                return
+            self.slow_cycles += 1
+        if metrics_on():
+            from . import flight  # deferred, as in Trace.finish
+
+            flight.RECORDER.record(
+                {"kind": "slow_cycle", "server": self.owner,
+                 "median_ms": _ms(median), **rec.as_dict()},
+                incident=True)
+
+    def _read_cpu(self, rec, closed):
+        """Give `rec` the processor time of its thread and of the
+        process since the reading before, and the cycles that covers
+        (`cpu_cycles`); nothing where the reading before was another
+        thread's (a restarted scheduler) or there was none."""
+        now = (threading.get_ident(), time.thread_time(),
+               time.process_time(), self._pushed, closed)
+        last, self._cpu = self._cpu, now
+        if last is not None and last[0] == now[0]:
+            rec.thread_cpu = now[1] - last[1]
+            rec.process_cpu = now[2] - last[2]
+            rec.cpu_cycles = now[3] - last[3]
+
+    def records(self) -> List[dict]:
+        """The ring's records, oldest first."""
+        with self._lock:
+            kept = list(self._records)
+        return [rec.as_dict() for rec in kept]
+
+    def summary(self) -> dict:
+        """{label: {"p50", "p95", "max"}} in milliseconds; None where
+        the window saw no such phase."""
+        def r(v):
+            return None if v is None else round(v, 3)
+        return {label: {"p50": r(h.percentile(0.50)),
+                        "p95": r(h.percentile(0.95)), "max": r(h.max)}
+                for label, h in self._hist.items()}
+
+    def metric_samples(self, name: str, labels: dict):
+        return [(name, {**labels, "phase": label}, h)
+                for label, h in self._hist.items()]
+
+    def clear(self):
+        """A new window (`stats(reset=True)`)."""
+        with self._lock:
+            self._records.clear()
+            self._medians = {}
+            self._pushed = 0
+            self._cpu = None
+            self.slow_cycles = 0
+            for h in self._hist.values():
+                h.reset()
+
+
+def current_cycle() -> Optional["cycle"]:
+    """The record open on this thread, for its owner's counts."""
+    return _tls.cycle
+
+
+class cycle:
+    """Context manager that is one cycle's record: while it is open on
+    the thread every `span` that closes there adds its length to
+    `phases` ({span name: seconds}; nested spans each under their own
+    name). The owner sets `key` (a cycle is compared with the ring's
+    cycles of the same key) and its counts in `attrs` before the block
+    ends; `drop()` keeps a cycle that did nothing out of the ring. On
+    exit the record takes its wall time and the collector's runs and
+    goes to `ring`, which gives a record that closes CPU_READ_S after
+    its last reading, and every slow one, the thread's and the
+    process's processor time since then (over `cpu_cycles` cycles). While a profile runs the
+    cycle also ends in a `paddle_tpu:<name>` marker whose metadata is
+    the record: it starts where the cycle ends, covers the ring's work
+    and says in `wall_us` where the cycle began; a span around the
+    whole cycle would make every idle moment an attributed one."""
+
+    __slots__ = ("name", "ring", "attrs", "key", "phases", "t0", "wall",
+                 "thread_cpu", "process_cpu", "cpu_cycles", "gc",
+                 "gc_runs", "_prev", "_dropped")
+
+    def __init__(self, name: str, ring: CycleRing, **attrs):
+        self.name = name
+        self.ring = ring
+        self.attrs = attrs
+        self.key = None
+        self.phases: Dict[str, float] = {}
+        self.cpu_cycles = 0
+        self._dropped = False
+
+    def drop(self):
+        self._dropped = True
+
+    def __enter__(self):
+        self._prev = _tls.cycle
+        _tls.cycle = self
+        self.gc, self.gc_runs = _gc_seen[0], _gc_seen[1]
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc):
+        self.wall = time.monotonic() - self.t0
+        self.gc = _gc_seen[0] - self.gc
+        self.gc_runs = _gc_seen[1] - self.gc_runs
+        _tls.cycle = self._prev
+        if self._dropped:
+            return False
+        if not _profiling():
+            self.ring._push(self)
+            return False
+        # the marker's start is the cycle's end: entered before the
+        # ring's work, its metadata set once the ring has given the
+        # record its processor time
+        with TraceAnnotation(SPAN_PREFIX + self.name) as mark:
+            self.ring._push(self)
+            meta = {k: v if isinstance(v, (int, float)) else str(v)
+                    for k, v in self.attrs.items()}
+            if self.cpu_cycles:
+                meta.update(
+                    cpu_cycles=self.cpu_cycles,
+                    thread_cpu_us=round(self.thread_cpu * 1e6),
+                    process_cpu_us=round(self.process_cpu * 1e6))
+            mark.set_metadata(
+                wall_us=round(self.wall * 1e6), key=str(self.key),
+                gc_us=round(self.gc * 1e6), **meta)
+        return False
+
+    def as_dict(self) -> dict:
+        """JSON-able: the ring's and the flight recorder's entry."""
+        cpu = {"cpu_cycles": self.cpu_cycles,
+               "thread_cpu_ms": _ms(self.thread_cpu),
+               "process_cpu_ms": _ms(self.process_cpu)} \
+            if self.cpu_cycles else {}
+        return {"name": self.name, "key": self.key,
+                "wall_ms": _ms(self.wall),
+                "phases": {n: _ms(v) for n, v in self.phases.items()},
+                **self.attrs, **cpu,
+                "gc_ms": _ms(self.gc), "gc_runs": self.gc_runs}
 
 
 # --- chrome trace dump -------------------------------------------------

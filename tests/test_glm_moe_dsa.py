@@ -220,6 +220,30 @@ def test_experts_counters_count_live_lanes_only(session):
     assert st["moe_hit"] <= st["moe_pairs"]
 
 
+def test_cycle_records_count_the_chunks_and_split_the_retirement(session):
+    """The scheduler's record of every cycle (PR 36): its prefill
+    chunks and positions add up to `pool_stats()`, and a cycle that
+    retired a lane holds the two pieces of `slotpool.retire` that are
+    not the sweep: the probes' readback and the radix tree's share."""
+    records = session["srv"]._cycles.records()
+    st = session["stats"]
+    assert sum(r.get("prefill_chunks", 0) for r in records) \
+        == st["prefill_chunks"] > 0
+    assert sum(r.get("prefill_positions", 0) for r in records) \
+        == st["prefill_tokens"]
+    assert sum(r["retired"] for r in records) == len(session["prompts"])
+    for rec in records:
+        ph = rec["phases"]
+        if rec["retired"]:
+            assert ph["slotpool.retire.probe"] \
+                + ph["slotpool.retire.tree"] \
+                <= ph["slotpool.retire"] + 1e-3
+        else:
+            assert "slotpool.retire.probe" not in ph
+        # a cycle with a chunk is an admitting one to the benchmark
+        assert ("prefill_chunks" in rec) == (rec["key"] != 0)
+
+
 # ---------------------------------------------------------------------
 # the ranks' shares, tied to the model
 # ---------------------------------------------------------------------

@@ -31,6 +31,7 @@ Covers the tentpole and its satellites:
   the queue-wait counter exists at every level.
 """
 import bisect
+import gc
 import json
 import time
 
@@ -718,12 +719,17 @@ SLOTPOOL_SPANS = {"slotpool.wait", "slotpool.plan", "slotpool.admit",
                   "slotpool.feed", "slotpool.dispatch",
                   "slotpool.retire", "slotpool.deliver",
                   "slotpool.submit"}
+# PR 36: the cycle's marker, the collector, and the sub-spans of the
+# two largest host pieces
+CYCLE_SPANS = {"slotpool.cycle", "gc", "slotpool.retire.tree",
+               "exe.state.gather", "exe.state.put"}
 CONTINUOUS_STATS_KEYS = {
     "requests", "completed", "queue_depth", "slots", "slot_occupancy",
     "ticks", "steps_per_tick", "uptime_s", "window_s", "compile_count",
     "cache_hit_count", "disk_load_count", "cache_evict_count",
     "warmed_compiles", "latency_ms", "ttft_ms", "queue_wait_ms",
-    "per_token_ms", "tokens", "retired_per_s", "cancelled",
+    "per_token_ms", "cycle_ms", "slow_cycles", "tokens",
+    "retired_per_s", "cancelled",
     "deadline_expired", "self_attention_routes",
     "cross_attention_routes", "device_telemetry"}
 
@@ -806,6 +812,7 @@ def profiled(tiny):
                         fetch_list=[y], scope=step_scope)
             with profiler.record_event("user_scope"):
                 pass
+            gc.collect()        # a server's ring exists: marked
             srv.start()
             time.sleep(0.05)    # the scheduler finds nothing: it waits
             replies = [srv.submit(p, stream=True) for p in prompts]
@@ -829,7 +836,8 @@ def _inside(inner, outer):
 
 
 class TestProfilerClock:
-    @pytest.mark.parametrize("name", sorted(EXE_SPANS | SLOTPOOL_SPANS))
+    @pytest.mark.parametrize("name", sorted(EXE_SPANS | SLOTPOOL_SPANS
+                                            | CYCLE_SPANS))
     def test_every_span_is_in_the_profile(self, profiled, name):
         assert any(ev[0] == name for ev in profiled["spans"]), sorted(
             {ev[0] for ev in profiled["spans"]})
@@ -852,6 +860,7 @@ class TestProfilerClock:
             (ev for ev in spans if ev[0].startswith("exe.")
              and _inside(ev, first)), key=lambda ev: ev[2])]
         assert order == ["exe.lookup", "exe.feed", "exe.state",
+                         "exe.state.gather", "exe.state.put",
                          "exe.call", "exe.store", "exe.fetch"]
 
     def test_compile_is_inside_the_first_lookup_only(self, profiled):
@@ -877,6 +886,51 @@ class TestProfilerClock:
         assert {"admits", "queue_depth", "tier"} <= set(plans[0][4])
         # two lanes, three requests: the third waited for a lane
         assert sorted(a[4]["wait_us"] for a in admits)[-1] > 0
+
+    def test_a_cycle_is_marked_at_its_end_with_its_record(self, profiled):
+        """One short `slotpool.cycle` event a dispatch, on the
+        scheduler's thread, whose metadata is the record; `wall_us`
+        before it the cycle began, so the interval holds the cycle's
+        one dispatch whole. No span encloses a cycle: idle time under
+        "any span" stays what the phases cover."""
+        spans = profiled["spans"]
+        dispatches = [ev for ev in spans if ev[0] == "slotpool.dispatch"]
+        marks = sorted((ev for ev in spans if ev[0] == "slotpool.cycle"),
+                       key=lambda ev: ev[2])
+        assert len(marks) == len(dispatches) > 0
+        assert {ev[1] for ev in marks} == {dispatches[0][1]}
+        counts = {"wall_us", "gc_us",
+                  "key", "admits", "queue_depth", "tier", "n_steps",
+                  "retired", "delivered", "submitted", "placed_arrays",
+                  "fetched_arrays"}
+        for m in marks:
+            meta = m[4]
+            assert counts <= set(meta), sorted(meta)
+            assert m[3] < meta["wall_us"] * 1e3      # a mark, no cover
+            held = [d for d in dispatches
+                    if m[2] - meta["wall_us"] * 1e3 <= d[2]
+                    and d[2] + d[3] <= m[2]]
+            assert len(held) == 1, (m, held)
+            assert held[0][4]["admits"] == meta["admits"]
+            assert meta["fetched_arrays"] >= 4
+        # a marker with a processor-time reading carries all of it
+        # (tests/test_cycle_record.py holds when one is taken)
+        for meta in (m[4] for m in marks if "cpu_cycles" in m[4]):
+            assert {"thread_cpu_us", "process_cpu_us"} <= set(meta)
+        assert sum(m[4]["admits"] for m in marks) == 3
+        assert sum(m[4]["retired"] for m in marks) == 3
+        assert sum(m[4]["delivered"] for m in marks) > 0
+
+    def test_the_sub_spans_nest_under_their_phase(self, profiled):
+        spans = profiled["spans"]
+        for inner, outer in (("exe.state.gather", "exe.state"),
+                             ("exe.state.put", "exe.state"),
+                             ("slotpool.retire.tree", "slotpool.retire")):
+            outers = [ev for ev in spans if ev[0] == outer]
+            for ev in (ev for ev in spans if ev[0] == inner):
+                assert any(_inside(ev, o) for o in outers), (inner, ev)
+        collections = [ev for ev in spans if ev[0] == "gc"]
+        assert any(ev[4].get("generation") == 2 for ev in collections)
 
     def test_submit_runs_on_the_callers_thread(self, profiled):
         spans = profiled["spans"]
