@@ -569,8 +569,9 @@ def _pin_state_layout_formats(fn, state_ex, const_ex, feeds_ex, rng_ex,
             # jax array: keep the layout it already has
             return Format(f.layout, on_dev)
         # host value (a numpy feed, which the executable places
-        # itself; a host-written block table, device_put every
-        # dispatch): it arrives in the device's DEFAULT
+        # itself: a server's block table among them; a host-written
+        # scope variable, device_put by the dispatch that finds it):
+        # it arrives in the device's DEFAULT
         # layout for its shape -- on the TPU not row-major for small
         # minor dims (an int32[9,3] table is (1,0)-major, tiled), so
         # no layout may be forced on it
@@ -673,7 +674,10 @@ class _Transfers:
     arrays of a few bytes to a few KB, so a transfer costs its call
     and its round trip, not its bytes: what has to be placed before
     the call (a scope's host-written variables; the data-parallel
-    step's feeds) goes up in one `jax.device_put` a dispatch each,
+    step's feeds) goes up in one `jax.device_put` a dispatch each
+    (a server's scheduler writes none: its tables are feeds of the
+    dispatch, which the executable takes up itself, so a serve cycle
+    places nothing and fetches one array, the bundle's packed row),
     and every fetch's copy to the host is queued behind the
     computation when the call returns, so the host waits for the
     device once and not once a fetch."""
@@ -755,10 +759,10 @@ def _stage_feeds(feed, block, placement, transfers, check=None,
 def _scope_state(scope, groups, placement, transfers):
     """Gather scope values: one dict for each list of names in
     `groups`, each value where `placement` (see _Placement) wants it.
-    What a single-device program's scope holds as host arrays (a
-    server's scheduler writes its tables so before every dispatch)
-    goes to the device in one transfer for all groups, and back into
-    the scope placed."""
+    What a single-device program's scope holds as host arrays (what
+    `init_slot_state` seeds before a server's first dispatch; a
+    caller's own writes) goes to the device in one transfer for all
+    groups, and back into the scope placed."""
     device, mesh, rule = placement.device, placement.mesh, placement.rule
     outs, going = [], []
     with _span("exe.state.gather"):
@@ -1815,8 +1819,8 @@ class Executor:
         byte-stable layout and prepared handles never re-specialize
         mid-traffic (the zero-steady-state-compiles contract); feeds
         and the rng are replicated on the mesh (numpy feeds are
-        device_put per call by the dispatch path — host-written
-        block tables stay plain numpy on the host side)."""
+        device_put per call by the dispatch path — a server's fed
+        block tables among them stay plain numpy on the host side)."""
         from .sharding_plan import plan_of
 
         plan = plan_of(program)

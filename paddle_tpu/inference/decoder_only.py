@@ -37,7 +37,8 @@ import time
 import jax
 import numpy as np
 
-from ..models.decode_engine import BlockKeys, BlockPoolExhausted
+from ..models.decode_engine import (BlockKeys, BlockPoolExhausted,
+                                    fed_name)
 from ..observability import tracing as obs_tracing
 from .serving import (GenerationReply, PagedContinuousGenerationServer,
                       ServerClosed, ServerQuiesced, StreamingReply,
@@ -86,6 +87,9 @@ class DecoderOnlyPagedServer(PagedContinuousGenerationServer):
             0)
         # per-lane state beside the paged cache: no prefix reuse
         self._lane_state = bundle.lane_state
+        # the experts' counters, as they end every dispatch's row
+        self._moe_keys = list(bundle.moe_keys)
+        self._moe_read = {k: 0 for k in self._moe_keys}
         kwargs.pop("radix_reuse", None)
         kwargs.pop("chunked_prefill", None)
         super().__init__(bundle, radix_reuse=True, chunked_prefill=False,
@@ -94,15 +98,6 @@ class DecoderOnlyPagedServer(PagedContinuousGenerationServer):
             self._tab = np.zeros((bundle.n_slots + 1, self._ctx_pages),
                                  np.int32)
         self._topk = bundle.selection_size or bundle.context
-
-    def _extra_fetch_names(self):
-        # the experts' counters ride every dispatch: a scope read from
-        # another thread would find the state given to a running step
-        names = self.bundle.state
-        self._moe_keys = ["moe_pairs", "moe_hit"] + sorted(
-            k for k in names if k.startswith("moe_load"))
-        self._moe_read = {k: 0 for k in self._moe_keys}
-        return [names[k] for k in self._moe_keys]
 
     # --- request path -------------------------------------------------
     def submit(self, src_ids, max_new_tokens=None, cache_tokens=None,
@@ -327,16 +322,17 @@ class DecoderOnlyPagedServer(PagedContinuousGenerationServer):
         return None
 
     def _pre_dispatch(self):
-        names = self.bundle.state
-        self.scope._set(names["block_tab"], self._tab.copy())
+        """The block table and the lane mask, as feeds of the dispatch
+        (the bundle's `fed_tables`)."""
         act = np.zeros((self.n_slots + 1,), np.int64)
         for s in range(self.n_slots):
             if self._lanes[s] is not None and s not in self._filling:
                 act[s] = 1
         # lanes whose prompt is still filling read 0 here; the
         # admission body raises the ones this dispatch finishes
-        self.scope._set(names["active"], act)
         self._harvest_ok = False
+        return {fed_name("block_tab"): self._tab.copy(),
+                fed_name("active"): act}
 
     def _post_dispatch(self, outs):
         step = np.asarray(outs[1]).astype(np.int64)

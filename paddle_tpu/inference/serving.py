@@ -71,7 +71,8 @@ from ..models.decode_engine import (AdmissionInfeasible,
                                     BlockLifetimeError,
                                     BlockPoolExhausted, HostBlockPool,
                                     PromptPrefixCache, RadixBlockTree,
-                                    ServingUnavailable)
+                                    SPEC_COUNTERS, SPEC_LANE_COUNTERS,
+                                    ServingUnavailable, fed_name)
 from ..observability import costmodel as obs_costmodel
 from ..observability import devtel as obs_devtel
 from ..observability import metrics as obs_metrics
@@ -1115,6 +1116,24 @@ class _GenRequest:
         self.n_streamed = 0
 
 
+class _PackedServe:
+    """A serve program's prepared handle, fetched for its bundle's
+    packed row alone (decode_engine.ServeRow): `run` hands the row back
+    cut into the list a fetch of the row's names would have given
+    (token rows first), as views of the one host buffer; everything
+    else is the prepared handle's."""
+
+    def __init__(self, prepared, row):
+        self._prepared, self._row = prepared, row
+
+    def run(self, feed, return_numpy: bool = True):
+        return self._row.cut(
+            self._prepared.run(feed, return_numpy=return_numpy)[0])
+
+    def __getattr__(self, name):
+        return getattr(self._prepared, name)
+
+
 class ContinuousGenerationServer:
     """Continuous-batching generation over a fixed slot pool
     (iteration-level scheduling: Orca, Yu et al. OSDI'22; slot-based
@@ -1247,10 +1266,7 @@ class ContinuousGenerationServer:
         self._spec_k = int(getattr(bundle, "spec_k", 0))
         self._toks_per_tick = int(getattr(bundle, "tokens_per_tick",
                                           1))
-        self._spec_names = [
-            bundle.state[c] for c in
-            ("spec_proposed", "spec_accepted", "spec_emitted",
-             "spec_draft_steps", "spec_target_steps")] \
+        self._spec_names = [bundle.state[c] for c in SPEC_COUNTERS] \
             if self._spec_k > 0 else []
         self._spec_tot = dict.fromkeys(
             ("proposed", "accepted", "emitted", "draft_steps",
@@ -1261,8 +1277,7 @@ class ContinuousGenerationServer:
         # variants — pure program selection, zero steady-state
         # compiles (inference/spec_controller.py)
         self._lane_names = [
-            bundle.state[c] for c in
-            ("spec_lane_accepted", "spec_lane_ticks")
+            bundle.state[c] for c in SPEC_LANE_COUNTERS
             if c in getattr(bundle, "state", {})] \
             if self._spec_k > 0 else []
         self._lane_tot = [None] * len(self._lane_names)
@@ -1320,21 +1335,31 @@ class ContinuousGenerationServer:
 
         # bind the prepared handles up front (= AOT warmup: all
         # compiles happen HERE, none in the traffic window): one fused
-        # serve program per admission flavor x bucket (0 = tick-only)
+        # serve program per admission flavor x bucket (0 = tick-only).
+        # Every one is fetched for the bundle's packed row alone
+        # (decode_engine.ServeRow) and hands it back cut into `outs`
+        # (_PackedServe): token rows, step, active, finished, the
+        # speculative counters, the telemetry counters, then what the
+        # bundle adds (a decoder-only bundle's expert counters)
         before = self.executor.compile_count
+        self._row = bundle.serve_row
         st = bundle.state
-        self._fetches = [st["tok_buf"], st["step"], st["active"],
-                         st["finished"]] + self._spec_names \
-            + self._lane_names + self._devtel.fetch_names \
-            + self._extra_fetch_names()
+        read = [st["tok_buf"], st["step"], st["active"],
+                st["finished"]] + self._spec_names \
+            + self._lane_names + self._devtel.fetch_names
+        if list(self._row.names[:len(read)]) != read:
+            raise ValueError(
+                f"the bundle's serve row {self._row.names} does not "
+                f"start with what this scheduler reads: {read}")
+        self._fetches = [self._row.name]
         self._serves = {}
         for key, prog in sorted(bundle.serves.items(),
                                 key=lambda kv: str(kv[0])):
             if self._skip_serve_key(key):
                 continue
-            self._serves[key] = self.executor.prepare(
+            self._serves[key] = _PackedServe(self.executor.prepare(
                 prog, feed=bundle.serve_feed_spec(key),
-                fetch_list=self._fetches, scope=self.scope)
+                fetch_list=self._fetches, scope=self.scope), self._row)
         self._admit_buckets = sorted(
             {k for k in self._serves if isinstance(k, int) and k > 0}
             | {k[1] for k in self._serves if isinstance(k, tuple)
@@ -1912,14 +1937,11 @@ class ContinuousGenerationServer:
                 + [0] * (A - len(admits)), np.int64)
         return A, feed
 
-    def _extra_fetch_names(self) -> List[str]:
-        """Hook: state a scheduler wants back from every dispatch
-        behind the base fetches (they end `outs`)."""
-        return []
-
-    def _pre_dispatch(self):
-        """Hook: publish host-owned state (paged block tables) just
-        before the fused dispatch."""
+    def _pre_dispatch(self) -> Dict[str, np.ndarray]:
+        """Hook: the host-owned tables this dispatch is fed (the paged
+        schedulers' block table, prompt references and lane mask: the
+        bundle's `fed_tables`), read just before the fused dispatch."""
+        return {}
 
     def _post_dispatch(self, outs):
         """Hook: absorb fetched state (paged per-lane step counters)
@@ -2090,7 +2112,7 @@ class ContinuousGenerationServer:
                         and ("k", kv, key) in self._serves:
                     key = ("k", kv, key)
                     k_used = kv
-            self._pre_dispatch()
+            feed.update(self._pre_dispatch())
             if rec is not None:
                 rec.key = key
                 rec.attrs["n_steps"] = n_steps
@@ -3568,12 +3590,12 @@ class PagedContinuousGenerationServer(ContinuousGenerationServer):
         return n_steps, min_active, True
 
     def _pre_dispatch(self):
-        """Publish the host-owned indirection + the pause/victim mask
-        just before the fused dispatch (prepared handles re-read scope
-        state per call, so this is the whole host->device channel)."""
-        names = self.bundle.state
-        self.scope._set(names["block_tab"], self._tab.copy())
-        self.scope._set(names["prompt_ref"], self._pref.copy())
+        """The host-owned indirection + the pause/victim mask, as feeds
+        of the fused dispatch: the whole host->device channel beside
+        the admission feeds (the executable takes host feeds up itself
+        inside the call, so nothing is set into the scope and nothing
+        is placed from Python; the program copies the mask into its
+        `active` state, which it goes on to write)."""
         act = np.zeros((self.n_slots + 1,), np.int64)
         for s in range(self.n_slots):
             if self._lanes[s] is not None and s not in self._paused:
@@ -3582,8 +3604,10 @@ class PagedContinuousGenerationServer(ContinuousGenerationServer):
         # exclusivity contract); retired/victim/idle lanes likewise;
         # freshly admitted lanes are raised by the admission body
         # inside the same dispatch either way
-        self.scope._set(names["active"], act)
         self._harvest_ok = False  # until this dispatch's outs land
+        return {fed_name("block_tab"): self._tab.copy(),
+                fed_name("prompt_ref"): self._pref.copy(),
+                fed_name("active"): act}
 
     def _post_dispatch(self, outs):
         self._lane_step = np.asarray(outs[1]).astype(np.int64).copy()
@@ -3976,12 +4000,16 @@ class DisaggregatedPrefillWorker:
         the entry content is bit-identical to it."""
         C = self.bundle.cache.chunk_tokens
         src = np.asarray(req.src).reshape(-1)
+        # no lane decodes on this scope: the tables every serve
+        # program is fed say so
+        idle = self.bundle.idle_table_feed()
         for key in self._chunk_keys:
             for ci in range(self._n_chunks):
                 feed = {"n_steps": np.array([0], np.int64),
                         "min_active": np.array([0], np.int64),
                         "chunk_entry": np.array([entry], np.int64),
-                        "chunk_pos": np.array([ci * C], np.int64)}
+                        "chunk_pos": np.array([ci * C], np.int64),
+                        **idle}
                 if key[1] == 0:
                     toks = np.zeros((1, C), np.int64)
                     seg = src[ci * C: ci * C + C]
@@ -4105,15 +4133,21 @@ class PagedBeamDecoder:
 
     def _admit(self, arr, tab, pref):
         st, scope = self._st, self.scope
+        # the probe and COW programs read the tables as scope state;
+        # the serve programs that admit are fed them (no tick runs
+        # here, so the mask they are fed is all down)
         scope._set(st["block_tab"], tab.copy())
         scope._set(st["prompt_ref"], pref.copy())
         zero = np.array([0], np.int64)
+        tables = {**self.bundle.idle_table_feed(),
+                  fed_name("block_tab"): tab.copy(),
+                  fed_name("prompt_ref"): pref.copy()}
         A = self._miss_A
         feed = {"src_ids": np.repeat(arr, A, axis=0),
                 "slots": np.full((A,), self.bundle.dustbin, np.int64),
                 "prompt_slots": np.full(
                     (A,), self.cache.n_prompt_entries, np.int64),
-                "n_steps": zero, "min_active": zero}
+                "n_steps": zero, "min_active": zero, **tables}
         feed["slots"][0] = 0
         feed["prompt_slots"][0] = 0
         if getattr(self.bundle, "needs_seeds", False):
@@ -4124,7 +4158,7 @@ class PagedBeamDecoder:
             slots = np.full((A,), self.bundle.dustbin, np.int64)
             slots[:self.beam - 1] = np.arange(1, self.beam)
             feed = {"slots": slots, "n_steps": zero,
-                    "min_active": zero}
+                    "min_active": zero, **tables}
             if getattr(self.bundle, "needs_seeds", False):
                 feed["seeds"] = np.zeros((A,), np.int64)
             self._hit.run(feed, return_numpy=True)

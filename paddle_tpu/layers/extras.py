@@ -9,6 +9,8 @@ layers/nn.py dynamic_lstmp.
 """
 from __future__ import annotations
 
+import numpy as np
+
 from ..layer_helper import LayerHelper
 from .sequence import SEQ_LEN_SUFFIX, seq_len_of
 
@@ -694,3 +696,19 @@ def lane_probe_write(hist, new, gate, step=None, name=None):
 
 
 __all__.append("lane_probe_write")
+
+
+def pack_row(xs, name, dtype="int64"):
+    """The integer variables `xs` laid end to end, each flattened, as
+    the one flat variable `name` (op pack_row): a serve program's fetch."""
+    helper = LayerHelper("pack_row", input=xs[0], name=name)
+    size = sum(int(np.prod(x.shape)) for x in xs)
+    out = helper.main_program.current_block().create_var(
+        name=name, shape=(size,), dtype=dtype, stop_gradient=True)
+    helper.append_op("pack_row", {"X": list(xs)}, {"Out": out},
+                     {"dtype": dtype})
+    return out
+
+
+__all__.append("pack_row")
+

@@ -43,6 +43,13 @@ Host-side allocation policy (``HostBlockPool``, ``PromptPrefixCache``)
 also lives here: the device only ever sees fed/persistable tables, so
 blocks/refcounts/prefix hashing stay plain testable Python in the
 serving scheduler (inference/serving.py).
+
+The host boundary of a serve dispatch carries one array each way
+(``build_serve_program``): the scheduler's tables (block table, prompt
+references, the lane mask: a bundle's ``fed_tables``) ride the call as
+feeds beside ``n_steps`` and the admission feeds, and what the
+scheduler reads back (token rows, lane counters, telemetry) comes home
+as one packed row (``ServeRow``).
 """
 from __future__ import annotations
 
@@ -74,6 +81,103 @@ POOL_MARK = "@POOL"
 # proven-divergent automatically (absint.mark_divergence_source
 # axes= semantics).
 LANE_AXIS = "lanes"
+
+
+def fed_name(table: str) -> str:
+    """The feed that carries the scheduler's table `table` (a logical
+    state name: 'block_tab', 'prompt_ref', 'active') into a serve
+    dispatch."""
+    return f"fed_{table}"
+
+
+class ServeRow:
+    """What a serve dispatch hands back to its scheduler, as ONE flat
+    array: the state variables `names` (token rows, lane counters, the
+    speculative and telemetry counters, a bundle's extras), each
+    flattened, end to end in that order in the variable `name`
+    (build_serve_program packs it on the device after the burst; a few
+    tens of KB). `cut(row)` gives the list a fetch of `names` would
+    have given, as views of the one host buffer. One row holds one
+    dtype, and everything a scheduler reads back today is int64; a
+    bundle that ever fetches another dtype gets a second row."""
+
+    __slots__ = ("name", "names", "shapes", "dtype", "size", "_cuts")
+
+    def __init__(self, name, names, specs, dtype="int64"):
+        self.name = name
+        self.names = tuple(names)
+        self.dtype = dtype
+        self.shapes, self._cuts, at = [], [], 0
+        for n in self.names:
+            shape, dt = specs[n]
+            if dt != dtype:
+                raise ValueError(
+                    f"serve row {name!r} holds {dtype}; {n!r} is {dt} "
+                    f"-- give it a row of its own")
+            size = int(np.prod(shape))
+            self.shapes.append(tuple(shape))
+            self._cuts.append((at, at + size))
+            at += size
+        self.size = at
+
+    def cut(self, row) -> list:
+        row = np.asarray(row)
+        return [row[a:b].reshape(shape)
+                for (a, b), shape in zip(self._cuts, self.shapes)]
+
+
+class _ServeBoundary:
+    """What a slot-pool bundle says of a serve dispatch's host
+    boundary (both bundle kinds): `fed_tables`, the logical names of
+    the scheduler-owned tables every serve program takes as feeds
+    (`fed_name`), and `serve_row`, the packed row every serve program
+    ends in."""
+
+    fed_tables: tuple = ()
+    serve_row: Optional[ServeRow] = None
+
+    def table_feed_spec(self) -> List[tuple]:
+        """(name, shape, dtype) of the fed tables: the tail of every
+        key's `serve_feed_spec`."""
+        return [(fed_name(t), *self._state_specs[self.state[t]])
+                for t in self.fed_tables]
+
+    def idle_table_feed(self) -> Dict[str, np.ndarray]:
+        """The fed tables of a dispatch no lane decodes in (what
+        `init_slot_state` leaves in a scope): no block, the dustbin
+        prompt entry, every lane down."""
+        feed = {}
+        for t in self.fed_tables:
+            shape, dt = self._state_specs[self.state[t]]
+            fill = self.cache.n_prompt_entries if t == "prompt_ref" \
+                else 0
+            feed[fed_name(t)] = np.full(shape, fill, dt)
+        return feed
+
+
+# the speculative counters a scheduler reads back every dispatch, in
+# the row's order: the five [1] totals, then the two per-lane ones
+SPEC_COUNTERS = ("spec_proposed", "spec_accepted", "spec_emitted",
+                 "spec_draft_steps", "spec_target_steps")
+SPEC_LANE_COUNTERS = ("spec_lane_accepted", "spec_lane_ticks")
+
+
+def serve_row_of(state_prefix, state, specs, cache, extra=()) -> ServeRow:
+    """The packed row of a bundle whose state map is `state`: the token
+    rows and the three lane vectors, the speculative counters where the
+    bundle speculates, the telemetry counters (DeviceTelemetry's own
+    list and order), then the bundle's `extra` logical names (a
+    decoder-only bundle's expert counters). The ONE place the order of
+    a serve dispatch's `outs` is decided; the servers index it."""
+    from types import SimpleNamespace
+
+    names = [state[k] for k in ("tok_buf", "step", "active", "finished")]
+    names += [state[c] for c in SPEC_COUNTERS + SPEC_LANE_COUNTERS
+              if c in state]
+    names += devtel.DeviceTelemetry(
+        SimpleNamespace(cache=cache, state=state)).fetch_names
+    names += [state[k] for k in extra]
+    return ServeRow(f"{state_prefix}serve_row", names, specs)
 
 
 @dataclass(frozen=True)
@@ -1031,7 +1135,7 @@ def build_incremental_decode_program(seq_len=16, max_out_len=16,
 # ---------------------------------------------------------------------------
 # Slot-pool front: bucketed admission + single-step/burst programs.
 # ---------------------------------------------------------------------------
-class DecodeStepBundle:
+class DecodeStepBundle(_ServeBoundary):
     """Program set for slot-pool continuous batching (reference
     tests/unittests/dist_transformer.py:1498 fast_decode is the decode
     loop; the slot-pool scheduling follows the iteration-level /
@@ -1052,10 +1156,12 @@ class DecodeStepBundle:
     per-lane buffers, or ``paged`` shared block pools + per-lane
     block-table/prompt-entry indirection (module docstring). Under
     the paged layout the block table and prompt-entry references are
-    HOST-owned read-only state: the serving scheduler allocates
-    blocks/entries (HostBlockPool/PromptPrefixCache) and writes the
-    tables into the scope between dispatches — the device programs
-    never mutate them.
+    HOST-owned and read-only: the serving scheduler allocates
+    blocks/entries (HostBlockPool/PromptPrefixCache) and FEEDS the
+    tables to every serve dispatch, with the lane mask it decides
+    (``fed_tables``; the unfused programs below still read all three
+    as scope state, which whoever drives them writes) — the device
+    programs never mutate the two tables.
 
     * ``prefills[A]`` — one admission program per bucket size A
       (power-of-two ladder up to n_slots): feeds ``src_ids`` [A,
@@ -1076,7 +1182,9 @@ class DecodeStepBundle:
       count drops to ``min_active`` (both fed as [1] int64). Keys are
       admission buckets (ints) for dense bundles and ``("hit"|"miss",
       A)`` tuples (plus 0) for paged ones; ``serve_feed_spec(key)``
-      names each program's feed signature. Chunked-prefill bundles
+      names each program's feed signature (the paged bundles' ends in
+      the fed tables), and every one ends by packing ``serve_row``,
+      the one array a scheduler fetches. Chunked-prefill bundles
       (``cache.chunk_tokens > 0``) additionally carry ``("chunked",
       p)`` programs — phase p of the incremental encoder over ONE
       prompt chunk, fused with the same decode While so live lanes
@@ -1095,7 +1203,10 @@ class DecodeStepBundle:
     def __init__(self, prefills, step, serves, startup, state,
                  n_slots, seq_len, max_out_len, start_id, end_id,
                  cache=None, hit_prefills=None, sampling=None,
-                 draft=None, cow=None, probe=None):
+                 draft=None, cow=None, probe=None, fed_tables=(),
+                 serve_row=None):
+        self.fed_tables = tuple(fed_tables)
+        self.serve_row = serve_row
         self.prefills = dict(prefills)   # bucket size A -> Program
         self.prefill = self.prefills[min(self.prefills)]
         self.hit_prefills = dict(hit_prefills or {})
@@ -1201,9 +1312,11 @@ class DecodeStepBundle:
 
     def serve_feed_spec(self, key) -> List[tuple]:
         """Feed signature (name, shape, dtype) of ``serves[key]`` —
-        the serving layer binds prepared handles from this."""
+        the serving layer binds prepared handles from this: the
+        key's admission feeds, the burst's two, then the scheduler's
+        tables (``fed_tables``), which every key takes."""
         feed = [("n_steps", (1,), "int64"),
-                ("min_active", (1,), "int64")]
+                ("min_active", (1,), "int64")] + self.table_feed_spec()
         if isinstance(key, tuple) and key and key[0] == "k":
             # adaptive-k variant: same admission body, same slot
             # state, same feeds — only the burst's draft length
@@ -1282,7 +1395,7 @@ class DecodeStepBundle:
                 scope._set(name, np.zeros(shape, dt))
 
 
-class DecoderOnlyStepBundle:
+class DecoderOnlyStepBundle(_ServeBoundary):
     """Program set of a DECODER-ONLY model on the slot pool (the
     builder in models/glm_moe_dsa.py): no encoder, no cross-attention
     prompt table. A request's prompt goes into its lane's OWN paged
@@ -1290,8 +1403,10 @@ class DecoderOnlyStepBundle:
     prefix cache. What the servers read of a DecodeStepBundle is here
     under the same names (`serves`, `state`, `n_slots`, `dustbin`,
     `max_out_len`, `end_id`, `cache`, `init_slot_state`,
-    `serve_feed_spec`); `decoder_only` tells
-    PagedContinuousGenerationServer to plan by prompt length.
+    `serve_feed_spec`, `fed_tables`: the block table and the lane
+    mask, `serve_row`: whose tail is the experts' counters);
+    `decoder_only` tells PagedContinuousGenerationServer to plan by
+    prompt length.
 
     * ``serves[0]`` — the tick-only program: a While of decode ticks,
       each advancing every live lane one token.
@@ -1314,6 +1429,7 @@ class DecoderOnlyStepBundle:
     most positions (prompt and reply) a lane's table can address."""
 
     decoder_only = True
+    fed_tables = ("block_tab", "active")
     PREFILL = ("prefill", 0)    # the serve key of the prefill program
     seq_len = None              # prompts come in their own length
     spec_k = 0
@@ -1325,8 +1441,10 @@ class DecoderOnlyStepBundle:
 
     def __init__(self, serves, startup, state, state_specs, n_slots,
                  max_out_len, context, end_id, cache, chunk_sizes,
-                 max_chunks, probes=None, selection_size=0,
+                 max_chunks, serve_row, probes=None, selection_size=0,
                  lane_state=()):
+        self.serve_row = serve_row
+        self.moe_keys = self.moe_keys_of(state)
         self.serves = dict(serves)
         self.startup = startup
         self.state = dict(state)
@@ -1352,6 +1470,15 @@ class DecoderOnlyStepBundle:
         # not where the state at the prefix's end exists nowhere
         self.lane_state = self._lane_state_of(lane_state)
 
+    @staticmethod
+    def moe_keys_of(state) -> tuple:
+        """The experts' counters of the state map `state` (logical
+        names), as they end the serve row: they ride every dispatch,
+        because a scope read from another thread would find the state
+        given to a running step."""
+        return ("moe_pairs", "moe_hit") + tuple(sorted(
+            k for k in state if k.startswith("moe_load")))
+
     def _lane_state_of(self, names):
         """{"names", "shapes" (a lane's), "bytes_per_lane"} of the
         state vars `names` ([rows, ...] each), or None."""
@@ -1376,7 +1503,7 @@ class DecoderOnlyStepBundle:
 
     def serve_feed_spec(self, key) -> List[tuple]:
         feed = [("n_steps", (1,), "int64"),
-                ("min_active", (1,), "int64")]
+                ("min_active", (1,), "int64")] + self.table_feed_spec()
         if key == 0:
             return feed
         a = self.max_chunks
@@ -2041,23 +2168,51 @@ def emit_lane_tokens(tok, tok_buf, stepv, fin, act, rows, maxT, end_id,
     layers.assign(new_fin, output=fin)
 
 
-def build_serve_program(specs, state_prefix, pre_body, step_body,
-                        mark=None):
+def build_serve_program(specs, state_prefix, pre_body, step_body, row,
+                        mark=None, fed=()):
     """One fused scheduler-cycle program of a slot-pool bundle: the
     slot state declared, `pre_body(sv)` (an admission, a prefill chunk,
     or nothing), then a While that runs `step_body(sv)` until `n_steps`
     ticks ran or the live-lane count drops to `min_active` (both fed as
-    [1] int64), then the burst's exit reason counted once. `mark(sv)`
-    annotates the declared state (ownership sources). Every serve
+    [1] int64), then the burst's exit reason counted once, then the
+    state a scheduler reads back packed into the one variable
+    `row.name` (a ServeRow: the program's only fetch). Every serve
     program of every bundle has this shape, so the servers drive them
-    alike."""
+    alike, and a dispatch crosses the host boundary with one array each
+    way beside its admission feeds.
+
+    `fed` names the scheduler-owned tables of `specs` (logical names:
+    'block_tab', 'prompt_ref', 'active') that ride the call as the
+    feeds `fed_name(table)`: the program copies each into its state
+    variable before `pre_body`, so the bodies and the While read (and,
+    the lane mask, write: an admission raises a lane, a finished lane
+    drops) the state variables they always did, the scope holds after
+    a dispatch what the scheduler fed it, and no program reads a table
+    from the scope that a scheduler would have to place there.
+    `mark(sv)` annotates the declared state (ownership sources); of a
+    fed table it annotates the feed, the host-owned source now, and the
+    state variable's provenance is derived through the copy. The lane
+    mask alone keeps its mark on the state variable too: the program
+    rewrites it, and the pin is what holds through that."""
     import paddle_tpu as fluid
 
     prog = fluid.Program()
     with fluid.program_guard(prog, fluid.Program()):
         sv = _declare_slot_state(prog.global_block, specs)
+        fed_vars = {}
+        for table in fed:
+            name = f"{state_prefix}{table}"
+            shape, dt = specs[name]
+            fed_vars[name] = layers.data(
+                fed_name(table), shape=list(shape), dtype=dt,
+                append_batch_size=False)
         if mark is not None:
-            sv = mark(sv)
+            mark({**sv, **fed_vars})
+        for name, var in fed_vars.items():
+            layers.assign(var, output=sv[name])
+        mask = f"{state_prefix}active"
+        if mask in fed_vars:
+            absint.mark_pool_index_source(sv[mask], "lane_active")
         pre_body(sv)
         n_steps = layers.data("n_steps", shape=[1], dtype="int64",
                               append_batch_size=False)
@@ -2124,6 +2279,7 @@ def build_serve_program(specs, state_prefix, pre_body, step_body,
                 layers.elementwise_mul(
                     not_ran,
                     layers.elementwise_sub(one, idle)))
+        layers.pack_row([sv[n] for n in row.names], row.name, row.dtype)
     return prog
 
 
@@ -2338,16 +2494,19 @@ def build_decoder_only_bundle(stack, layer_specs, *, state_prefix, vocab,
         tel_add(sv, p, "tel_admit_miss",
                 layers.reduce_sum(admitted, keep_dim=True))
 
-    serves = {0: build_serve_program(specs, p, lambda sv: None, tick_body,
-                                     mark=mark)}
-    serves[DecoderOnlyStepBundle.PREFILL] = build_serve_program(
-        specs, p, prefill_body, tick_body, mark=mark)
     state = {k: f"{p}{k}" for k in
              ("tok_buf", "step", "finished", "active", "base", "limit",
               "block_tab", "moe_pairs", "moe_hit")}
     state.update(devtel.state_entries(p, True, chunked=True))
     state.update({f"moe_load{li}": f"{p}moe_load{li}"
                   for li in moe_layers})
+    row = serve_row_of(p, state, specs, cache,
+                       extra=DecoderOnlyStepBundle.moe_keys_of(state))
+    fed = DecoderOnlyStepBundle.fed_tables
+    serves = {0: build_serve_program(specs, p, lambda sv: None, tick_body,
+                                     row, mark=mark, fed=fed)}
+    serves[DecoderOnlyStepBundle.PREFILL] = build_serve_program(
+        specs, p, prefill_body, tick_body, row, mark=mark, fed=fed)
     probes = {"selected": selected_probes,
               "chosen": {li: f"{p}chosen_hist{li}" for li in moe_layers}}
     if probe_logits:
@@ -2356,7 +2515,7 @@ def build_decoder_only_bundle(stack, layer_specs, *, state_prefix, vocab,
         probes["top_logit"] = f"{p}top_logit_hist"
     return DecoderOnlyStepBundle(
         serves, fluid.Program(), state, specs, n_slots, maxT, context,
-        end_id, cache, chunk_sizes, max_chunks, probes=probes,
+        end_id, cache, chunk_sizes, max_chunks, row, probes=probes,
         selection_size=selection_size, lane_state=lane_state)
 
 
@@ -3369,6 +3528,33 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
         body(_mark_ownership(
             _declare_slot_state(step_prog.global_block, specs)))
 
+    # --- the logical -> scope-name map of the slot state, and from it
+    # what every serve program hands back (the packed row) and takes
+    # in as feeds (the scheduler's tables) ---------------------------
+    state = {"tok_buf": f"{state_prefix}tok_buf",
+             "step": f"{state_prefix}step",
+             "finished": f"{state_prefix}finished",
+             "active": f"{state_prefix}active"}
+    if paged:
+        state["block_tab"] = f"{state_prefix}block_tab"
+        state["prompt_ref"] = f"{state_prefix}prompt_ref"
+        state["prefill_until"] = f"{state_prefix}prefill_until"
+        if not spec:
+            state["probe_probs"] = f"{state_prefix}probe_probs"
+    if needs_seeds:
+        state["seed"] = f"{state_prefix}seed"
+    if spec:
+        for c in SPEC_COUNTERS + SPEC_LANE_COUNTERS:
+            state[c] = f"{state_prefix}{c}"
+        if draft.k_options:
+            state.update(devtel.spec_k_state_entries(
+                state_prefix, draft.k_options))
+    # devtel counters join the state map (and therefore the PTA150
+    # counter-presence sweep) under their logical names
+    state.update(devtel.state_entries(state_prefix, paged))
+    fed_tables = ("block_tab", "prompt_ref", "active") if paged else ()
+    row = serve_row_of(state_prefix, state, specs, cache)
+
     # --- fused serve programs: [admission +] a decode-burst While —
     # a WHOLE scheduler cycle (admit + burst) is ONE dispatch, so the
     # host overhead amortizes over A admissions and a burst of tokens
@@ -3394,8 +3580,8 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
         # executables built up front, zero steady-state compiles)
         return build_serve_program(
             specs, state_prefix, pre_body,
-            body if step_body is None else step_body,
-            mark=_mark_ownership)
+            body if step_body is None else step_body, row,
+            mark=_mark_ownership, fed=fed_tables)
 
     # --- chunked-prefill phase bodies (cache.chunk_tokens > 0): the
     # miss admission's encoder, re-cut into resumable C-token ticks.
@@ -3660,35 +3846,13 @@ def build_decode_step_program(seq_len=16, max_out_len=16, d_model=64,
             _step_body(_mark_ownership(_declare_slot_state(
                 probe_prog.global_block, specs)), probe=True)
 
-    state = {"tok_buf": f"{state_prefix}tok_buf",
-             "step": f"{state_prefix}step",
-             "finished": f"{state_prefix}finished",
-             "active": f"{state_prefix}active"}
-    if paged:
-        state["block_tab"] = f"{state_prefix}block_tab"
-        state["prompt_ref"] = f"{state_prefix}prompt_ref"
-        state["prefill_until"] = f"{state_prefix}prefill_until"
-        if probe_prog is not None:
-            state["probe_probs"] = f"{state_prefix}probe_probs"
-    if needs_seeds:
-        state["seed"] = f"{state_prefix}seed"
-    if spec:
-        for c in ("spec_proposed", "spec_accepted", "spec_emitted",
-                  "spec_draft_steps", "spec_target_steps",
-                  "spec_lane_accepted", "spec_lane_ticks"):
-            state[c] = f"{state_prefix}{c}"
-        if draft.k_options:
-            state.update(devtel.spec_k_state_entries(
-                state_prefix, draft.k_options))
-    # devtel counters join the state map (and therefore the PTA150
-    # counter-presence sweep) under their logical names
-    state.update(devtel.state_entries(state_prefix, paged))
     bundle = DecodeStepBundle(prefills, step_prog, serves, startup,
                               state, n_slots, seq_len, maxT, start_id,
                               end_id, cache=cache,
                               hit_prefills=hit_prefills,
                               sampling=sampling, draft=draft,
-                              cow=cow_prog, probe=probe_prog)
+                              cow=cow_prog, probe=probe_prog,
+                              fed_tables=fed_tables, serve_row=row)
     bundle._state_specs = {
         n: (shape, dt) for n, (shape, dt) in specs.items()}
     if sharding is not None and sharding.enabled:
@@ -4436,4 +4600,5 @@ __all__ = ["CacheConfig", "SamplingConfig", "DraftConfig",
            "cached_decoder_step",
            "step_logits", "init_token_buffer", "emit_token_step",
            "emit_lane_tokens", "lane_onehots", "tel_add",
-           "build_serve_program", "heads_of"]
+           "build_serve_program", "heads_of", "ServeRow", "fed_name",
+           "serve_row_of"]

@@ -21,8 +21,8 @@ program, following the r14 speculative-counter pattern:
   (analysis/checkers.py) enforces both properties on every var
   carrying the ``@TEL`` name mark.
 * counters are CUMULATIVE since ``init_slot_state``; the serving layer
-  fetches them once per dispatch (they join the fetch list the
-  dispatch already reads) and DELTAS them into per-window stats and
+  fetches them once per dispatch (they ride the packed row the
+  dispatch already reads back) and DELTAS them into per-window stats and
   uniquely-labeled pull-provider metric samples
   (``paddle_tpu_devtel_*``). The device-side cost is a handful of
   scalar int64 adds per tick — measured unresolvable next to the
@@ -316,8 +316,9 @@ class DeviceTelemetry:
 
     @property
     def fetch_names(self) -> List[str]:
-        """Var names to append to the dispatch fetch list (order
-        matches ``absorb``'s expectation)."""
+        """Var names a dispatch hands back, in the order ``absorb``
+        expects them: their place in the bundle's packed serve row
+        (models/decode_engine.serve_row_of)."""
         return [name for _, name in self._counters]
 
     def absorb(self, values: Iterable) -> Dict[str, int]:

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register_op
+from ..core.types import to_jnp_dtype
 
 
 @register_op("masked_pool_write", differentiable=False,
@@ -529,3 +530,16 @@ def lane_probe_write(ctx):
     here = (jnp.arange(hist.shape[1])[None] == step.reshape(-1, 1)) \
         & on[:, None]
     return jnp.where(here[..., None], new[:, None], hist)
+
+
+@register_op("pack_row", differentiable=False, stop_gradient_slots=("X",))
+def pack_row(ctx):
+    """X (a list of integer arrays of any shapes) laid end to end, each
+    flattened, as one flat row of the output's dtype: what a serve
+    program hands back to its scheduler in one array
+    (models/decode_engine.ServeRow cuts it back by the same shapes)."""
+    dtype = jax.dtypes.canonicalize_dtype(
+        to_jnp_dtype(ctx.attr("dtype", "int64")))
+    return jnp.concatenate(
+        [x.reshape(-1).astype(dtype) for x in ctx.inputs("X")])
+

@@ -300,7 +300,8 @@ class TestPromptEntryWriters:
         b, exe, scope = built["bundle"], built["exe"], built["scope"]
         src, want = projection
         idle = {"n_steps": np.array([0], np.int64),
-                "min_active": np.array([0], np.int64)}
+                "min_active": np.array([0], np.int64),
+                **b.idle_table_feed()}
         b.init_slot_state(scope)
         if writer == "miss_admission":
             exe.run(b.serves[("miss", 1)],

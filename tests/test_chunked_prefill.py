@@ -38,7 +38,8 @@ from paddle_tpu.core.scope import Scope
 from paddle_tpu.inference.serving import (DisaggregatedPrefillWorker,
                                           PagedContinuousGenerationServer)
 from paddle_tpu.models import transformer as T
-from paddle_tpu.models.decode_engine import POOL_MARK, CacheConfig
+from paddle_tpu.models.decode_engine import (POOL_MARK, CacheConfig,
+                                             fed_name)
 
 V, D, H, L, S, MAXT = 16, 32, 2, 2, 10, 32
 BS, NB, E, C = 8, 24, 3, 4
@@ -117,16 +118,17 @@ class TestDeviceChunkParity:
             3, V, (1, S)).astype(np.int64)
         tab = np.zeros((N_SLOTS + 1, MAXT // BS), np.int32)
         tab[0] = np.arange(MAXT // BS)
-        scope._set(PREFIX + "block_tab", tab)
         pref = np.full((N_SLOTS + 1,), E, np.int32)
         pref[0] = 0
-        scope._set(PREFIX + "prompt_ref", pref)
+        # the scheduler's tables ride every serve dispatch as feeds
+        idle = {"n_steps": np.array([0], np.int64),
+                "min_active": np.array([0], np.int64),
+                **b.idle_table_feed(),
+                fed_name("block_tab"): tab, fed_name("prompt_ref"): pref}
         exe.run(b.serves[("miss", 1)],
                 feed={"src_ids": src,
                       "slots": np.array([0], np.int64),
-                      "prompt_slots": np.array([0], np.int64),
-                      "n_steps": np.array([0], np.int64),
-                      "min_active": np.array([0], np.int64)},
+                      "prompt_slots": np.array([0], np.int64), **idle},
                 fetch_list=[b.state["active"]], scope=scope)
         names = [f"{PREFIX}cross_{kind}{li}{POOL_MARK}"
                  for kind in ("k", "v") for li in range(L)]
@@ -135,8 +137,7 @@ class TestDeviceChunkParity:
             for ci in range(NC):
                 feed = {"chunk_entry": np.array([1], np.int64),
                         "chunk_pos": np.array([ci * C], np.int64),
-                        "n_steps": np.array([0], np.int64),
-                        "min_active": np.array([0], np.int64)}
+                        **idle}
                 if key[1] == 0:
                     pad = np.zeros((1, C), np.int64)
                     seg = src[0, ci * C: ci * C + C]

@@ -74,7 +74,7 @@ def session():
     def keep_tables():
         tables.append((srv._tab.copy(),
                        [r is not None for r in srv._lanes]))
-        pre()
+        return pre()
     srv._pre_dispatch = keep_tables
     rng = np.random.default_rng(0)
     doc = rng.integers(3, c["vocab"], 40)

@@ -703,6 +703,133 @@ class TestPTA192:
         assert not _diags(main, "PTA190")
 
 
+def _unmark(var):
+    """Take `var`'s ownership mark away (the variable's and its
+    producer's), as a builder that forgot it would have left it."""
+    del var._ownership_tag, var._ownership_bound
+    for blk in var.block.program.blocks:
+        for op in blk.ops:
+            if var.name in op.output_arg_names:
+                op.attrs.pop(absint.OWNERSHIP_ATTR, None)
+                op.attrs.pop(absint.OWNERSHIP_BOUND_ATTR, None)
+    var.block.program._version += 1
+
+
+class TestMarksOnTheFedTables:
+    """A serve program takes the scheduler's tables as feeds and copies
+    them into its state (decode_engine.build_serve_program): the marks
+    sit on the feeds, the read-only tables' state is proven THROUGH the
+    copy, and every rule the marks feed still fires when one is
+    missing or wrong."""
+
+    NB, E = 4, 2
+
+    def _serve(self, key=0):
+        from paddle_tpu import unique_name
+        from paddle_tpu.models import transformer as T
+        from paddle_tpu.models.decode_engine import CacheConfig
+
+        with unique_name.guard():
+            bundle = T.build_decode_step_program(
+                seq_len=8, max_out_len=8, d_model=32, n_heads=2,
+                n_layers=1, d_inner=64, vocab=50, n_slots=2,
+                state_prefix="@ownfed/",
+                cache=CacheConfig(layout="paged", block_size=4,
+                                  n_blocks=self.NB,
+                                  n_prompt_entries=self.E))
+        return bundle, bundle.serves[key]
+
+    def _errors(self, prog):
+        return [d for d in run_checks(prog)
+                if d.code in ("PTA190", "PTA191", "PTA192")
+                and d.severity == ERROR]
+
+    def test_the_feeds_carry_the_marks_and_every_access_is_proven(self):
+        from paddle_tpu.models.decode_engine import fed_name
+
+        bundle, prog = self._serve(("miss", 2))
+        blk = prog.global_block
+        for table, tag, bound in (
+                ("block_tab", "block_table", self.NB),
+                ("prompt_ref", "prompt_entry_ref", self.E + 1),
+                ("active", "lane_active", None)):
+            fed = blk.var(fed_name(table))
+            assert not fed.persistable
+            assert (fed._ownership_tag, fed._ownership_bound) \
+                == (tag, bound)
+        # the two read-only tables' state is no pinned source: its
+        # provenance is derived through the copy from the feed
+        facts = absint.analyze(prog)
+        for table, tag in (("block_tab", "block_table"),
+                           ("prompt_ref", "prompt_entry_ref")):
+            var = blk.var(bundle.state[table])
+            assert var.persistable
+            assert getattr(var, "_ownership_tag", None) is None
+            assert facts.prov_of(var.name).tags == (tag,)
+        # the lane mask keeps its pin: the program rewrites it
+        assert blk.var(bundle.state["active"])._ownership_tag \
+            == "lane_active"
+        assert facts.ownership_ledger()["unproven"] == 0
+        assert not self._errors(prog)
+
+    @pytest.mark.parametrize("table", ["block_tab", "prompt_ref"])
+    def test_an_unmarked_fed_table_is_unknown_provenance(self, table):
+        from paddle_tpu.models.decode_engine import fed_name
+
+        _bundle, prog = self._serve()
+        _unmark(prog.global_block.var(fed_name(table)))
+        ds = [d for d in self._errors(prog) if d.code == "PTA190"]
+        assert ds and any("UNKNOWN provenance" in d.message for d in ds)
+
+    def test_the_fed_prompt_refs_bound_is_held_to_the_pool(self):
+        from paddle_tpu.models.decode_engine import fed_name
+
+        _bundle, prog = self._serve()
+        fed = prog.global_block.var(fed_name("prompt_ref"))
+        absint.mark_pool_index_source(fed, "prompt_entry_ref",
+                                      bound=self.E + 2)
+        assert [d for d in self._errors(prog) if d.code == "PTA190"]
+
+    def test_an_unmarked_lane_mask_gates_nothing(self):
+        from paddle_tpu.models.decode_engine import fed_name
+
+        bundle, prog = self._serve()
+        blk = prog.global_block
+        _unmark(blk.var(fed_name("active")))
+        _unmark(blk.var(bundle.state["active"]))
+        ds = [d for d in self._errors(prog) if "lane-active" in d.message]
+        assert ds and ds[0].code == "PTA190"
+
+    def test_a_write_through_the_fed_prompt_refs_is_write_while_shared(
+            self):
+        """The refcounted tag survives the copy into the state
+        variable: wired as a write index it is the COW violation."""
+        main, startup, g = _guarded()
+        with g:
+            blk = main.global_block
+            pool = _mk_pool(blk)
+            new = layers.data("new", shape=[3, 2, 8], dtype="float32",
+                              append_batch_size=False)
+            fed = layers.data("fed_prompt_ref", shape=[3], dtype="int32",
+                              append_batch_size=False)
+            absint.mark_pool_index_source(fed, "prompt_entry_ref",
+                                          bound=8)
+            pref = _mk_state(blk, "@own/prompt_ref", (3,))
+            layers.assign(fed, output=pref)
+            layers.masked_pool_write(pool, new, pref, leading_dims=2,
+                                     exclusive_via="host_indices")
+        ds = _diags(main, "PTA192")
+        assert ds and ds[0].severity == ERROR
+        assert "prompt_entry_ref" in ds[0].message
+
+    def test_the_burst_exit_is_still_the_divergence_source(self):
+        _bundle, prog = self._serve()
+        marked = [op for blk in prog.blocks for op in blk.ops
+                  if op.attrs.get(absint.DIVERGENCE_ATTR)
+                  == "lane_active_mask"]
+        assert len(marked) == 2         # the cond before and inside
+
+
 class TestLedgerAndBaseline:
     def _paged_bundle(self):
         from paddle_tpu.models import transformer as T
