@@ -1931,21 +1931,22 @@ def _chunk_or_tick(chunk, gate, pos):
 
 
 def causal_conv_tail(x, tail, kernel, name, chunk=None, gate=None,
-                     pos=None):
+                     pos=None, bias=True):
     """silu(causal depthwise convolution of x [N, W] with `<name>.w`
-    [W, kernel] + `<name>.b`), the `kernel - 1` inputs before row 0
-    read from and left in `tail` [R, kernel-1, W] (per-lane state, in
-    place). `chunk`: the rows are one lane's consecutive positions;
-    else row r is lane r's next input (ops/ssm_ops.py)."""
+    [W, kernel] + `<name>.b` (none where `bias` is False)), the
+    `kernel - 1` inputs before row 0 read from and left in `tail` [R,
+    kernel-1, W] (per-lane state, in place). `chunk`: the rows are one
+    lane's consecutive positions; else row r is lane r's next input
+    (ops/ssm_ops.py)."""
     helper = LayerHelper("causal_conv_tail", input=x, name=name)
     width = x.shape[-1]
     w = _matrix(helper, f"{name}.w", (width, kernel), x.dtype)
-    b = _ones(helper, f"{name}.b", width, x.dtype, 0.0)
+    inputs = {"X": x, "Tail": tail, "Filter": w}
+    if bias:
+        inputs["Bias"] = _ones(helper, f"{name}.b", width, x.dtype, 0.0)
     out = helper.create_variable_for_type_inference(x.dtype, True)
     helper.append_op(
-        "causal_conv_tail",
-        {"X": x, "Tail": tail, "Filter": w, "Bias": b,
-         **_chunk_or_tick(chunk, gate, pos)},
+        "causal_conv_tail", {**inputs, **_chunk_or_tick(chunk, gate, pos)},
         {"Out": out, "TailOut": tail}, {})
     return out
 
@@ -1977,17 +1978,60 @@ def mamba2_scan(xbc, dt, state, n_groups, name, block=128, chunk=None,
 
 
 def gated_group_rms_norm(x, z, groups, epsilon=1e-5, param_attr=None,
-                         name=None):
+                         name=None, norm_before_gate=False):
     """group_rms_norm(x * silu(z)) * w in `groups` groups of the last
-    axis; x [N, D] float32, z [N, D]; z's dtype out."""
+    axis; x [N, D] float32, z [N, D]; z's dtype out.
+    `norm_before_gate`: group_rms_norm(x) * w * silu(z), with one w [D
+    / groups] that every group shares (Gated DeltaNet's order)."""
     helper = LayerHelper("gated_group_rms_norm", input=z, name=name)
-    scale = _ones(helper, param_attr, x.shape[-1], z.dtype)
+    attrs = {"groups": int(groups), "epsilon": float(epsilon)}
+    width = x.shape[-1]
+    if norm_before_gate:
+        attrs["norm_before_gate"] = True
+        width //= groups
+    scale = _ones(helper, param_attr, width, z.dtype)
     out = helper.create_variable_for_type_inference(z.dtype, True)
     helper.append_op("gated_group_rms_norm",
-                     {"X": x, "Z": z, "Scale": scale}, {"Out": out},
-                     {"groups": int(groups), "epsilon": float(epsilon)})
+                     {"X": x, "Z": z, "Scale": scale}, {"Out": out}, attrs)
     return out
 
 
+# ---------------------------------------------------------------------------
+# gated delta-rule token mixing with per-lane state (ops/delta_ops.py)
+# ---------------------------------------------------------------------------
+def gated_delta(q, k, v, beta, a, state, name, block=64,
+                allow_neg_eigval=False, chunk=None, gate=None, pos=None):
+    """o [N, H*Dv] float32 of the gated delta rule on rows q, k [N,
+    H*Dk], v [N, H*Dv] (after the convolution), beta and a [N, H]
+    (before sigmoid and softplus), from and into the per-lane `state`
+    [R, H, Dk, Dv] float32 (in place). `chunk`: one lane's consecutive
+    positions, by the chunked form in blocks of `block` (op
+    gated_delta_chunk); else one step of every lane (op
+    gated_delta_step). Parameters `<name>_dt_bias`, `<name>_A_log` [H]
+    float32."""
+    helper = LayerHelper("gated_delta", input=q, name=name)
+    heads = state.shape[1]
+    dt_bias, a_log = (_ones(helper, f"{name}_{leaf}", heads, "float32",
+                            0.0) for leaf in ("dt_bias", "A_log"))
+    y = helper.create_variable_for_type_inference("float32", True)
+    attrs = {"allow_neg_eigval": bool(allow_neg_eigval)}
+    if chunk is not None:
+        attrs["block"] = int(block)
+    helper.append_op(
+        "gated_delta_step" if chunk is None else "gated_delta_chunk",
+        {"Q": q, "K": k, "V": v, "Beta": beta, "A": a, "DtBias": dt_bias,
+         "ALog": a_log, "State": state, **_chunk_or_tick(chunk, gate, pos)},
+        {"Y": y, "StateOut": state}, attrs)
+    return y
+
+
+def rope_at(x, pos, n_heads, theta, name=None):
+    """Rotary positions (rotate-half layout) on the `n_heads` heads of
+    rows x [N, H*D] at positions pos [N] (ops/lm_ops.py)."""
+    return _single_out(LayerHelper("rope_at", input=x, name=name),
+                       "rope_at", {"X": x, "Pos": pos},
+                       {"n_heads": int(n_heads), "theta": float(theta)})
+
+
 __all__.extend(["relu2", "causal_conv_tail", "mamba2_scan",
-                "gated_group_rms_norm"])
+                "gated_group_rms_norm", "gated_delta", "rope_at"])

@@ -56,6 +56,26 @@ def rotary_embedding(ctx):
     return {"Out": out.astype(x.dtype)}
 
 
+@register_op("rope_at", differentiable=False)
+def rope_at(ctx):
+    """Rotary positions (Su et al. '21, the rotate-half layout) on the
+    heads of X [N, H*D] at positions Pos [N]: x * cos + rotate_half(x)
+    * sin, head by head, angle pos * theta^(-2i/D). attrs n_heads,
+    theta. Angles and products float32, X's dtype out."""
+    x = ctx.input("X")
+    n, heads = x.shape[0], int(ctx.attr("n_heads"))
+    dim = x.shape[-1] // heads
+    inv = float(ctx.attr("theta")) ** (
+        -jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    angle = ctx.input("Pos").reshape(n).astype(jnp.float32)[:, None] * inv
+    angle = jnp.concatenate([angle, angle], -1)[:, None]    # [N, 1, D]
+    xf = x.astype(jnp.float32).reshape(n, heads, dim)
+    half = dim // 2
+    rot = jnp.concatenate([-xf[..., half:], xf[..., :half]], -1)
+    out = xf * jnp.cos(angle) + rot * jnp.sin(angle)
+    return {"Out": out.reshape(x.shape).astype(x.dtype)}
+
+
 @register_op("swiglu")
 def swiglu(ctx):
     """silu(a) * b for X = [a, b] side by side on the last axis

@@ -92,6 +92,13 @@ def masked_pool_write(ctx):
 # what the routing record calls the op's decision, by what it reads
 ROUTE_LABELS = {"cells": "paged_decode_attention",
                 "prompt_table": "paged_decode_attention.prompt_table"}
+# past this, a tick does not gather every lane's whole table at once (the
+# reference and grouped routes do) but reads one lane at a time
+# (`paged_attention_by_lane`): 33 lanes of 9,216 positions of 3,840
+# bfloat16 numbers are 2.2 GB a pool, which the chip cannot hold beside
+# pools that fill it (PR 38); every other cell's tick gathers 0.2 GB or
+# less, or takes the kernel
+WHOLE_TABLES_BYTES = 1 << 30
 
 
 @register_op("paged_decode_attention", differentiable=False,
@@ -142,6 +149,11 @@ def paged_decode_attention(ctx):
     pos = pos.reshape(q.shape[0])
     n_kv = int(ctx.attr("n_kv_heads", 0)) or kw["n_heads"]
     label = ROUTE_LABELS[ctx.attr("reads", "cells")]
+    if q.shape[0] * tab.shape[1] * kw["block_size"] * pool_k.shape[1] \
+            * pool_k.dtype.itemsize > WHOLE_TABLES_BYTES:
+        note_route(label, q.shape, False)
+        return paged_attention_by_lane(q, pool_k, pool_v, tab, pos,
+                                       n_kv_heads=n_kv, **kw)
     if n_kv != kw["n_heads"]:
         note_route(label, q.shape, False)
         return grouped_paged_attention(q, pool_k, pool_v, tab, pos,
@@ -262,6 +274,54 @@ def grouped_paged_attention(q, pool_k, pool_v, tab, pos, *, block_size,
         return _by_query_blocks(block, args)
 
     return _over_live_pages(run, n_pages, block_size, live)
+
+
+def paged_attention_by_lane(q, pool_k, pool_v, tab, pos, *, block_size,
+                            n_heads, n_kv_heads, scale, cuts=8):
+    """`grouped_paged_attention` of q [R, n, H*Dh] (query j of lane r
+    at position pos[r] + j) one lane after another (`lax.map`), each
+    over the fewest of `cuts` even shares of its table that hold its
+    positions (`lax.switch`): a tick reads what its lanes hold, and
+    holds one lane's keys and values at a time, where gathering every
+    lane's whole table ([R, NP*BS, Hkv*Dh]) would not fit beside wide
+    pools. Scores, softmax and accumulation float32, operands as
+    stored. Out [R, n, H*Dh] in q's dtype."""
+    r, n, hd = q.shape
+    dh = hd // n_heads
+    rep = n_heads // n_kv_heads
+    n_pages = tab.shape[1]
+    kb = pool_k.reshape(-1, block_size, pool_k.shape[-1])
+    vb = pool_v.reshape(-1, block_size, pool_v.shape[-1])
+    sizes = sorted({max(1, -(-n_pages * f // cuts))
+                    for f in range(1, cuts + 1)})
+
+    def lane(args):
+        qr, row, first = args           # [n, H*Dh], [NP], []
+
+        def run(pages):
+            t = pages * block_size
+            k, v = (b.at[row[:pages]].get(mode="promise_in_bounds")
+                    .reshape(t, n_kv_heads, dh) for b in (kb, vb))
+            s = jnp.einsum("qgrd,tgd->grqt",
+                           qr.reshape(n, n_kv_heads, rep, dh), k,
+                           preferred_element_type=jnp.float32) * scale
+            seen = jnp.arange(t, dtype=jnp.int32)[None] \
+                <= first + jnp.arange(n, dtype=jnp.int32)[:, None]
+            s = jnp.where(seen, s, -jnp.inf)
+            p = jnp.exp(s - s.max(-1, keepdims=True))
+            p = p / p.sum(-1, keepdims=True)
+            out = jnp.einsum("grqt,tgd->qgrd", p.astype(v.dtype), v,
+                             preferred_element_type=jnp.float32)
+            return out.reshape(n, hd).astype(q.dtype)
+
+        live = first + n
+        which = sum((live > c * block_size).astype(jnp.int32)
+                    for c in sizes[:-1])
+        return jax.lax.switch(which, [functools.partial(run, c)
+                                      for c in sizes])
+
+    return jax.lax.map(lane, (q, tab.astype(jnp.int32),
+                              pos.astype(jnp.int32)))
 
 
 @register_op("paged_prefill_attention", differentiable=False,

@@ -37,11 +37,11 @@ def _f32(x):
 
 def _conv_silu(seq, w, b, n):
     """seq [..., K-1+n, W] float32 -> silu(conv + b) [..., n, W]: tap
-    K-1 of Filter [W, K] is the present."""
+    K-1 of Filter [W, K] is the present; b None: no bias."""
     taps = w.shape[1]
     wf = _f32(w)
     conv = sum(seq[..., j:j + n, :] * wf[:, j] for j in range(taps))
-    return jax.nn.silu(conv + _f32(b))
+    return jax.nn.silu(conv if b is None else conv + _f32(b))
 
 
 @register_op("causal_conv_tail", differentiable=False,
@@ -50,7 +50,8 @@ def _conv_silu(seq, w, b, n):
 def causal_conv_tail(ctx):
     """silu(causal depthwise convolution + bias) with the K-1 inputs
     before the first row carried in Tail [R, K-1, W] (also the op's
-    output TailOut, in place). X [N, W]; Filter [W, K]; Bias [W].
+    output TailOut, in place). X [N, W]; Filter [W, K]; Bias [W] or
+    none.
 
     A chunk (Lane [1] given): the N rows are consecutive positions of
     lane Lane from position Pos [1]; the first Len [1] are real. The
@@ -204,11 +205,20 @@ def mamba2_step(ctx):
 def gated_group_rms_norm(ctx):
     """rms_norm in `groups` equal groups of the last axis of X * silu(Z),
     times Scale [D] (Mamba-2's gated norm, the gate before the norm).
-    X [N, D] float32; Z [N, D]. Out in Z's dtype; statistics float32."""
+    X [N, D] float32; Z [N, D]. Out in Z's dtype; statistics float32.
+    attr `norm_before_gate` (Gated DeltaNet's order): rms_norm of each
+    group of X times Scale [D / groups], one weight every group shares,
+    then times silu(Z)."""
     x, z = _f32(ctx.input("X")), ctx.input("Z")
     groups = int(ctx.attr("groups"))
+    eps = float(ctx.attr("epsilon", 1e-5))
+    if ctx.attr("norm_before_gate", False):
+        v = x.reshape(x.shape[0], groups, -1)
+        v = v * jax.lax.rsqrt(jnp.mean(v * v, -1, keepdims=True) + eps) \
+            * _f32(ctx.input("Scale"))
+        return {"Out": (v.reshape(x.shape) * jax.nn.silu(_f32(z)))
+                .astype(z.dtype)}
     v = (x * jax.nn.silu(_f32(z))).reshape(x.shape[0], groups, -1)
-    v = v * jax.lax.rsqrt(jnp.mean(v * v, -1, keepdims=True)
-                          + float(ctx.attr("epsilon", 1e-5)))
+    v = v * jax.lax.rsqrt(jnp.mean(v * v, -1, keepdims=True) + eps)
     return {"Out": (v.reshape(x.shape) * _f32(ctx.input("Scale")))
             .astype(z.dtype)}

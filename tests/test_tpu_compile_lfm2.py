@@ -1,4 +1,6 @@
-"""The Pallas kernels of the LFM2-MoE step compiled for a TPU v5e that
+"""The Pallas kernels of the LFM2-MoE step (and, since PR 38, the delta
+rule and the wide paged reads of the Olmo-Hybrid serve programs)
+compiled for a TPU v5e that
 is described, not attached, at the widths the benchmark cell runs: what
 the chip's compiler would refuse (a tile that does not fit its fast
 memory, a misaligned slice) fails here at no chip time. Nothing runs,
@@ -229,3 +231,90 @@ def test_prompt_table_read_at_the_serve_cells_size(one_chip):
 
     assert not re.search(r"= f32\[(129,256|33024),1024\]\S* copy\(",
                          text)
+
+
+# ---------------------------------------------------------------------
+# Olmo-Hybrid's serve programs (PR 38): the delta rule and the tick's
+# full attention over bfloat16 pools of 3,840 numbers a position, at the
+# widths of `serve_olmoh_sessions_32`; what each holds beside its
+# arguments is bounded, which a gather of every lane's whole table was
+# not (4.4 GB, refused on the chip for the memory)
+# ---------------------------------------------------------------------
+class _Ctx:
+    def __init__(self, attrs, **inputs):
+        self.inputs, self.attrs = inputs, attrs
+
+    def input(self, slot):
+        return self.inputs.get(slot)
+
+    def attr(self, name, default=None):
+        return self.attrs.get(name, default)
+
+
+_H, _DK, _DV, _ROWS = 30, 96, 192, 33
+
+
+def _delta_args(one_chip, rows):
+    return [_spec(one_chip, (rows, _H * _DK)),
+            _spec(one_chip, (rows, _H * _DK)),
+            _spec(one_chip, (rows, _H * _DV)),
+            _spec(one_chip, (rows, _H)), _spec(one_chip, (rows, _H)),
+            _spec(one_chip, (_H,), jnp.float32),
+            _spec(one_chip, (_H,), jnp.float32),
+            _spec(one_chip, (_ROWS, _H, _DK, _DV), jnp.float32)]
+
+
+def _delta_ctx(args, attrs, **where):
+    return _Ctx(attrs, **dict(zip(("Q", "K", "V", "Beta", "A", "DtBias",
+                                   "ALog", "State"), args)), **where)
+
+
+def test_the_chunked_delta_rule_of_a_chunk_of_2048(one_chip):
+    from paddle_tpu.ops import delta_ops
+
+    def chunk(*args):
+        *args, lane, length, pos = args
+        return delta_ops.gated_delta_chunk(_delta_ctx(
+            args, {"block": 64, "allow_neg_eigval": True}, Lane=lane,
+            Len=length, Pos=pos))
+    one = _spec(one_chip, (1,), jnp.int32)
+    compiled = jax.jit(chunk, donate_argnums=(7,)).lower(
+        *_delta_args(one_chip, 2048), one, one, one).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+def test_the_delta_rule_step_of_every_lane_in_place(one_chip):
+    from paddle_tpu.ops import delta_ops
+
+    def step(*args):
+        *args, gate, pos = args
+        return delta_ops.gated_delta_step(_delta_ctx(
+            args, {"allow_neg_eigval": True}, Gate=gate, Pos=pos))
+    compiled = jax.jit(step, donate_argnums=(7,)).lower(
+        *_delta_args(one_chip, _ROWS),
+        _spec(one_chip, (_ROWS,), jnp.float32),
+        _spec(one_chip, (_ROWS,), jnp.int32)).compile()
+    memory = compiled.memory_analysis()
+    # the state is written where it is stored: 73 MB aliased (97 MB as
+    # the chip lays it out, rows of 192 on tiles of 128 lanes), no copy
+    assert memory.alias_size_in_bytes >= _ROWS * _H * _DK * _DV * 4
+    assert memory.temp_size_in_bytes < 0.1e9
+
+
+def test_a_tick_reads_wide_pools_one_lane_at_a_time(one_chip):
+    from paddle_tpu.ops import paged_ops
+
+    blocks, size, pages, width = 3584, 64, 144, 3840
+
+    def attend(q, pool_k, pool_v, tab, pos):
+        return paged_ops.paged_attention_by_lane(
+            q, pool_k, pool_v, tab, pos, block_size=size, n_heads=30,
+            n_kv_heads=30, scale=128 ** -0.5)
+    compiled = jax.jit(attend).lower(
+        _spec(one_chip, (_ROWS, 1, width)),
+        _spec(one_chip, (blocks * size, width)),
+        _spec(one_chip, (blocks * size, width)),
+        _spec(one_chip, (_ROWS, pages), jnp.int32),
+        _spec(one_chip, (_ROWS,), jnp.int32)).compile()
+    # one lane's whole table, keys and values: 2 x 70.8 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
