@@ -715,6 +715,32 @@ def sweep_cases(tiny):
                   paged_attention.usable(q, tk, ref, seq), True,
                   paged_fwd((q, tk, tv, ref, last),
                             dict(kw, block_size=seq)), 1e-3))
+
+    # the Olmo-Hybrid serve cell's full attention: 30 heads of 128 over
+    # bfloat16 pools of 3,584 blocks of 64, tables of 144 pages, the
+    # lanes streamed; its reference is the route it replaces
+    from paddle_tpu.ops import paged_ops
+
+    rws, hh, dh, nb, bs, pages = (5, 4, 32, 24, 16, 4) if tiny \
+        else (33, 30, 128, 3584, 64, 144)
+    q = rnd(22, (rws, 1, hh * dh), jnp.bfloat16)
+    pk, pv = (rnd(i, (nb * bs, hh * dh), jnp.bfloat16) for i in (23, 24))
+    tab = jnp.asarray(rs.randint(0, nb, (rws, pages)).astype(np.int32))
+    step = jnp.asarray(rs.randint(0, pages * bs, (rws,)).astype(np.int32))
+    kw = dict(block_size=bs, n_heads=hh, scale=dh ** -0.5)
+
+    def streamed_fwd():
+        args = (q, pk, pv, tab, step)
+        got = jax.jit(
+            lambda *a: paged_attention.paged_decode_attention_streamed(
+                *a, **kw))(*args)
+        return [("out", got, paged_ops.paged_attention_by_lane(
+            *args, n_kv_heads=hh, **kw))]
+    cases.append(("paged_attention_streamed",
+                  f"q({rws},1,{hh * dh}) pool({nb * bs},{hh * dh}) "
+                  f"table({rws},{pages}) bf16",
+                  paged_attention.streams(q, pk, tab, bs, hh, hh), True,
+                  [("fwd", streamed_fwd)], 2e-2))
     return cases
 
 

@@ -93,11 +93,15 @@ def masked_pool_write(ctx):
 ROUTE_LABELS = {"cells": "paged_decode_attention",
                 "prompt_table": "paged_decode_attention.prompt_table"}
 # past this, a tick does not gather every lane's whole table at once (the
-# reference and grouped routes do) but reads one lane at a time
-# (`paged_attention_by_lane`): 33 lanes of 9,216 positions of 3,840
+# reference and grouped routes do): 33 lanes of 9,216 positions of 3,840
 # bfloat16 numbers are 2.2 GB a pool, which the chip cannot hold beside
-# pools that fill it (PR 38); every other cell's tick gathers 0.2 GB or
-# less, or takes the kernel
+# pools that fill it. There, on one TPU, a tick of one query a
+# lane over bfloat16 pools with a key-value head a query head streams
+# each lane's live blocks through VMEM once (the Pallas kernel
+# `paged_decode_attention_streamed`); off the TPU, under a mesh,
+# for grouped heads and for several queries a lane it reads one lane at
+# a time (`paged_attention_by_lane`). Every other cell's tick gathers
+# 0.2 GB or less, or takes the float32 kernel
 WHOLE_TABLES_BYTES = 1 << 30
 
 
@@ -129,9 +133,11 @@ def paged_decode_attention(ctx):
     prompt table) and their rows are ignored downstream.
 
     Nothing of shape ``[R, H, maxT, Dh]`` is built: the routes in
-    ops/pallas/paged_attention.py (a Pallas kernel for q = 1 on one
-    TPU, a jnp composition elsewhere) keep ``H*Dh`` on the lanes, and
-    ``note_route`` records which was taken. Reads are NOT clamped or
+    ops/pallas/paged_attention.py (Pallas kernels for q = 1 on one TPU,
+    one over float32 pools and one that streams wide bfloat16 pools
+    past ``WHOLE_TABLES_BYTES``; a jnp composition elsewhere) and the
+    jnp routes below keep ``H*Dh`` on the lanes, and ``note_route``
+    records whether a kernel was taken. Reads are NOT clamped or
     filled: Table must be proven in bounds, which the ownership prover
     does at build time (analysis/absint.py records the read with
     Table as its index, PTA190 wants provenance from a marked host
@@ -151,7 +157,10 @@ def paged_decode_attention(ctx):
     label = ROUTE_LABELS[ctx.attr("reads", "cells")]
     if q.shape[0] * tab.shape[1] * kw["block_size"] * pool_k.shape[1] \
             * pool_k.dtype.itemsize > WHOLE_TABLES_BYTES:
-        note_route(label, q.shape, False)
+        if note_route(label, q.shape, PA.streams(
+                q, pool_k, tab, kw["block_size"], kw["n_heads"], n_kv)):
+            return PA.paged_decode_attention_streamed(
+                q, pool_k, pool_v, tab, pos, **kw)
         return paged_attention_by_lane(q, pool_k, pool_v, tab, pos,
                                        n_kv_heads=n_kv, **kw)
     if n_kv != kw["n_heads"]:

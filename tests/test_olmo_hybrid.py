@@ -474,7 +474,7 @@ def test_a_tick_reads_each_lane_over_its_live_pages(hq, hkv, monkeypatch):
     attention by heads over the lane's gathered positions (float64);
     the same as the route that gathers every lane's whole table."""
     import jax.numpy as jnp
-    from paddle_tpu.ops import paged_ops
+    from paddle_tpu.ops import paged_ops, pallas
 
     lanes, pages, bs, dh = 5, 16, 8, 16
     rng = np.random.default_rng(hq + hkv)
@@ -490,7 +490,11 @@ def test_a_tick_reads_each_lane_over_its_live_pages(hq, hkv, monkeypatch):
     ins = dict(Q=q, PoolK=pk, PoolV=pv, Table=jnp.asarray(tab), Pos=pos)
     whole = np.asarray(paged_ops.paged_decode_attention(Ctx(attrs, **ins)))
     monkeypatch.setattr(paged_ops, "WHOLE_TABLES_BYTES", 1 << 10)
-    out = np.asarray(paged_ops.paged_decode_attention(Ctx(attrs, **ins)))
+    with pallas.record_routes() as routes:
+        out = np.asarray(paged_ops.paged_decode_attention(
+            Ctx(attrs, **ins)))
+    # on the CPU no kernel takes it: the route is by_lane's
+    assert routes == [("paged_decode_attention", q.shape, False)]
     assert (np.asarray(paged_ops.paged_attention_by_lane(
         q, pk, pv, jnp.asarray(tab), pos, block_size=bs, n_heads=hq,
         n_kv_heads=hkv, scale=dh ** -0.5)) == out).all()
@@ -507,3 +511,205 @@ def test_a_tick_reads_each_lane_over_its_live_pages(hq, hkv, monkeypatch):
             want = (w / w.sum()) @ v[:pos[r] + 1, g]
             assert np.abs(out[r, 0, h * dh:(h + 1) * dh] - want).max() \
                 < 1e-5
+
+
+# the ticks of the serve cells as paged_decode_attention sees them: Q,
+# the pools, the table, block size, heads, key-value heads, what it reads
+TICKS = {
+    "olmoh": ((33, 1, 3840), (3584 * 64, 3840), "bfloat16", (33, 144),
+              64, 30, 30, "cells"),
+    "olmoh_grouped_heads": ((33, 1, 3840), (3584 * 64, 1920), "bfloat16",
+                            (33, 144), 64, 30, 15, "cells"),
+    "olmoh_several_queries": ((33, 4, 3840), (3584 * 64, 3840),
+                              "bfloat16", (33, 144), 64, 30, 30, "cells"),
+    "transformer_big_serve": ((33, 1, 1024), (1280 * 16, 1024), "float32",
+                              (33, 16), 16, 16, 16, "cells"),
+    "transformer_big_serve_prompts": ((33, 1, 1024), (129 * 256, 1024),
+                                      "float32", (33, 1), 256, 16, 16,
+                                      "prompt_table"),
+    "nemotron": ((129, 1, 4096), (5120 * 64, 256), "bfloat16", (129, 40),
+                 64, 32, 2, "cells"),
+}
+# the route each takes on the CPU and on a TPU (interpret mode stands in
+# for it): the streamed kernel, the float32 kernel, one lane at a time,
+# or one gather of every lane's table
+ROUTES = {
+    "olmoh": ("by_lane", "streamed"),
+    "olmoh_grouped_heads": ("by_lane", "by_lane"),
+    "olmoh_several_queries": ("by_lane", "by_lane"),
+    "transformer_big_serve": ("gather", "kernel"),
+    "transformer_big_serve_prompts": ("gather", "kernel"),
+    "nemotron": ("gather", "gather"),
+}
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["cpu", "tpu"])
+@pytest.mark.parametrize("tick", list(TICKS))
+def test_a_tick_takes_its_route_by_what_the_op_sees(tick, interpret):
+    """The route of each serve cell's tick at its own shapes, traced and
+    not run: past WHOLE_TABLES_BYTES one query a lane over bfloat16
+    pools with a key-value head a query head streams on the TPU, and
+    reads one lane at a time off it, for grouped heads and for several
+    queries; the ticks under the threshold keep their routes. The
+    routing record says whether a kernel was taken."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import paged_ops, pallas
+    from paddle_tpu.ops.pallas import attention as base
+    from test_paged_decode import _eqns
+
+    qs, ps, dtype, ts, bs, h, kv, reads = TICKS[tick]
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        (qs, dtype), (ps, dtype), (ps, dtype), (ts, jnp.int32),
+        (qs[:1], jnp.int32))]
+    attrs = {"block_size": bs, "n_heads": h, "n_kv_heads": kv,
+             "scale": 0.125, "reads": reads}
+
+    def op(q, pk, pv, tab, pos):
+        return paged_ops.paged_decode_attention(Ctx(
+            attrs, Q=q, PoolK=pk, PoolV=pv, Table=tab, Pos=pos))
+    base.force_interpret(interpret)
+    try:
+        with pallas.record_routes() as routes:
+            closed = jax.make_jaxpr(op)(*args)
+    finally:
+        base.force_interpret(False)
+    eqns = list(_eqns(closed.jaxpr))
+    kernels = {e.params["name"] for e in eqns
+               if e.primitive.name == "pallas_call"}
+    if kernels:
+        route = {"paged_decode_attention_streamed": "streamed",
+                 "paged_decode_attention": "kernel"}[kernels.pop()]
+    else:
+        route = "by_lane" if any(e.primitive.name == "scan"
+                                 for e in eqns) else "gather"
+    assert route == ROUTES[tick][interpret]
+    assert routes == [(paged_ops.ROUTE_LABELS[reads], qs,
+                       route in ("streamed", "kernel"))]
+
+
+# the streamed kernel in interpret mode at a width it takes (4 heads of
+# 32), blocks of 16 rows and chunks of two blocks, each lane a table of
+# 8 blocks from a permutation: lane -> its position
+STREAM = dict(bs=16, pages=8, heads=4, dh=32)
+STREAM_LANES = {
+    "position_0": 0,
+    "last_row_of_a_block": 15,
+    "first_row_of_a_block": 16,
+    "last_row_of_a_chunk": 31,
+    "first_row_of_the_next_chunk": 32,
+    "the_tables_last_position": 127,
+    "idle_on_block_0": 0,           # a cleared table row
+}
+
+
+@pytest.fixture(scope="module")
+def streamed():
+    import jax.numpy as jnp
+    from paddle_tpu.ops import paged_ops
+    from paddle_tpu.ops.pallas import attention as base
+    from paddle_tpu.ops.pallas import paged_attention as PA
+
+    bs, pages, h, dh = (STREAM[k] for k in ("bs", "pages", "heads", "dh"))
+    names = list(STREAM_LANES)
+    rng = np.random.default_rng(17)
+    n_blocks = len(names) * pages + 3
+    tab = rng.permutation(n_blocks)[:len(names) * pages].reshape(
+        len(names), pages).astype(np.int32)
+    tab[names.index("idle_on_block_0")] = 0
+    pos = np.array(list(STREAM_LANES.values()), np.int32)
+    pk, pv = (jnp.asarray(rng.normal(size=(n_blocks * bs, h * dh)),
+                          jnp.bfloat16) for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(len(names), 1, h * dh)), jnp.bfloat16)
+    kw = dict(block_size=bs, n_heads=h, scale=dh ** -0.5)
+    budget = PA._STREAM_BUFFERS
+    PA._STREAM_BUFFERS = 4 * 2 * bs * h * dh * 2
+    base.force_interpret(True)
+    try:
+        chunk_pages = PA.stream_chunk_pages(bs, h * dh, 2, pages)
+        got = PA.paged_decode_attention_streamed(
+            q, pk, pv, jnp.asarray(tab), jnp.asarray(pos), **kw)
+    finally:
+        base.force_interpret(False)
+        PA._STREAM_BUFFERS = budget
+    by_lane = paged_ops.paged_attention_by_lane(
+        q, pk, pv, jnp.asarray(tab), jnp.asarray(pos), n_kv_heads=h, **kw)
+    cells = (tab[:, :, None] * bs + np.arange(bs)).reshape(len(names), -1)
+    k64, v64 = (np.asarray(p, np.float64) for p in (pk, pv))
+    want = []
+    for r, last in enumerate(pos):
+        k = k64[cells[r, :last + 1]].reshape(-1, h, dh)
+        v = v64[cells[r, :last + 1]].reshape(-1, h, dh)
+        qr = np.asarray(q[r, 0], np.float64).reshape(h, dh)
+        s = np.einsum("thd,hd->ht", k, qr) * dh ** -0.5
+        w = np.exp(s - s.max(1, keepdims=True))
+        w /= w.sum(1, keepdims=True)
+        want.append(np.einsum("ht,thd->hd", w, v).reshape(-1))
+    return {"chunk_pages": chunk_pages, "dtype": got.dtype,
+            "got": np.asarray(got, np.float64)[:, 0],
+            "by_lane": np.asarray(by_lane, np.float64)[:, 0],
+            "want": np.array(want)}
+
+
+@pytest.mark.parametrize("lane", list(STREAM_LANES))
+def test_the_streamed_kernel_reads_a_lane_as_by_lane_does(streamed, lane):
+    """Each lane's context rows from the kernel, its live blocks copied
+    a chunk at a time, against the route it replaces and a float64
+    oracle over the lane's positions: bfloat16 out, as q is, within a
+    unit of bfloat16's last place at the lane's largest number of both
+    (each rounds its output, and the kernel rounds the softmax's
+    weights before they are normalised, by_lane after: by_lane itself
+    reads up to 0.6 of that unit against the oracle)."""
+    assert streamed["chunk_pages"] == 2 and streamed["dtype"] == "bfloat16"
+    i = list(STREAM_LANES).index(lane)
+    got, want = streamed["got"][i], streamed["want"][i]
+    ulp = 2.0 ** (np.floor(np.log2(np.abs(want).max())) - 7)
+    assert np.abs(got - streamed["by_lane"][i]).max() <= ulp
+    assert np.abs(got - want).max() <= ulp
+
+
+def test_the_streamed_chunk_is_whole_blocks_in_a_vmem_budget():
+    """A chunk is as many blocks as four buffers of them fit in the
+    kernel's budget: 256 rows at the Olmo cell's width of 3,840
+    bfloat16 numbers, one block at least, the table at most."""
+    from paddle_tpu.ops.pallas import paged_attention as PA
+
+    assert PA.stream_chunk_pages(64, 3840, 2, 144) * 64 == 256
+    assert PA.stream_chunk_pages(64, 1 << 16, 2, 144) == 1
+    assert PA.stream_chunk_pages(16, 128, 2, 8) == 8
+
+
+def test_an_olmo_tick_past_the_threshold_serves_alike_on_both_routes(
+        monkeypatch):
+    """A bfloat16 Olmo-Hybrid bundle at a width the kernel takes (four
+    heads of 32), its tables made to count as too large to gather:
+    served through the streamed kernel (interpret mode) and through
+    by_lane (the CPU), the tokens are the same, and the serve programs
+    record the route each took."""
+    from paddle_tpu.ops import paged_ops
+    from paddle_tpu.ops.pallas import attention as base
+
+    monkeypatch.setattr(paged_ops, "WHOLE_TABLES_BYTES", 1 << 10)
+    c = sizes(weight_dtype="bfloat16", n_heads=4, n_kv_heads=4,
+              head_dim=32)
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(3, c["vocab"], n) for n in (5, 20, 37)]
+    rows, routes = [], []
+    for interpret in (False, True):
+        base.force_interpret(interpret)
+        try:
+            srv, _ = build(c, block_size=16, n_blocks=32)
+            try:
+                replies = [srv.submit(p, max_new_tokens=8)
+                           for p in prompts]
+                rows.append([np.asarray(r.result(timeout=600))
+                             for r in replies])
+                routes.append(srv.stats()["self_attention_routes"])
+            finally:
+                srv.close()
+        finally:
+            base.force_interpret(False)
+    for a, b in zip(*rows):
+        assert (a == b).all()
+    assert {tuple(v) for v in routes[0].values()} == {("reference",)}
+    assert {tuple(v) for v in routes[1].values()} == {("kernel",)}

@@ -318,3 +318,41 @@ def test_a_tick_reads_wide_pools_one_lane_at_a_time(one_chip):
         _spec(one_chip, (_ROWS,), jnp.int32)).compile()
     # one lane's whole table, keys and values: 2 x 70.8 MB
     assert compiled.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+def test_a_tick_streams_wide_pools_through_the_kernel(one_chip,
+                                                      monkeypatch):
+    """The same tick's two full-attention layers through the op, routed
+    as on a TPU (the described chip's process sees the CPU backend, so
+    the test says it is on one): one Mosaic kernel a layer, streaming
+    each lane's live blocks, no float32 ``[*, 30, 128]`` relayout of a
+    lane's keys and values, and nothing of the by-lane route's size
+    held beside the arguments."""
+    import re
+
+    from paddle_tpu.ops import paged_ops, pallas
+
+    monkeypatch.setattr(pallas, "on_tpu", lambda: True)
+    blocks, size, pages, width, layers = 3584, 64, 144, 3840, 2
+    attrs = {"block_size": size, "n_heads": 30, "n_kv_heads": 30,
+             "scale": 128 ** -0.5}
+
+    def tick(q, pools, tab, pos):
+        for li in range(layers):
+            q = q + paged_ops.paged_decode_attention(_Ctx(
+                attrs, Q=q, PoolK=pools[2 * li], PoolV=pools[2 * li + 1],
+                Table=tab, Pos=pos))
+        return q
+    compiled = jax.jit(tick).lower(
+        _spec(one_chip, (_ROWS, 1, width)),
+        [_spec(one_chip, (blocks * size, width))
+         for _ in range(2 * layers)],
+        _spec(one_chip, (_ROWS, pages), jnp.int32),
+        _spec(one_chip, (_ROWS,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert _kernel_names(compiled) == {"paged_decode_attention_streamed"}
+    assert text.count('custom_call_target="tpu_custom_call"') == layers
+    assert not re.search(r"f32\[\d+,30,128\]", text)
+    # the by-lane route holds a lane's whole table, keys and values:
+    # up to 0.2 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.01e9
